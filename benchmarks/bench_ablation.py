@@ -31,5 +31,7 @@ def run(fast_frac: float = 0.25):
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import setup_compile_cache
+    setup_compile_cache()
     for r in run():
         print(",".join(map(str, r)))

@@ -39,5 +39,7 @@ def run(arch: str = "smollm-360m", fast_frac: float = 0.3):
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import setup_compile_cache
+    setup_compile_cache()
     for r in run() + run_table3():
         print(",".join(map(str, r)))

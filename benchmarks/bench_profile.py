@@ -29,5 +29,7 @@ def run():
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import setup_compile_cache
+    setup_compile_cache()
     for r in run():
         print(",".join(map(str, r)))

@@ -32,6 +32,7 @@ import json
 
 from repro import runtime
 from repro.core.hardware import PAPER_HM, TPU_V5E
+from repro.launch.cache import setup_compile_cache
 from repro.runtime.synthetic import synthetic_profile, synthetic_serve_trace
 
 FRACS = (0.1, 0.2, 0.4, 0.8)
@@ -71,6 +72,7 @@ def main(argv=None):
                          "piecewise-stationary drift workloads and gate "
                          "online vs static-stale vs clairvoyant")
     args = ap.parse_args(argv)
+    setup_compile_cache()
 
     prof = synthetic_profile()
     trace = synthetic_serve_trace()

@@ -67,5 +67,7 @@ def run_depth_sweep():
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import setup_compile_cache
+    setup_compile_cache()
     for r in run() + run_depth_sweep():
         print(",".join(map(str, r)))

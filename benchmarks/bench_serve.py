@@ -59,6 +59,7 @@ import json
 
 from repro import runtime
 from repro.configs.base import get_config
+from repro.launch.cache import setup_compile_cache
 from repro.core import hmsim
 from repro.core.hardware import PAPER_HM, TPU_V5E
 from repro.serve.engine import serve_trace_for
@@ -565,123 +566,127 @@ def run_disagg(arch: str = ARCH):
     return rows, (match, repacks, xdev, xdev_pred, tok_d, tok_c)
 
 
-_SHARDED_SCENARIO = """
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-import dataclasses, json, time
-import jax
-import jax.numpy as jnp
-from repro import runtime
-from repro.configs.base import get_config
-from repro.core.hardware import TPU_V5E
-from repro.models import model
-from repro.models.layers import split_params
-from repro.serve.disagg import DisaggregatedEngine
-from repro.serve.engine import predict_pool_counters, serve_trace_for
+def _sharded_scenario(arch: str, devs) -> dict:
+    """The live 2-shard engine on ``devs`` (>= 4 devices: 2 prefill + 2
+    decode, and the first two for the single-decode comparison): wall-clock
+    tok/s single vs sharded, the per-edge ledger against the replay, and one
+    decode step's overlap with a concurrent prefill->decode KV stream."""
+    import dataclasses
+    import time
 
-ARCH = %(arch)r
-cfg = dataclasses.replace(get_config(ARCH).reduced(), use_paged_decode=True)
-params, _ = split_params(model.init_params(jax.random.PRNGKey(0), cfg))
-max_seq, slots = 64, 4
-requests = [(48, 12)] * 4            # prefill-heavy: long prompts, short gen
-trace = serve_trace_for(get_config(ARCH), requests, slots=slots,
-                        layer_group=8)
-plan = runtime.plan(trace, TPU_V5E, 0.3 * trace.peak_kv_bytes())
-plan = dataclasses.replace(plan, hot_window=32, slot_hot_windows=None,
-                           page_tokens=8)
+    import jax
+    import jax.numpy as jnp
 
-def drive(devices, sd, seed=3):
+    from repro.models import model
+    from repro.models.layers import split_params
+    from repro.serve.disagg import DisaggregatedEngine
+    from repro.serve.engine import predict_pool_counters
+
+    cfg = dataclasses.replace(get_config(arch).reduced(),
+                              use_paged_decode=True)
+    params, _ = split_params(model.init_params(jax.random.PRNGKey(0), cfg))
+    max_seq, slots = 64, 4
+    requests = [(48, 12)] * 4        # prefill-heavy: long prompts, short gen
+    trace = serve_trace_for(get_config(arch), requests, slots=slots,
+                            layer_group=8)
+    plan = runtime.plan(trace, TPU_V5E, 0.3 * trace.peak_kv_bytes())
+    plan = dataclasses.replace(plan, hot_window=32, slot_hot_windows=None,
+                               page_tokens=8)
+    halves = [s % 2 for s in range(slots)]
+
+    def drive(devices, sd, seed=3):
+        b = DisaggregatedEngine(params, cfg, slots, max_seq,
+                                plan=dataclasses.replace(plan,
+                                                         slot_devices=sd),
+                                devices=devices)
+        key = jax.random.PRNGKey(seed)
+        for plen, d in requests:
+            key, sub = jax.random.split(key)
+            b.submit(jax.random.randint(sub, (plen,), 0,
+                                        cfg.vocab_size).astype(jnp.int32), d)
+        t0 = time.perf_counter()
+        outs = b.run()
+        dt = time.perf_counter() - t0
+        return sum(len(o) for o in outs) / dt, b
+
+    drive(devs[:2], None)                      # compile warmup, both shapes
+    drive(devs, halves)
+    tps1, _ = drive(devs[:2], None)
+    tps2, b2 = drive(devs, halves)
+    b2.mesh_table.check()
+    pred = predict_pool_counters(
+        requests, dataclasses.replace(plan, slot_devices=halves),
+        slots=slots, max_seq=max_seq, page_tokens=b2.page_tokens,
+        row_bytes=b2._row_bytes, dense_admit=True)
+    ledger_exact = (dict(b2.mesh_table.edge_bytes)
+                    == pred["edge_migration_bytes"])
+
+    # measured overlap: one decode step with vs without a concurrent
+    # KV-page stream over the prefill->decode edge.  Primed on a fresh
+    # engine so every timed step has all slots active and no admissions in
+    # flight.
     b = DisaggregatedEngine(params, cfg, slots, max_seq,
-                            plan=dataclasses.replace(plan, slot_devices=sd),
-                            devices=devices)
-    key = jax.random.PRNGKey(seed)
-    for plen, d in requests:
+                            plan=dataclasses.replace(plan,
+                                                     slot_devices=halves),
+                            devices=devs)
+    key = jax.random.PRNGKey(5)
+    for plen, _d in requests:
         key, sub = jax.random.split(key)
         b.submit(jax.random.randint(sub, (plen,), 0,
-                                    cfg.vocab_size).astype(jnp.int32), d)
-    t0 = time.perf_counter()
-    outs = b.run()
-    dt = time.perf_counter() - t0
-    return sum(len(o) for o in outs) / dt, b
+                                    cfg.vocab_size).astype(jnp.int32), 12)
+    while b.queue or b._jobs:
+        b.step()
+    b.step()                                   # compile the decode step
 
-devs = jax.devices()
-drive(devs[:2], None)                          # compile warmup, both shapes
-drive(devs, [s %% 2 for s in range(slots)])
-tps1, _ = drive(devs[:2], None)
-tps2, b2 = drive(devs, [s %% 2 for s in range(slots)])
-b2.mesh_table.check()
-pred = predict_pool_counters(
-    requests, dataclasses.replace(plan, slot_devices=[s %% 2
-                                                      for s in range(slots)]),
-    slots=slots, max_seq=max_seq, page_tokens=b2.page_tokens,
-    row_bytes=b2._row_bytes, dense_admit=True)
-ledger_exact = (dict(b2.mesh_table.edge_bytes)
-                == pred["edge_migration_bytes"])
+    width = cfg.num_kv_heads * cfg.head_dim
+    payload = jnp.zeros((cfg.num_layers, 2, 4, b.page_tokens, width),
+                        jnp.float32)
+    payload = jax.device_put(payload, b.prefill_devices[0])
+    payload.block_until_ready()
+    stream_bytes = float(payload.size * 4)
 
-# measured overlap: one decode step with vs without a concurrent KV-page
-# stream over the prefill->decode edge.  Primed on a fresh engine so every
-# timed step has all slots active and no admissions in flight.
-b = DisaggregatedEngine(params, cfg, slots, max_seq,
-                        plan=dataclasses.replace(
-                            plan, slot_devices=[s %% 2
-                                                for s in range(slots)]),
-                        devices=devs)
-key = jax.random.PRNGKey(5)
-for plen, _d in requests:
-    key, sub = jax.random.split(key)
-    b.submit(jax.random.randint(sub, (plen,), 0,
-                                cfg.vocab_size).astype(jnp.int32), 12)
-while b.queue or b._jobs:
-    b.step()
-b.step()                                       # compile the decode step
-
-D = cfg.num_kv_heads * cfg.head_dim
-payload = jnp.zeros((cfg.num_layers, 2, 4, b.page_tokens, D), jnp.float32)
-payload = jax.device_put(payload, b.prefill_devices[0])
-payload.block_until_ready()
-stream_bytes = float(payload.size * 4)
-
-def t_stream():
-    t0 = time.perf_counter()
-    y = jax.device_put(payload, b.decode_devices[0])
-    y.block_until_ready()
-    return time.perf_counter() - t0
-
-def t_step(with_stream):
-    t0 = time.perf_counter()
-    y = jax.device_put(payload, b.decode_devices[0]) if with_stream else None
-    b.step()
-    jax.block_until_ready(b.last_tok)
-    if y is not None:
+    def t_stream():
+        t0 = time.perf_counter()
+        y = jax.device_put(payload, b.decode_devices[0])
         y.block_until_ready()
-    return time.perf_counter() - t0
+        return time.perf_counter() - t0
 
-def med(xs):
-    xs = sorted(xs)
-    return xs[len(xs) // 2]
+    def t_step(with_stream):
+        t0 = time.perf_counter()
+        y = jax.device_put(payload, b.decode_devices[0]) if with_stream \
+            else None
+        b.step()
+        jax.block_until_ready(b.last_tok)
+        if y is not None:
+            y.block_until_ready()
+        return time.perf_counter() - t0
 
-stream_s = med([t_stream() for _ in range(5)])
-plain, both = [], []
-while any(b.active) and len(plain) < 8:
-    plain.append(t_step(False))
-    if any(b.active):
-        both.append(t_step(True))
-plain_s, both_s = med(plain), med(both or [0.0])
-denom = min(stream_s, plain_s) or 1.0
-overlap = max(0.0, min(1.0, (plain_s + stream_s - both_s) / denom))
-print(json.dumps({
-    "tok_s_single": tps1, "tok_s_sharded": tps2,
-    "ledger_exact": ledger_exact,
-    "stream_bytes": stream_bytes,
-    "step_ms": plain_s * 1e3, "stream_ms": stream_s * 1e3,
-    "step_with_stream_ms": both_s * 1e3,
-    "overlap_frac": overlap}))
-"""
+    def med(xs):
+        xs = sorted(xs)
+        return xs[len(xs) // 2]
+
+    stream_s = med([t_stream() for _ in range(5)])
+    plain, both = [], []
+    while any(b.active) and len(plain) < 8:
+        plain.append(t_step(False))
+        if any(b.active):
+            both.append(t_step(True))
+    plain_s, both_s = med(plain), med(both or [0.0])
+    denom = min(stream_s, plain_s) or 1.0
+    overlap = max(0.0, min(1.0, (plain_s + stream_s - both_s) / denom))
+    return {"tok_s_single": tps1, "tok_s_sharded": tps2,
+            "ledger_exact": ledger_exact, "stream_bytes": stream_bytes,
+            "step_ms": plain_s * 1e3, "stream_ms": stream_s * 1e3,
+            "step_with_stream_ms": both_s * 1e3, "overlap_frac": overlap,
+            "devices": [d.device_kind for d in devs]}
 
 
-def run_disagg_sharded(arch: str = ARCH):
+def run_disagg_sharded(arch: str = ARCH, devices=None):
     """Multi-shard disaggregation: the planner-side scaling gate plus the
-    measured KV-stream/decode overlap on a forced 4-device host mesh.
+    measured KV-stream/decode overlap, run in this process on ``devices``
+    (default: every device JAX sees; at least four — 2 prefill + 2 decode).
+    On a CPU host, start the process with
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=4``.
 
     Gates (deterministic, modeled): (a) ``price_disagg`` with two decode
     shards — each keeping the single run's per-device HBM — must price
@@ -691,16 +696,18 @@ def run_disagg_sharded(arch: str = ARCH):
     integer-exactly.  The wall-clock rows (sharded vs single tok/s; one
     decode step with vs without a concurrent prefill->decode KV-page
     stream, next to the cost model's edge-pipe time for the same bytes)
-    are published, not gated — the forced host "devices" share the same
+    are published, not gated — on forced host devices they share the same
     physical cores, so CPU wall-clock says nothing about a real mesh.
     """
-    import os
-    import subprocess
-    import sys
+    import jax
 
     from repro.core.hardware import default_cost_model
     from repro.serve.disagg import price_disagg
 
+    devices = list(devices) if devices is not None else jax.devices()
+    if len(devices) < 4:
+        raise ValueError(f"run_disagg_sharded needs 4 devices, got "
+                         f"{len(devices)}")
     cm = default_cost_model()
     heavy = [(480, 24), (512, 16), (448, 32), (500, 20)]
     htrace = serve_trace_for(get_config(arch), heavy, slots=len(heavy),
@@ -713,19 +720,7 @@ def run_disagg_sharded(arch: str = ARCH):
     tok_1 = single["disagg"].tokens_per_s
     tok_n = sharded["disagg"].tokens_per_s
 
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.pathsep.join(
-        [os.path.join(os.path.dirname(__file__), "..", "src")]
-        + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
-    env.pop("XLA_FLAGS", None)
-    out = subprocess.run([sys.executable, "-c",
-                          _SHARDED_SCENARIO % {"arch": arch}],
-                         capture_output=True, text=True, env=env,
-                         timeout=900)
-    if out.returncode != 0:
-        raise RuntimeError("sharded disagg scenario failed:\n"
-                           + out.stderr[-3000:])
-    rec = json.loads(out.stdout.strip().splitlines()[-1])
+    rec = _sharded_scenario(arch, devices[:4])
     modeled_stream_ms = (rec["stream_bytes"] / cm.link_bw * 1e3
                          if cm.link_bw else float("inf"))
 
@@ -800,6 +795,7 @@ def main(argv=None):
     ap.add_argument("--json", default="",
                     help="write rows + verdicts to this JSON file")
     args = ap.parse_args(argv)
+    setup_compile_cache()
 
     fracs = tuple(float(x) for x in args.fracs.split(",") if x)
     slots_list = tuple(int(x) for x in args.slots.split(",") if x)
@@ -993,35 +989,41 @@ def main(argv=None):
               f"tok_s={tok_d:.1f}/{tok_c:.1f},"
               f"{'OK' if d_ok else 'FAIL'}")
 
-        srows, (tok_n, tok_1, ledger_exact, rec) = \
-            run_disagg_sharded(args.arch)
-        disagg_rows += srows
-        for r in srows:
-            print(",".join(map(str, r)))
-        s_ok = tok_n >= tok_1 and ledger_exact
-        ok &= s_ok
-        checks.append({"check": "disagg_sharded",
-                       "modeled_sharded_tok_s": round(tok_n, 1),
-                       "modeled_single_tok_s": round(tok_1, 1),
-                       "ledger_exact": ledger_exact,
-                       "wall_sharded_tok_s":
-                           round(rec["tok_s_sharded"], 2),
-                       "wall_single_tok_s":
-                           round(rec["tok_s_single"], 2),
-                       "overlap": {
-                           "step_ms": round(rec["step_ms"], 3),
-                           "stream_ms_measured":
-                               round(rec["stream_ms"], 3),
-                           "step_with_stream_ms":
-                               round(rec["step_with_stream_ms"], 3),
-                           "overlap_frac":
-                               round(rec["overlap_frac"], 3)},
-                       "status": "OK" if s_ok else "FAIL"})
-        print(f"check,disagg_sharded,"
-              f"modeled_tok_s={tok_n:.1f}/{tok_1:.1f},"
-              f"ledger_exact={ledger_exact},"
-              f"overlap_frac={rec['overlap_frac']:.3f},"
-              f"{'OK' if s_ok else 'FAIL'}")
+        import jax
+        n_dev = len(jax.devices())
+        if n_dev < 4:
+            print(f"check,disagg_sharded,SKIPPED (needs 4 devices, "
+                  f"{n_dev} found)")
+        else:
+            srows, (tok_n, tok_1, ledger_exact, rec) = \
+                run_disagg_sharded(args.arch)
+            disagg_rows += srows
+            for r in srows:
+                print(",".join(map(str, r)))
+            s_ok = tok_n >= tok_1 and ledger_exact
+            ok &= s_ok
+            checks.append({"check": "disagg_sharded",
+                           "modeled_sharded_tok_s": round(tok_n, 1),
+                           "modeled_single_tok_s": round(tok_1, 1),
+                           "ledger_exact": ledger_exact,
+                           "wall_sharded_tok_s":
+                               round(rec["tok_s_sharded"], 2),
+                           "wall_single_tok_s":
+                               round(rec["tok_s_single"], 2),
+                           "overlap": {
+                               "step_ms": round(rec["step_ms"], 3),
+                               "stream_ms_measured":
+                                   round(rec["stream_ms"], 3),
+                               "step_with_stream_ms":
+                                   round(rec["step_with_stream_ms"], 3),
+                               "overlap_frac":
+                                   round(rec["overlap_frac"], 3)},
+                           "status": "OK" if s_ok else "FAIL"})
+            print(f"check,disagg_sharded,"
+                  f"modeled_tok_s={tok_n:.1f}/{tok_1:.1f},"
+                  f"ledger_exact={ledger_exact},"
+                  f"overlap_frac={rec['overlap_frac']:.3f},"
+                  f"{'OK' if s_ok else 'FAIL'}")
 
     if args.json:
         with open(args.json, "w") as f:

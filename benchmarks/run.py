@@ -9,6 +9,8 @@ import time
 
 def main() -> None:
     t0 = time.time()
+    from repro.launch.cache import setup_compile_cache
+    setup_compile_cache()
     from benchmarks import (bench_ablation, bench_compare, bench_planner,
                             bench_profile, bench_sensitivity, roofline)
 

@@ -6,17 +6,18 @@ memory, and the tiered run reproduces the all-HBM outputs exactly.
 
     PYTHONPATH=src python examples/serve_batched.py
 
-``--disagg`` instead demos prefill/decode disaggregation on a 2-device CPU
-mesh (forced host devices): prefill runs on one device, the finished KV
-pages stream over the device edge into the decode pools, and the outputs
-match the single-device engine bit for bit.
+``--disagg`` instead demos prefill/decode disaggregation on two devices:
+prefill runs on one, the finished KV pages stream over the device edge into
+the decode pools, and the outputs match the single-device engine bit for
+bit.  With ``JAX_PLATFORMS=cpu`` the two devices are forced host devices.
 
-    PYTHONPATH=src python examples/serve_batched.py --disagg
+    JAX_PLATFORMS=cpu PYTHONPATH=src python examples/serve_batched.py --disagg
 """
 import os
 import sys
 
-if "--disagg" in sys.argv:               # must land before the jax import
+if "--disagg" in sys.argv and os.environ.get("JAX_PLATFORMS") == "cpu":
+    # two host devices for the demo — must land before the jax import
     os.environ.setdefault(
         "XLA_FLAGS", "--xla_force_host_platform_device_count=2")
 

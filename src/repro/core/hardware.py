@@ -54,3 +54,21 @@ PAPER_HM = HWSpec(
     mig_overhead=2e-6,         # per page, amortized over batched move_pages
                                # with 4-thread parallel copy (Yan et al. mech.)
 )
+
+
+# The hardware description for each accelerator this repo runs on, keyed by
+# ``jax.devices()[0].device_kind``.  A device that is not listed is an error:
+# planning a chip with another chip's numbers would be silently wrong.
+DEVICE_KINDS = {
+    "TPU v5 lite": TPU_V5E,
+    "TPU v5e": TPU_V5E,
+}
+
+
+def for_device_kind(kind: str) -> HWSpec:
+    """The ``HWSpec`` the planner uses on a device of this ``device_kind``."""
+    try:
+        return DEVICE_KINDS[kind]
+    except KeyError:
+        raise ValueError(f"no hardware description for device kind {kind!r} "
+                         f"(known: {sorted(DEVICE_KINDS)})") from None

@@ -2,10 +2,11 @@
 
 The serving-time replacement for the concat-based cold-KV read: instead of
 materializing `concat(cold_prefix, hot_window)` before attention, KV lives in
-fixed-size sequence *pages* split across two physical pools —
+fixed-size sequence *pages* split across two physical pools, head-major so
+that one (page, head) block is a whole ``(page, D)`` tile —
 
-  k_hot / v_hot    (n_hot,  page, KVH, D)  device memory (HBM)
-  k_cold / v_cold  (n_cold, page, KVH, D)  host memory (pinned_host on TPU)
+  k_hot / v_hot    (n_hot,  KVH, page, D)  device memory (HBM)
+  k_cold / v_cold  (n_cold, KVH, page, D)  the cold tier's pages
 
 with a per-slot page table mapping logical page i of slot b to a physical
 page in one of the pools:
@@ -22,19 +23,25 @@ The pools the kernel reads are **persistent** in the serving engine
 (models/kvcache.py::PagedKVPools): decode scatters each new token's KV into
 its physical hot page through the same table before the kernel runs, admit /
 demote / free mutate single pages, and the table arrays are re-uploaded only
-when the PageTable's version changes.  The ``pool_layout`` / ``gather_pools``
-/ ``pack_kv_pools`` helpers below build the pool layout *from a dense cache*
-— the one-shot form used by model-level parity tests and ad-hoc callers, not
-by the engine's steady-state loop (which never re-packs).
+when the PageTable's version changes.  The engine keeps the cold tier in
+host memory (``pinned_host``) and stages it into HBM as a named, counted
+step before the kernel runs (``PagedKVPools.stage_cold``): this compiler
+implements no host->VMEM DMA, and its host->HBM DMA takes only blocks of
+8 x 1024 32-bit words (32 KiB), 16x one (page, head) of smollm-360m at
+16-token pages.  The ``pool_layout`` / ``gather_pools`` / ``pack_kv_pools``
+helpers below build the pool layout *from a dense cache* — the one-shot form
+used by model-level parity tests and ad-hoc callers, not by the engine's
+steady-state loop (which never re-packs).
 
-The kernel runs one (batch, kv_head) grid cell as a flash-decode loop over
-that slot's logical pages.  Every page — hot or cold — is streamed into a
-double-buffered VMEM window with `pltpu.make_async_copy`: while page i is in
-the online-softmax update, the DMA for page i+1 is already in flight, so the
-host->VMEM copy of cold pages overlaps with compute exactly like Sentinel's
-migration threads overlap training compute.  With `window > 0` the loop
-starts at the first page that intersects the attention window, skipping the
-cold prefix entirely.
+The kernel's grid is (batch, kv_head, logical page).  Page tables, tiers and
+lengths ride in SMEM as scalar prefetch; each pool operand's BlockSpec
+index map resolves logical page i to its physical ``(page, D)`` block, so
+the Pallas pipeline double-buffers the page DMAs behind the online-softmax
+update.  A page of the other tier repeats its pool's previous block index
+(``_block_indices``), and the pipeline skips a copy whose block index did
+not change — each step fetches only the page it reads.  Pages outside
+``[lo, npages)`` (beyond the slot's length, or before the attention window)
+neither compute nor fetch.
 
 Oracle: repro.kernels.ref.paged_decode_attention_ref — the same page loop in
 pure jnp, bit-exact against this kernel in interpret mode (same op sequence,
@@ -53,109 +60,108 @@ from repro.kernels.decode_attention import (NEG_INF, masked_scores,
                                             online_softmax_update)
 
 
-def _kernel(table_ref, tier_ref, len_ref, q_ref, k_hot, v_hot, k_cold, v_cold,
-            o_ref, k_win, v_win, sem, *, page, G, D, window, softcap_val,
-            n_hot, n_cold):
-    h = pl.program_id(1)
-    length = len_ref[0]
+def _kernel(hot_blk, cold_blk, tier_ref, len_ref, q_ref, kh_ref, vh_ref,
+            kc_ref, vc_ref, o_ref, acc, m_s, l_s, *, page, window,
+            softcap_val):
+    b, i = pl.program_id(0), pl.program_id(2)
+    del hot_blk, cold_blk                  # consumed by the index maps
+    length = len_ref[b]
     npages = pl.cdiv(length, page)
     lo = jnp.maximum(0, (length - window) // page) if window else 0
 
-    def start(i, slot):
-        """Kick off the async copy of logical page i into window ``slot``."""
-        phys = table_ref[0, i]
+    @pl.when(i == 0)
+    def _init():
+        acc[...] = jnp.zeros_like(acc)
+        m_s[...] = jnp.full_like(m_s, NEG_INF)
+        l_s[...] = jnp.zeros_like(l_s)
 
-        @pl.when(tier_ref[0, i] == 0)
-        def _():
-            p = jnp.clip(phys, 0, n_hot - 1)
-            pltpu.make_async_copy(k_hot.at[p, :, h], k_win.at[slot],
-                                  sem.at[slot, 0]).start()
-            pltpu.make_async_copy(v_hot.at[p, :, h], v_win.at[slot],
-                                  sem.at[slot, 1]).start()
-
-        @pl.when(tier_ref[0, i] != 0)
-        def _():
-            p = jnp.clip(phys, 0, n_cold - 1)
-            pltpu.make_async_copy(k_cold.at[p, :, h], k_win.at[slot],
-                                  sem.at[slot, 0]).start()
-            pltpu.make_async_copy(v_cold.at[p, :, h], v_win.at[slot],
-                                  sem.at[slot, 1]).start()
-
-    def wait(slot):
-        # the wait only needs dst shape/dtype for semaphore accounting, so a
-        # fixed hot-pool source stands in for whichever pool the copy used
-        pltpu.make_async_copy(k_hot.at[0, :, h], k_win.at[slot],
-                              sem.at[slot, 0]).wait()
-        pltpu.make_async_copy(v_hot.at[0, :, h], v_win.at[slot],
-                              sem.at[slot, 1]).wait()
-
-    q = q_ref[0, 0].astype(jnp.float32)                        # (G, D)
-
-    @pl.when(lo < npages)
-    def _warmup():
-        start(lo, jax.lax.rem(lo, 2))
-
-    def body(i, carry):
-        acc, m, l = carry
-        slot = jax.lax.rem(i, 2)
-
-        @pl.when(i + 1 < npages)
-        def _():  # next page's DMA overlaps this page's softmax update
-            start(i + 1, jax.lax.rem(i + 1, 2))
-
-        wait(slot)
-        s = masked_scores(q, k_win[slot].astype(jnp.float32), i * page,
+    @pl.when((i >= lo) & (i < npages))
+    def _page():
+        hot = tier_ref[b, i] == 0
+        k = jnp.where(hot, kh_ref[...], kc_ref[...]).astype(jnp.float32)
+        v = jnp.where(hot, vh_ref[...], vc_ref[...]).astype(jnp.float32)
+        s = masked_scores(q_ref[...].astype(jnp.float32), k, i * page,
                           length, window=window, softcap_val=softcap_val)
-        return online_softmax_update(s, v_win[slot].astype(jnp.float32),
-                                     acc, m, l)
+        acc[...], m_s[...], l_s[...] = online_softmax_update(
+            s, v, acc[...], m_s[...], l_s[...])
 
-    acc, m, l = jax.lax.fori_loop(
-        lo, npages, body,
-        (jnp.zeros((G, D), jnp.float32), jnp.full((G,), NEG_INF, jnp.float32),
-         jnp.zeros((G,), jnp.float32)))
-    o_ref[0, 0] = (acc / jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
+    @pl.when(i == pl.num_programs(2) - 1)
+    def _final():
+        o_ref[...] = (acc[...] / jnp.maximum(l_s[...], 1e-30)[:, None]
+                      ).astype(o_ref.dtype)
+
+
+def _block_indices(page_table, page_tier, lengths, page, window, n_hot,
+                   n_cold):
+    """Per (slot, logical page) physical block of each pool for the index
+    maps.  Page i reads page ``clip(i, lo, npages - 1)``; where that page
+    lives in the other tier, the pool's index repeats the nearest page of
+    its own tier in range (the previous one, else the next), so the pipeline
+    re-uses the block already in VMEM instead of fetching a page nobody
+    reads."""
+    B, NP = page_table.shape
+    i = jnp.arange(NP, dtype=jnp.int32)[None, :]
+    npages = (lengths + page - 1) // page
+    lo = jnp.maximum(0, (lengths - window) // page) if window \
+        else jnp.zeros_like(lengths)
+    in_range = (i >= lo[:, None]) & (i < npages[:, None])
+    i_eff = jnp.clip(i, lo[:, None], jnp.maximum(npages - 1, 0)[:, None])
+
+    def blocks(tier, n):
+        own = in_range & (page_tier == tier)
+        last = jax.lax.cummax(jnp.where(own, i, -1), axis=1)
+        nxt = jax.lax.cummin(jnp.where(own, i, NP), axis=1, reverse=True)
+        last_at = jnp.take_along_axis(last, i_eff, axis=1)
+        next_at = jnp.take_along_axis(nxt, i_eff, axis=1)
+        src = jnp.where(last_at >= 0, last_at,
+                        jnp.where(next_at < NP, next_at, 0))
+        phys = jnp.take_along_axis(page_table, src, axis=1)
+        return jnp.clip(phys, 0, n - 1).astype(jnp.int32)
+
+    return blocks(0, n_hot), blocks(1, n_cold)
 
 
 def paged_decode_attention(q, k_hot, v_hot, k_cold, v_cold, page_table,
                            page_tier, lengths, *, window: int = 0,
                            softcap_val: float = 0.0, interpret: bool = False):
-    """q: (B, H, D); pools (n, page, KVH, D); page_table/page_tier (B, NP);
+    """q: (B, H, D); pools (n, KVH, page, D); page_table/page_tier (B, NP);
     lengths: (B,) valid tokens per slot (>= 1). Returns (B, H, D)."""
     B, H, D = q.shape
-    page, KVH = k_hot.shape[1], k_hot.shape[2]
+    KVH, page = k_hot.shape[1], k_hot.shape[2]
     NP = page_table.shape[1]
     G = H // KVH
-    n_hot, n_cold = k_hot.shape[0], k_cold.shape[0]
+    page_table = page_table.astype(jnp.int32)
+    page_tier = page_tier.astype(jnp.int32)
+    lengths = lengths.astype(jnp.int32)
+    hot_blk, cold_blk = _block_indices(page_table, page_tier, lengths, page,
+                                       window, k_hot.shape[0],
+                                       k_cold.shape[0])
 
     qg = q.reshape(B, KVH, G, D)
-    kernel = functools.partial(_kernel, page=page, G=G, D=D, window=window,
-                               softcap_val=softcap_val, n_hot=n_hot,
-                               n_cold=n_cold)
+    kernel = functools.partial(_kernel, page=page, window=window,
+                               softcap_val=softcap_val)
+    hot_spec = pl.BlockSpec((None, None, page, D),
+                            lambda b, h, i, hb, cb, tr, ln: (hb[b, i], h, 0, 0))
+    cold_spec = pl.BlockSpec((None, None, page, D),
+                             lambda b, h, i, hb, cb, tr, ln: (cb[b, i], h, 0, 0))
+    row_spec = pl.BlockSpec((None, None, G, D),
+                            lambda b, h, i, *_: (b, h, 0, 0))
     out = pl.pallas_call(
         kernel,
-        grid=(B, KVH),
-        in_specs=[
-            pl.BlockSpec((1, NP), lambda b, h: (b, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, NP), lambda b, h: (b, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1,), lambda b, h: (b,), memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, G, D), lambda b, h: (b, h, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-        ],
-        out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h: (b, h, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(B, KVH, NP),
+            in_specs=[row_spec, hot_spec, hot_spec, cold_spec, cold_spec],
+            out_specs=row_spec,
+            scratch_shapes=[pltpu.VMEM((G, D), jnp.float32),
+                            pltpu.VMEM((G,), jnp.float32),
+                            pltpu.VMEM((G,), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((B, KVH, G, D), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((2, page, D), k_hot.dtype),    # double-buffered K window
-            pltpu.VMEM((2, page, D), v_hot.dtype),    # double-buffered V window
-            pltpu.SemaphoreType.DMA((2, 2)),          # (buffer, k/v)
-        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(page_table.astype(jnp.int32), page_tier.astype(jnp.int32),
-      lengths.astype(jnp.int32), qg, k_hot, v_hot, k_cold, v_cold)
+        name="paged_decode",
+    )(hot_blk, cold_blk, page_tier, lengths, qg, k_hot, v_hot, k_cold, v_cold)
     return out.reshape(B, H, D)
 
 
@@ -188,18 +194,19 @@ def pool_layout(cold_tokens, num_pages: int, page_tokens: int):
 
 
 def gather_pools(k_cache, v_cache, layout, page_tokens: int):
-    """One layer's (k_hot, v_hot, k_cold, v_cold) pools for a shared layout.
-    k_cache/v_cache: dense (B, S, KVH, D)."""
+    """One layer's (k_hot, v_hot, k_cold, v_cold) pools, each
+    (n, KVH, page, D), for a shared layout.  k_cache/v_cache: dense
+    (B, S, KVH, D)."""
     B, S, KVH, D = k_cache.shape
     assert S % page_tokens == 0, (S, page_tokens)
     NP = S // page_tokens
     _, _, hot_idx, cold_idx = layout
-    kp = k_cache.reshape(B, NP, page_tokens, KVH, D)
-    vp = v_cache.reshape(B, NP, page_tokens, KVH, D)
+    kp = k_cache.reshape(B, NP, page_tokens, KVH, D).transpose(0, 1, 3, 2, 4)
+    vp = v_cache.reshape(B, NP, page_tokens, KVH, D).transpose(0, 1, 3, 2, 4)
 
     def gather(pages, idx):
         if not idx:
-            return jnp.zeros((1, page_tokens, KVH, D), pages.dtype)
+            return jnp.zeros((1, KVH, page_tokens, D), pages.dtype)
         bs = jnp.asarray([b for b, _ in idx])
         ps = jnp.asarray([i for _, i in idx])
         return pages[bs, ps]

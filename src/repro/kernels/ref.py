@@ -69,7 +69,7 @@ def paged_decode_attention_ref(q, k_hot, v_hot, k_cold, v_cold, page_table,
     """Oracle for kernels/paged_decode.py: the same flash-decode page loop in
     pure jnp, vectorized over (batch, kv_head).
 
-    q: (B, H, D); pools (n, page, KVH, D); page_table/page_tier (B, NP);
+    q: (B, H, D); pools (n, KVH, page, D); page_table/page_tier (B, NP);
     lengths (B,).  The loop visits every logical page and relies on exact
     float semantics for tier-agnostic correctness: a fully masked page scores
     NEG_INF everywhere, whose exp underflows to exactly 0.0 in float32, so
@@ -82,7 +82,7 @@ def paged_decode_attention_ref(q, k_hot, v_hot, k_cold, v_cold, page_table,
     from repro.kernels.decode_attention import (masked_scores,
                                                 online_softmax_update)
     B, H, D = q.shape
-    page, KVH = k_hot.shape[1], k_hot.shape[2]
+    KVH, page = k_hot.shape[1], k_hot.shape[2]
     NP = page_table.shape[1]
     G = H // KVH
     qg = q.reshape(B, KVH, G, D).astype(jnp.float32)
@@ -93,8 +93,8 @@ def paged_decode_attention_ref(q, k_hot, v_hot, k_cold, v_cold, page_table,
         t = page_tier[:, i]
         hot = pool_hot[jnp.clip(phys, 0, pool_hot.shape[0] - 1)]
         cold = pool_cold[jnp.clip(phys, 0, pool_cold.shape[0] - 1)]
-        pg = jnp.where(t[:, None, None, None] == 0, hot, cold)  # (B,page,KVH,D)
-        return pg.transpose(0, 2, 1, 3).astype(jnp.float32)     # (B,KVH,page,D)
+        pg = jnp.where(t[:, None, None, None] == 0, hot, cold)  # (B,KVH,page,D)
+        return pg.astype(jnp.float32)
 
     def body(i, carry):
         acc, m, l = carry

@@ -164,9 +164,9 @@ def _paged_decode_core(cfg, q, k_all, v_all, cache_index, paged_view, window):
 
     The just-updated dense cache is split at each slot's cold boundary into
     the hot/cold pool layout of kernels/paged_decode.py and read back through
-    the per-slot page table — on TPU the Pallas kernel streams cold pages
-    over PCIe into a double-buffered VMEM window; on CPU the bit-equivalent
-    jnp oracle runs (dispatch in kernels/ops.py).  ``boundaries`` and
+    the per-slot page table — on TPU the Pallas kernel streams each page
+    into a double-buffered VMEM window; on CPU the bit-equivalent jnp oracle
+    runs (dispatch in kernels/ops.py).  ``boundaries`` and
     ``page_tokens`` must be concrete python ints (pool packing builds the
     page table at trace time), which the serving engine guarantees; the
     engine precomputes the layer-independent ``layout`` (page table, tier,
@@ -212,7 +212,9 @@ def _pool_prefill_core(cfg, q, k, v, cache, cache_index, paged_view, window,
     zero probability by ``attn_bias`` (exp(x + NEG_INF) == 0.0 in float32),
     the same way the dense path masks its zero-filled tail.  Shared-prefix
     pages below ``cache_index`` are read, never written — the engine caps
-    the start offset so the write region covers only private pages.
+    the start offset so the write region covers only private pages.  The
+    returned cache holds the hot pools only: prefill never writes a cold
+    page.
     """
     B, Sq, KV, G, hd = q.shape
     assert B == 1, "pool prefill admits one slot at a time"
@@ -223,18 +225,20 @@ def _pool_prefill_core(cfg, q, k, v, cache, cache_index, paged_view, window,
         + jnp.arange(Sq, dtype=jnp.int32)
     phys = table[0, pos // page]               # physical hot page per token
     off = pos % page
-    k_hot = cache["k_hot"].at[phys, off].set(k.reshape(Sq, KV * hd))
-    v_hot = cache["v_hot"].at[phys, off].set(v.reshape(Sq, KV * hd))
-    new_cache = {"k_hot": k_hot, "v_hot": v_hot,
-                 "k_cold": cache["k_cold"], "v_cold": cache["v_cold"]}
-    # full-row gather: (max_pages, page, KV*hd) -> (1, max_seq, KV, hd);
-    # out-of-pool indices clamp and are discarded by the tier select
-    sel = (tier[0] == 0)[:, None, None]
-    k_all = jnp.where(sel, k_hot[table[0]], cache["k_cold"][table[0]])
-    v_all = jnp.where(sel, v_hot[table[0]], cache["v_cold"][table[0]])
+    k_hot = cache["k_hot"].at[phys, :, off].set(k.reshape(Sq, KV, hd))
+    v_hot = cache["v_hot"].at[phys, :, off].set(v.reshape(Sq, KV, hd))
+    new_cache = {"k_hot": k_hot, "v_hot": v_hot}
+    # full-row gather: (max_pages, KV, page, hd) -> (1, max_seq, KV, hd);
+    # out-of-pool indices clamp and are discarded by the tier select.  A
+    # forward without staged cold pools maps no cold page: hot pages only.
+    k_all, v_all = k_hot[table[0]], v_hot[table[0]]
+    if "k_cold" in cache:
+        sel = (tier[0] != 1)[:, None, None, None]
+        k_all = jnp.where(sel, k_all, cache["k_cold"][table[0]])
+        v_all = jnp.where(sel, v_all, cache["v_cold"][table[0]])
     Skv = k_all.shape[0] * page
-    k_all = k_all.reshape(1, Skv, KV, hd)
-    v_all = v_all.reshape(1, Skv, KV, hd)
+    k_all = jnp.swapaxes(k_all, 1, 2).reshape(1, Skv, KV, hd)
+    v_all = jnp.swapaxes(v_all, 1, 2).reshape(1, Skv, KV, hd)
     bias = attn_bias(positions, jnp.arange(Skv), window=window)
     if bias.ndim == 2:
         bias = bias[None]
@@ -255,8 +259,8 @@ def _pool_decode_core(cfg, q, k, v, cache, cache_index, paged_view, window,
     lockstep decode can never corrupt a page a live slot references — the
     engine's pre-step CoW guarantees every active write page is exclusive),
     then attention reads the pools through ops.paged_decode_attention.
-    Returns (out (B,1,KV,G,hd)-shaped, new_cache) — the cold pools pass
-    through untouched: decode never writes below a boundary.
+    Returns (out (B,1,KV,G,hd)-shaped, new_cache) — the new cache holds
+    the hot pools only: decode never writes below a boundary.
     """
     from repro.kernels import ops as kernel_ops
 
@@ -274,21 +278,16 @@ def _pool_decode_core(cfg, q, k, v, cache, cache_index, paged_view, window,
     if active is not None:
         phys = jnp.where(active, phys, paged_view["garbage_page"])
     off = ci % page
-    kf = k.reshape(B, KV * hd)
-    vf = v.reshape(B, KV * hd)
-    k_hot = cache["k_hot"].at[phys, off].set(kf)
-    v_hot = cache["v_hot"].at[phys, off].set(vf)
-    new_cache = {"k_hot": k_hot, "v_hot": v_hot,
-                 "k_cold": cache["k_cold"], "v_cold": cache["v_cold"]}
-
-    def pool4(a):
-        return a.reshape(a.shape[0], page, KV, hd)
-
+    k_hot = cache["k_hot"].at[phys, :, off].set(k.reshape(B, KV, hd))
+    v_hot = cache["v_hot"].at[phys, :, off].set(v.reshape(B, KV, hd))
+    # the cold pools are read-only here (decode never writes below a
+    # boundary) and are not returned; a forward without staged cold pools
+    # maps no cold page, and the hot pools stand in for the cold operands
     out = kernel_ops.paged_decode_attention(
-        q.reshape(B, KV * G, hd), pool4(k_hot), pool4(v_hot),
-        pool4(cache["k_cold"]), pool4(cache["v_cold"]), table_arr, tier_arr,
-        ci + 1, window=window, softcap_val=cfg.attn_softcap)
-    return out, new_cache
+        q.reshape(B, KV * G, hd), k_hot, v_hot,
+        cache.get("k_cold", k_hot), cache.get("v_cold", v_hot), table_arr,
+        tier_arr, ci + 1, window=window, softcap_val=cfg.attn_softcap)
+    return out, {"k_hot": k_hot, "v_hot": v_hot}
 
 
 # ------------------------------------------------------------------- MLA ----

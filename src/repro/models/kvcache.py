@@ -4,13 +4,16 @@ periods to match the scanned layer stack.
 Tier-aware construction (Sentinel-Serve): a cache can be split along the KV
 sequence dimension into a *cold prefix* (old tokens, host/slow memory) and a
 *hot window* (recent tokens, HBM/fast memory), per the decode-phase
-``ServePlan``.  On TPU the cold prefix lives in ``pinned_host`` and streams
-over PCIe at read time; on CPU (this repo's CI) the only memory kind is the
-host itself, so placement degrades to an explicit no-op while the splice and
-merge mechanics stay identical.
+``ServePlan``.  The cold tier lives in host memory — ``pinned_host`` on TPU,
+and on the CPU backend too, which exposes it beside its ``device`` memory —
+so every op that reads a cold array next to device arrays states its
+transfer (``to_device`` / ``PagedKVPools.stage_cold``) and every write lands
+back in host memory explicitly (``to_host`` / ``cold_page_put``).
 """
 from __future__ import annotations
 
+import functools
+import math
 from dataclasses import dataclass
 from typing import Any, Dict, Optional
 
@@ -65,40 +68,113 @@ def init_cache(cfg, batch: int, max_seq: int, dtype=jnp.bfloat16) -> Dict[str, A
 HOST_MEMORY_KINDS = ("pinned_host", "unpinned_host")
 
 
-def host_memory_kind() -> Optional[str]:
-    """First host-side memory kind the default device exposes, or None.
-    TPU: 'pinned_host'.  CPU: 'unpinned_host' (which is also its default —
-    host offload is then an explicit no-op, keeping the code path uniform)."""
-    dev = jax.devices()[0]
-    try:
-        kinds = {m.kind for m in dev.addressable_memories()}
-    except Exception:
-        return None
+def host_memory_kind(device=None) -> str:
+    """The memory kind the cold tier lives in on ``device`` (default: the
+    first device).  A TPU must expose ``pinned_host``; elsewhere the first
+    host kind the device exposes.  Raises when there is none — a cold tier
+    silently left in device memory would make every tiering number a lie."""
+    dev = device if device is not None else jax.devices()[0]
+    kinds = {m.kind for m in dev.addressable_memories()}
+    if dev.platform == "tpu":
+        if "pinned_host" not in kinds:
+            raise RuntimeError(f"{dev} exposes no pinned_host memory "
+                               f"(memory kinds: {sorted(kinds)})")
+        return "pinned_host"
     for k in HOST_MEMORY_KINDS:
         if k in kinds:
             return k
-    return None
+    raise RuntimeError(f"{dev} exposes no host memory kind "
+                       f"(memory kinds: {sorted(kinds)})")
+
+
+def _device_of(a):
+    """The device an array is committed to (numpy inputs: the default)."""
+    devs = getattr(a, "devices", None)
+    return next(iter(devs())) if devs is not None else jax.devices()[0]
 
 
 def to_host(tree, device=None):
-    """Place every array leaf in host memory (async copy; XLA overlaps it with
-    whatever is executing — the migration channel).  Identity when the backend
-    exposes no host memory kind.  ``device`` selects whose host path the copy
-    rides (and on CPU-style backends, which device the array commits to) —
-    a sharded engine pins each shard's cold pool to that shard's device so
-    hot<->cold scatters never mix committed devices; default: device 0."""
-    kind = host_memory_kind()
-    if kind is None:
-        return tree
-    dev = device if device is not None else jax.devices()[0]
-    sh = jax.sharding.SingleDeviceSharding(dev, memory_kind=kind)
-    return jax.tree.map(lambda a: jax.device_put(a, sh), tree)
+    """Place every array leaf in host memory (async copy; XLA overlaps it
+    with whatever is executing — the migration channel).  ``device`` selects
+    whose host path the copy rides; by default each leaf keeps its own
+    device, so a sharded engine's cold pools stay with their shard."""
+    def put(a):
+        dev = device if device is not None else _device_of(a)
+        return jax.device_put(a, jax.sharding.SingleDeviceSharding(
+            dev, memory_kind=host_memory_kind(dev)))
+    return jax.tree.map(put, tree)
 
 
 def to_device(tree):
-    """Bring host-resident leaves back to the device's default memory."""
-    dev = jax.devices()[0]
-    return jax.tree.map(lambda a: jax.device_put(a, dev), tree)
+    """Bring host-resident leaves into their own device's memory."""
+    def put(a):
+        dev = _device_of(a)
+        return jax.device_put(a, jax.sharding.SingleDeviceSharding(
+            dev, memory_kind=dev.default_memory().kind))
+    return jax.tree.map(put, tree)
+
+
+# Cold pages in host memory.  A TPU program can read a host buffer and write
+# one in place (async dynamic-slice / dynamic-update-slice DMAs) when the
+# update spans the buffer's most-major dimension only and its rows are
+# lane-dense — hence the page-major cold storage of ``PagedKVPools``.  The
+# CPU backend has no in-program host placement: there a page update runs on
+# a device copy of the pool, which is then placed back in host memory.
+
+@functools.lru_cache(maxsize=None)
+def _cold_put_fn(device):
+    host = jax.sharding.SingleDeviceSharding(device,
+                                             memory_kind="pinned_host")
+
+    def put(cold, page, phys):
+        cold = jax.device_put(cold, jax.memory.Space.Host)   # already there
+        page = jax.device_put(page, jax.memory.Space.Host)
+        return jax.lax.dynamic_update_index_in_dim(cold, page, phys, 0)
+    return jax.jit(put, donate_argnums=0, out_shardings=host)
+
+
+def cold_page_put(cold, page, phys):
+    """``cold[phys] = page[0]`` for a host-resident page-major pool and a
+    device-resident ``page`` of shape ``(1,) + cold.shape[1:]``; returns the
+    new pool, in host memory."""
+    dev = _device_of(cold)
+    if dev.platform == "tpu":          # in place: only the page moves
+        return _cold_put_fn(dev)(cold, page, phys)
+    return to_host(to_device(cold).at[phys].set(page[0]), dev)
+
+
+@jax.jit
+def cold_page_get(cold, phys):
+    """Device copy ``cold[phys:phys+1]`` of one page of a host pool."""
+    return jax.device_put(jax.lax.dynamic_index_in_dim(cold, phys, 0),
+                          jax.memory.Space.Device)
+
+
+@functools.partial(jax.jit, static_argnums=(1,))
+def _cold_stage(cold, page_shape):
+    """The page-major host pool (n, [P,] R, C) as an HBM pool in the
+    kernel's layout ([P,] n, KVH, page, D)."""
+    x = jax.device_put(cold, jax.memory.Space.Device)
+    x = x.reshape(x.shape[:-2] + page_shape)
+    return jnp.moveaxis(x, 0, -4)
+
+
+def pool_page_tokens(cfg, requested: int, max_seq: int, device=None) -> int:
+    """The page size the persistent pools use for a plan's ``page_tokens``:
+    the largest size <= the request that divides ``max_seq``.  On a TPU it
+    is first rounded up to a multiple of ``128 / gcd(128, KVH * D)`` tokens,
+    so that one cold page of one layer fills whole 128-lane rows — the
+    layout a TPU program can write into host memory in place."""
+    dev = device if device is not None else jax.devices()[0]
+    quantum = 1
+    if dev.platform == "tpu":
+        quantum = 128 // math.gcd(128, cfg.num_kv_heads * cfg.head_dim)
+    page = -(-max(1, int(requested)) // quantum) * quantum
+    for p in range(page, 0, -quantum):
+        if max_seq % p == 0:
+            return p
+    raise ValueError(f"max_seq {max_seq} has no page size that is a "
+                     f"multiple of {quantum} tokens")
 
 
 def kv_token_bytes(cfg, dtype_bytes: int = 2) -> float:
@@ -138,10 +214,11 @@ def split_seq_cache(caches, max_seq: int, cold_len: int):
 
 
 def merge_seq_cache(cold, hot):
-    """Inverse of split_seq_cache.  Inside jit, the concatenate reading a
-    host-resident cold leaf is exactly the streamed cold-KV fetch."""
+    """Inverse of split_seq_cache.  A host-resident cold leaf is brought to
+    its device first — the cold-KV fetch, stated explicitly."""
     return jax.tree.map(
-        lambda c, h: h if c is None else jnp.concatenate([c, h], axis=-2),
+        lambda c, h: h if c is None else jnp.concatenate(
+            [to_device(c), h], axis=-2),
         cold, hot, is_leaf=lambda x: x is None)
 
 
@@ -722,8 +799,10 @@ class PagedTieredCache:
     def merged(self):
         """Masked where-merge: rows below each slot's boundary read the cold
         (host) copy — inside jit this read IS the streamed cold-KV fetch —
-        rows above it read the hot tree.  Bit-identical to the dense cache."""
+        rows above it read the hot tree.  Bit-identical to the dense cache.
+        The cold tree is brought to the device explicitly first."""
         b = jnp.asarray(self.boundaries, jnp.int32)
+        cold = to_device(self.cold)
         pos = jnp.arange(self.max_seq)
 
         def one(stacked):
@@ -737,10 +816,10 @@ class PagedTieredCache:
             return f
 
         none_leaf = lambda x: x is None
-        return {"prologue": jax.tree.map(one(False), self.cold["prologue"],
+        return {"prologue": jax.tree.map(one(False), cold["prologue"],
                                          self.hot["prologue"],
                                          is_leaf=none_leaf),
-                "slots": jax.tree.map(one(True), self.cold["slots"],
+                "slots": jax.tree.map(one(True), cold["slots"],
                                       self.hot["slots"], is_leaf=none_leaf)}
 
     def set_boundary(self, slot: int, cold_tokens: int):
@@ -759,8 +838,9 @@ class PagedTieredCache:
             else int(jnp.asarray(self.boundaries)[slot])
         if new_cold_tokens <= old:
             return 0
-        self.cold = to_host(copy_slot_rows(self.cold, self.hot, slot, old,
-                                           new_cold_tokens, self.max_seq))
+        self.cold = to_host(copy_slot_rows(to_device(self.cold), self.hot,
+                                           slot, old, new_cold_tokens,
+                                           self.max_seq))
         self.set_boundary(slot, new_cold_tokens)
         return new_cold_tokens - old
 
@@ -790,15 +870,26 @@ class PagedKVPools:
     instead of a dense per-slot cache that gets re-packed into pools every
     step, the pools ARE the cache.  Per attention layer the storage is
 
-      k_hot / v_hot    (n_hot+1, page, KV*hd)   device memory (HBM)
-      v_cold / k_cold  (n_cold,  page, KV*hd)   host memory
+      k_hot / v_hot    ([P,] n_hot+1, KVH, page, D)   device memory (HBM)
+      k_cold / v_cold  (n_cold, [P,] R, C)            host memory
 
-    addressed through one layer-independent :class:`PageTable`.  Decode
-    *writes into the pools* via the table (models/attention.py resolves each
-    slot's write position to a physical hot page); admit / demote / free are
-    incremental per-page deltas; ``as_arrays`` uploads of the table happen
-    only when the table's ``version`` changes.  Non-attention layer caches
-    (stateful kinds, MLA) keep their dense batched form inside ``tree``.
+    addressed through one layer-independent :class:`PageTable` (``[P,]`` is
+    the scanned period axis of stacked layers).  The hot pools are
+    head-major, the layout the kernel reads.  The cold pools are
+    *page-major*: one cold page of every stacked layer is one contiguous
+    lane-dense block of ``R x C`` (``C = 128`` when ``KVH * page * D``
+    tiles it), which is what lets a demotion write just that page into host
+    memory.  Decode *writes into the hot pools* via the table
+    (models/attention.py resolves each slot's write position to a physical
+    hot page); admit / demote / free are incremental per-page deltas;
+    ``as_arrays`` uploads of the table happen only when the table's
+    ``version`` changes.  Non-attention layer caches (stateful kinds, MLA)
+    keep their dense batched form inside ``tree``.
+
+    The forward never sees the host pools: ``forward_tree`` hands it the hot
+    pools plus, when any page is cold, the cold pools staged into HBM in the
+    kernel's layout (``stage_cold``, counted in ``cold_stage_bytes``), and
+    ``adopt`` takes back the updated hot pools.
 
     The extra hot page at index ``garbage`` (= n_hot) absorbs the lockstep
     writes of inactive batch slots, so a finished slot can never scribble
@@ -807,16 +898,17 @@ class PagedKVPools:
     ``stats`` counts the events the steady-state acceptance test pins to
     zero: ``repacks`` (dense->pool re-packs — never in this design),
     ``table_uploads`` (layout deltas), ``page_copies`` (demote/CoW data
-    movement), ``admit_page_writes`` (prefill landing in the pools).
+    movement), ``admit_page_writes`` (prefill landing in the pools), and
+    the staging step's ``cold_stages`` / ``cold_stage_bytes``.
     """
 
     def __init__(self, cfg, slots: int, max_seq: int, page_tokens: int,
                  dtype=jnp.bfloat16, hot_pages: Optional[int] = None,
                  cold_pages: Optional[int] = None, device=None):
         assert max_seq % page_tokens == 0, (max_seq, page_tokens)
-        # the device whose host path cold pages ride (None = device 0): a
-        # sharded engine gives each shard's pool its own device so demotes
-        # never scatter across committed devices
+        # the device that holds the hot pools and whose host memory holds
+        # the cold ones (None: the default device) — a sharded engine gives
+        # each shard's pool its own device
         self.device = device
         self.cfg, self.num_slots = cfg, slots
         self.max_seq, self.page_tokens = max_seq, page_tokens
@@ -824,44 +916,47 @@ class PagedKVPools:
         self.table = PageTable(slots, self.num_pages, page_tokens,
                                hot_pages, cold_pages)
         self.garbage = self.table.n_hot          # scratch page, never mapped
+        KVH, D = cfg.num_kv_heads, cfg.head_dim
+        self.page_shape = (KVH, page_tokens, D)
+        width = KVH * page_tokens * D
+        lanes = 128 if width % 128 == 0 else width
+        self.cold_rows = (width // lanes, lanes)
         self.tree = self._init_tree(dtype)
         self._cached_arrays = None
         self._cached_version = -1
         self.stats = {"repacks": 0, "table_uploads": 0, "page_copies": 0,
-                      "admit_page_writes": 0}
+                      "admit_page_writes": 0, "cold_stages": 0,
+                      "cold_stage_bytes": 0}
         self.peak_pages = 0
 
     # --------------------------------------------------------- construction --
-    def _pool_layer(self, kind: str, dtype):
-        cfg = self.cfg
-        if kind in ATTN_KINDS:
-            D = cfg.num_kv_heads * cfg.head_dim
-            hot = jnp.zeros((self.table.n_hot + 1, self.page_tokens, D), dtype)
-            cold = jnp.zeros((self.table.n_cold, self.page_tokens, D), dtype)
-            return {"k_hot": hot, "v_hot": hot,
-                    "k_cold": cold, "v_cold": cold}
-        return init_layer_cache(cfg, kind, self.num_slots, self.max_seq, dtype)
+    def _pool_layer(self, kind: str, dtype, lead: tuple):
+        if kind not in ATTN_KINDS:
+            one = init_layer_cache(self.cfg, kind, self.num_slots,
+                                   self.max_seq, dtype)
+            if not lead:
+                return one
+            return jax.tree.map(
+                lambda a: jnp.broadcast_to(a[None], lead + a.shape).copy(),
+                one)
+
+        def hot():
+            return jnp.zeros(lead + (self.table.n_hot + 1,) + self.page_shape,
+                             dtype, device=self.device)
+
+        def cold():                       # its own buffer: writes donate it
+            return to_host(jnp.zeros((self.table.n_cold,) + lead
+                                     + self.cold_rows, dtype,
+                                     device=self.device), self.device)
+        return {"k_hot": hot(), "v_hot": hot(), "k_cold": cold(),
+                "v_cold": cold()}
 
     def _init_tree(self, dtype):
         cfg = self.cfg
-
-        def host_cold(entry, kind):
-            if kind in ATTN_KINDS:
-                entry["k_cold"] = to_host(entry["k_cold"], self.device)
-                entry["v_cold"] = to_host(entry["v_cold"], self.device)
-            return entry
-
-        pro = [host_cold(self._pool_layer(k, dtype), k) for k in cfg.prologue]
-
-        def stacked(kind):
-            one = self._pool_layer(kind, dtype)
-            d = jax.tree.map(
-                lambda a: jnp.broadcast_to(
-                    a[None], (cfg.num_periods,) + a.shape).copy()
-                if cfg.num_periods > 1 else a[None], one)
-            return host_cold(d, kind)
-
-        return {"prologue": pro, "slots": [stacked(k) for k in cfg.period]}
+        return {"prologue": [self._pool_layer(k, dtype, ())
+                             for k in cfg.prologue],
+                "slots": [self._pool_layer(k, dtype, (cfg.num_periods,))
+                          for k in cfg.period]}
 
     def _attn_entries(self, *others):
         """Yield (stacked, pool_entry[, other_entry...]) per attention layer."""
@@ -896,6 +991,85 @@ class PagedKVPools:
                 "page_tokens": self.page_tokens, "active": active_mask,
                 "garbage_page": self.garbage}
 
+    # ------------------------------------------------- the forward's view --
+    def has_cold(self) -> bool:
+        """Whether any physical cold page is in use."""
+        return len(self.table.cold_free) < self.table.n_cold
+
+    def stage_cold(self, entry) -> tuple:
+        """One attention layer's cold pools staged from host memory into HBM
+        in the kernel's layout — the named (``jit(_cold_stage)`` in a
+        trace), counted cold read of a step."""
+        k = _cold_stage(entry["k_cold"], self.page_shape)
+        v = _cold_stage(entry["v_cold"], self.page_shape)
+        self.stats["cold_stage_bytes"] += int(k.nbytes) + int(v.nbytes)
+        return k, v
+
+    def forward_tree(self, stage: bool):
+        """The cache tree a forward consumes: hot pools and dense caches,
+        plus — with ``stage`` — the staged cold pools.  Without ``stage`` no
+        page the table maps may be cold, and attention reads the hot pools
+        only.  The host pools are never handed to the forward."""
+        def view(entry, kind):
+            if kind not in ATTN_KINDS:
+                return entry
+            out = {"k_hot": entry["k_hot"], "v_hot": entry["v_hot"]}
+            if stage:
+                out["k_cold"], out["v_cold"] = self.stage_cold(entry)
+            return out
+
+        if stage:
+            self.stats["cold_stages"] += 1
+        return {"prologue": [view(e, k) for e, k in
+                             zip(self.tree["prologue"], self.cfg.prologue)],
+                "slots": [view(e, k) for e, k in
+                          zip(self.tree["slots"], self.cfg.period)]}
+
+    def adopt(self, new_tree) -> None:
+        """Take a forward's updated caches back: the hot pools of attention
+        layers (their cold pools stay where they are), every other layer's
+        cache whole."""
+        for part, kinds in (("prologue", self.cfg.prologue),
+                            ("slots", self.cfg.period)):
+            for i, kind in enumerate(kinds):
+                if kind in ATTN_KINDS:
+                    self.tree[part][i] = dict(
+                        self.tree[part][i], k_hot=new_tree[part][i]["k_hot"],
+                        v_hot=new_tree[part][i]["v_hot"])
+                else:
+                    self.tree[part][i] = new_tree[part][i]
+
+    # ------------------------------------------------------ page transfers --
+    def hot_page(self, entry, stacked: bool, phys: int) -> tuple:
+        """(k, v) of one hot page: ([P,] KVH, page, D) device arrays."""
+        if stacked:
+            return entry["k_hot"][:, phys], entry["v_hot"][:, phys]
+        return entry["k_hot"][phys], entry["v_hot"][phys]
+
+    def set_hot_page(self, entry, stacked: bool, phys: int, k, v) -> None:
+        if stacked:
+            entry["k_hot"] = entry["k_hot"].at[:, phys].set(k)
+            entry["v_hot"] = entry["v_hot"].at[:, phys].set(v)
+        else:
+            entry["k_hot"] = entry["k_hot"].at[phys].set(k)
+            entry["v_hot"] = entry["v_hot"].at[phys].set(v)
+
+    def cold_page(self, entry, phys: int) -> tuple:
+        """(k, v) of one cold page, brought to the device in storage form
+        (1, [P,] R, C)."""
+        return (cold_page_get(entry["k_cold"], phys),
+                cold_page_get(entry["v_cold"], phys))
+
+    def set_cold_page(self, entry, phys: int, k, v) -> None:
+        """Write one cold page (device arrays in storage form) into host
+        memory."""
+        entry["k_cold"] = cold_page_put(entry["k_cold"], k, phys)
+        entry["v_cold"] = cold_page_put(entry["v_cold"], v, phys)
+
+    def _cold_form(self, page):
+        """A hot page ([P,] KVH, page, D) in cold storage form."""
+        return page.reshape((1,) + page.shape[:-3] + self.cold_rows)
+
     # ----------------------------------------------------------- mutations --
     def free_slot(self, slot: int) -> int:
         n = self.table.free_slot(slot)
@@ -927,18 +1101,12 @@ class PagedKVPools:
             return False
         src, new, tier = r
         self._note()
-        kk, vv = ("k_cold", "v_cold") if tier == 1 else ("k_hot", "v_hot")
-        for entry in self._attn_entries():
-            stacked, pool = entry[0], entry[1]
-            if stacked:
-                k2 = pool[kk].at[:, new].set(pool[kk][:, src])
-                v2 = pool[vv].at[:, new].set(pool[vv][:, src])
-            else:
-                k2 = pool[kk].at[new].set(pool[kk][src])
-                v2 = pool[vv].at[new].set(pool[vv][src])
+        for stacked, entry in self._attn_entries():
             if tier == 1:
-                k2, v2 = to_host(k2, self.device), to_host(v2, self.device)
-            pool[kk], pool[vv] = k2, v2
+                self.set_cold_page(entry, new, *self.cold_page(entry, src))
+            else:
+                self.set_hot_page(entry, stacked, new,
+                                  *self.hot_page(entry, stacked, src))
         self.stats["page_copies"] += 1
         return True
 
@@ -953,18 +1121,22 @@ class PagedKVPools:
             "admit writes land in the hot pool"
         phys = [self.table.table[slot][i] for i in pages]
         pg = self.page_tokens
+        KVH, _, D = self.page_shape
+
+        def head_major(rows):            # ([P,] pg, KVH*D) -> ([P,] KVH, pg, D)
+            rows = rows.reshape(rows.shape[:-1] + (KVH, D))
+            return jnp.swapaxes(rows, -3, -2)
+
         for entry in self._attn_entries(fresh):
             stacked, pool, fr = entry
-            kh, vh = pool["k_hot"], pool["v_hot"]
             for i, ph in zip(pages, phys):
                 lo = i * pg
-                if stacked:                  # pool (P,n,pg,D), fresh (P,1,S,D)
-                    kh = kh.at[:, ph].set(fr["k"][:, 0, lo:lo + pg])
-                    vh = vh.at[:, ph].set(fr["v"][:, 0, lo:lo + pg])
-                else:                        # pool (n,pg,D),   fresh (1,S,D)
-                    kh = kh.at[ph].set(fr["k"][0, lo:lo + pg])
-                    vh = vh.at[ph].set(fr["v"][0, lo:lo + pg])
-            pool["k_hot"], pool["v_hot"] = kh, vh
+                if stacked:                  # fresh (P, 1, S, KVH*D)
+                    k, v = fr["k"][:, 0, lo:lo + pg], fr["v"][:, 0, lo:lo + pg]
+                else:                        # fresh (1, S, KVH*D)
+                    k, v = fr["k"][0, lo:lo + pg], fr["v"][0, lo:lo + pg]
+                self.set_hot_page(pool, stacked, ph, head_major(k),
+                                  head_major(v))
         self.stats["admit_page_writes"] += len(pages)
 
     def splice_other(self, fresh, slot: int) -> None:
@@ -995,23 +1167,17 @@ class PagedKVPools:
         hot->cold only when the PageTable allocated a fresh cold copy
         (exclusive page, or the first sharer to demote) — twin reuse by
         later sharers moves zero bytes, which is how shared pages' migration
-        bytes are counted exactly once.  Returns whether data was copied."""
+        bytes are counted exactly once.  The copy writes that one page of
+        every stacked layer into host memory.  Returns whether data was
+        copied."""
         idx = self.table.cold_pages(slot)
         cold_phys, src, copied = self.table.demote(slot, idx)
         self._note()
         if copied:
-            for entry in self._attn_entries():
-                stacked, pool = entry
-                if stacked:
-                    kc = pool["k_cold"].at[:, cold_phys].set(
-                        pool["k_hot"][:, src])
-                    vc = pool["v_cold"].at[:, cold_phys].set(
-                        pool["v_hot"][:, src])
-                else:
-                    kc = pool["k_cold"].at[cold_phys].set(pool["k_hot"][src])
-                    vc = pool["v_cold"].at[cold_phys].set(pool["v_hot"][src])
-                pool["k_cold"], pool["v_cold"] = \
-                    to_host(kc, self.device), to_host(vc, self.device)
+            for stacked, entry in self._attn_entries():
+                k, v = self.hot_page(entry, stacked, src)
+                self.set_cold_page(entry, cold_phys, self._cold_form(k),
+                                   self._cold_form(v))
             self.stats["page_copies"] += 1
         return copied
 

@@ -586,8 +586,11 @@ def pack_slots(weights: Sequence[float], decode_devices: int,
     on the shard minimizing (same-tenant slots already there, load, index):
     load balance with an anti-affinity tie-break that spreads a tenant's
     slots across shards, so one device failure cannot take out a whole
-    tenant.  Deterministic — equal keys resolve by slot then shard index."""
+    tenant.  Equal loads go to the shard holding fewer slots (zero-weight
+    slots would otherwise stack on one shard).  Deterministic — equal keys
+    resolve by slot then shard index."""
     load = [0.0] * decode_devices
+    count = [0] * decode_devices
     tenant_count = [dict() for _ in range(decode_devices)]
     out = [0] * len(weights)
     order = sorted(range(len(weights)), key=lambda s: (-weights[s], s))
@@ -595,9 +598,10 @@ def pack_slots(weights: Sequence[float], decode_devices: int,
         tn = slot_tenants[s] if slot_tenants else None
         d = min(range(decode_devices),
                 key=lambda i: (tenant_count[i].get(tn, 0) if tn is not None
-                               else 0, load[i], i))
+                               else 0, load[i], count[i], i))
         out[s] = d
         load[d] += weights[s]
+        count[d] += 1
         if tn is not None:
             tenant_count[d][tn] = tenant_count[d].get(tn, 0) + 1
     return out

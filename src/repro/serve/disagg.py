@@ -98,17 +98,13 @@ class DisaggregatedEngine(ContinuousBatcher):
         self.decode_device = decode_devs[0]
         self.n_shards = len(self.decode_devices)
         params = jax.device_put(params, self.decode_device)
-        super().__init__(params, cfg, batch_slots, max_seq, scfg=scfg,
-                         plan=plan, paged=True, slot_tenants=slot_tenants)
         # prompts prefill whole on the prefill group and stream across the
         # device edge — pool-direct suffix chunks would write the decode
-        # pools from the wrong device, so the legacy dense path stays on
-        self._pool_prefill_ok = False
-        if self.prefill_chunk_tokens:
-            raise ValueError(
-                "chunked prefill is colocated-engine only: the "
-                "DisaggregatedEngine prefills whole prompts on the prefill "
-                "group (set prefill_chunk_tokens=0)")
+        # pools from the wrong device, so the dense admission stays on (and
+        # chunked prefill is refused)
+        super().__init__(params, cfg, batch_slots, max_seq, scfg=scfg,
+                         plan=plan, paged=True, slot_tenants=slot_tenants,
+                         device=self.decode_device, pool_prefill=False)
         if self.pool is None:
             raise ValueError(
                 "DisaggregatedEngine needs the persistent pools layout: "
@@ -142,8 +138,8 @@ class DisaggregatedEngine(ContinuousBatcher):
             self.slot_devices = validate_slot_devices(sd, batch_slots,
                                                       self.n_shards)
             dt = jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
-            # one B-slot pool per shard, each pinned to its device (cold
-            # pages ride the owning shard's host path): a slot's pages live
+            # one B-slot pool per shard, each on its device (cold pages in
+            # that device's host memory): a slot's pages live
             # only in its owning shard's pool, and the global slot index
             # doubles as the local one — re-homing lands in an empty
             # same-index row
@@ -151,11 +147,6 @@ class DisaggregatedEngine(ContinuousBatcher):
                 kvcache.PagedKVPools(cfg, batch_slots, max_seq, pg, dt,
                                      device=d)
                 for d in self.decode_devices[1:]]
-            self.pool.device = self.decode_device
-            for entry in self.pool._attn_entries():
-                _, e = entry
-                e["k_cold"] = kvcache.to_host(e["k_cold"], self.decode_device)
-                e["v_cold"] = kvcache.to_host(e["v_cold"], self.decode_device)
             self.params_shards = [self.params] + [
                 jax.device_put(params, d) for d in self.decode_devices[1:]]
             self.mesh_table = kvcache.MeshPageTable(
@@ -285,9 +276,9 @@ class DisaggregatedEngine(ContinuousBatcher):
             logits, new_tree, _ = model.forward(
                 self.params_shards[d], self.cfg,
                 {"tokens": self.last_tok[idx][:, None]},
-                caches=pool.tree, cache_index=self.lengths[idx],
-                decode=True, paged_view=view)
-            pool.tree = new_tree
+                caches=pool.forward_tree(stage=pool.has_cold()),
+                cache_index=self.lengths[idx], decode=True, paged_view=view)
+            pool.adopt(new_tree)
             outs.append((idxs, logits))
         for s in range(self.B):
             if not self.active[s]:
@@ -319,10 +310,7 @@ class DisaggregatedEngine(ContinuousBatcher):
             return super().apply_plan(new_plan)
         if hasattr(new_plan, "changes"):       # a PlanDelta, not a plan
             new_plan = self.plan.apply_delta(new_plan)
-        page = max(1, new_plan.page_tokens)
-        if self.max_seq % page:
-            page = next(p for p in range(page, 0, -1)
-                        if self.max_seq % p == 0)
+        page = self._plan_page_tokens(new_plan)
         if page != self.page_tokens:
             raise ValueError(
                 f"re-plan changes page geometry ({page} != "
@@ -382,32 +370,18 @@ class DisaggregatedEngine(ContinuousBatcher):
         out = self.mesh_table.migrate_slot(self._gslot(old, slot),
                                            self._gslot(new, slot))
         dst_phys = list(dt.table[slot][base:base + n])
+        dst_dev = self.decode_devices[new]
         for i in range(n):
-            hot = src_tier[i] == 0
-            kk, vv = ("k_hot", "v_hot") if hot else ("k_cold", "v_cold")
             sp, dp = src_phys[i], dst_phys[i]
-            for entry in src_pool._attn_entries(dst_pool.tree):
-                stacked, s_ent, d_ent = entry
-                if stacked:
-                    val_k, val_v = s_ent[kk][:, sp], s_ent[vv][:, sp]
-                else:
-                    val_k, val_v = s_ent[kk][sp], s_ent[vv][sp]
-                if hot:                        # the shard<->shard edge copy
-                    val_k = jax.device_put(val_k, self.decode_devices[new])
-                    val_v = jax.device_put(val_v, self.decode_devices[new])
-                else:                          # host-internal re-homing
-                    val_k = jnp.asarray(jax.device_get(val_k))
-                    val_v = jnp.asarray(jax.device_get(val_v))
-                if stacked:
-                    k2 = d_ent[kk].at[:, dp].set(val_k)
-                    v2 = d_ent[vv].at[:, dp].set(val_v)
-                else:
-                    k2 = d_ent[kk].at[dp].set(val_k)
-                    v2 = d_ent[vv].at[dp].set(val_v)
-                if not hot:
-                    k2 = kvcache.to_host(k2, self.decode_devices[new])
-                    v2 = kvcache.to_host(v2, self.decode_devices[new])
-                d_ent[kk], d_ent[vv] = k2, v2
+            for stacked, s_ent, d_ent in src_pool._attn_entries(dst_pool.tree):
+                if src_tier[i] == 0:           # the shard<->shard edge copy
+                    k, v = jax.device_put(
+                        src_pool.hot_page(s_ent, stacked, sp), dst_dev)
+                    dst_pool.set_hot_page(d_ent, stacked, dp, k, v)
+                else:                          # re-homed into dst's host
+                    k, v = jax.device_put(src_pool.cold_page(s_ent, sp),
+                                          dst_dev)
+                    dst_pool.set_cold_page(d_ent, dp, k, v)
         return out["hot_bytes"] + out["cold_bytes"]
 
     # ----------------------------------------------------------- counters --

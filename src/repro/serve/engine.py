@@ -110,7 +110,8 @@ class ContinuousBatcher:
     def __init__(self, params, cfg, batch_slots: int, max_seq: int,
                  scfg: Optional[ServeConfig] = None, plan=None,
                  paged: bool = False, slot_tenants=None,
-                 prefill_chunk_tokens: Optional[int] = None):
+                 prefill_chunk_tokens: Optional[int] = None, device=None,
+                 pool_prefill: bool = True):
         if paged and plan is None:
             raise ValueError("paged=True requires a ServePlan (plan=...)")
         self.params, self.cfg = params, cfg
@@ -159,16 +160,15 @@ class ContinuousBatcher:
         self._mig_accounted = 0.0
         self.paged = self.tiered = self.caches = self.ptable = None
         self.pool = None
+        self.device = device                       # pools' device (None: default)
         if paged:
-            page = max(1, plan.page_tokens)
-            if max_seq % page:                     # buffer must tile in pages
-                page = next(p for p in range(page, 0, -1) if max_seq % p == 0)
+            page = self._plan_page_tokens(plan)
             self.page_tokens = page
             if cfg.use_paged_decode and not cfg.prefix_lm:
                 # persistent pools: the page table owns physical placement,
                 # decode writes through it (no dense mirror to re-pack)
                 self.pool = kvcache.PagedKVPools(cfg, batch_slots, max_seq,
-                                                 page, dt)
+                                                 page, dt, device=device)
                 self.ptable = self.pool.table
             else:
                 self.paged = kvcache.init_paged_cache(cfg, batch_slots,
@@ -208,19 +208,29 @@ class ContinuousBatcher:
         self.prefill_skipped_tokens = 0        # rows skipped via shared pages
         # pool-direct prefill (suffix compute straight into the physical
         # pages) needs every layer's cache to be a pool entry: pure-attention
-        # stacks only, and not the disaggregated engine (which prefills on
-        # its own device group).  Everything else keeps the legacy dense
-        # one-shot prefill + admit_rows splice.
+        # stacks only.  Everything else keeps the legacy dense one-shot
+        # prefill + admit_rows splice, as does ``pool_prefill=False`` — the
+        # disaggregated engine's admission (on a TPU the two prefills attend
+        # through different kernels and are not bit-identical).
         kinds = tuple(cfg.prologue) + tuple(cfg.period)
         self._pool_prefill_ok = (
-            self.pool is not None
+            pool_prefill and self.pool is not None
             and all(k in kvcache.ATTN_KINDS for k in kinds)
             and not cfg.prefix_lm and not cfg.num_prefix_tokens
             and not cfg.num_codebooks)
         if self.prefill_chunk_tokens and not self._pool_prefill_ok:
             raise ValueError(
-                "prefill_chunk_tokens requires the persistent-pools layout "
-                "with a pure-attention stack (pool-direct prefill)")
+                "prefill_chunk_tokens requires pool-direct prefill: the "
+                "persistent-pools layout with a pure-attention stack, on a "
+                "colocated engine (the DisaggregatedEngine prefills whole "
+                "prompts on its prefill group)")
+
+    def _plan_page_tokens(self, plan) -> int:
+        """The engine's page size for a plan: the plan's ``page_tokens``
+        fitted to ``max_seq`` and, on a TPU, to lane-dense cold pages
+        (kvcache.pool_page_tokens)."""
+        return kvcache.pool_page_tokens(self.cfg, plan.page_tokens,
+                                        self.max_seq, self.device)
 
     def submit(self, tokens, num_tokens: int, prefix_key=None, tenant=None):
         """Queue a request.  ``prefix_key`` (hashable) marks requests that
@@ -406,7 +416,7 @@ class ContinuousBatcher:
             fc, fh = kvcache.split_seq_cache(fresh, self.max_seq,
                                              self.cold_len)
             self.tiered.cold = kvcache.to_host(kvcache.splice_slot(
-                self.tiered.cold, fc, slot, self.B))
+                kvcache.to_device(self.tiered.cold), fc, slot, self.B))
             self.tiered.hot = kvcache.splice_slot(
                 self.tiered.hot, fh, slot, self.B)
             # global boundary: the full cold prefix re-hosts on refill
@@ -509,10 +519,15 @@ class ContinuousBatcher:
         table, tier = self.pool.arrays()
         view = {"page_table": table[slot][None], "page_tier": tier[slot][None],
                 "page_tokens": pg, "active": None, "prefill": True}
-        job.last, self.pool.tree = model.prefill_suffix(
+        # the chunk reads this slot's row only: stage the cold tier iff the
+        # row maps a cold page (a shared prefix the donor already demoted)
+        caches = self.pool.forward_tree(
+            stage=self.ptable.cold_pages(slot) > 0)
+        job.last, new_tree = model.prefill_suffix(
             self.params, self.cfg,
             {"tokens": job.tokens[job.done:end][None]},
-            caches=self.pool.tree, start=job.done, paged_view=view)
+            caches=caches, start=job.done, paged_view=view)
+        self.pool.adopt(new_tree)
         self.prefill_compute_tokens += end - job.done
         self._step_prefill += end - job.done
         job.done = end
@@ -543,9 +558,9 @@ class ContinuousBatcher:
         paged_view = self.pool.paged_view(self._active_mask)
         logits, new_caches, _ = model.forward(
             self.params, self.cfg, {"tokens": self.last_tok[:, None]},
-            caches=self.pool.tree, cache_index=self.lengths,
-            decode=True, paged_view=paged_view)
-        self.pool.tree = new_caches
+            caches=self.pool.forward_tree(stage=self.pool.has_cold()),
+            cache_index=self.lengths, decode=True, paged_view=paged_view)
+        self.pool.adopt(new_caches)
         # advance each grown slot's own cold boundary by whole pages;
         # twin-deduped shared pages advance the boundary with zero copy
         for s in range(self.B):
@@ -625,8 +640,8 @@ class ContinuousBatcher:
                         pos = self._host_len[s]
                         self.tiered.cold = kvcache.to_host(
                             kvcache.copy_slot_rows(
-                                self.tiered.cold, new_caches, s, pos, pos + 1,
-                                self.max_seq))
+                                kvcache.to_device(self.tiered.cold),
+                                new_caches, s, pos, pos + 1, self.max_seq))
                         self.sim_migration_bytes += self._row_bytes
             else:
                 self.caches = new_caches
@@ -674,10 +689,7 @@ class ContinuousBatcher:
                              "layout (use_paged_decode=True)")
         if hasattr(new_plan, "changes"):       # a PlanDelta, not a plan
             new_plan = self.plan.apply_delta(new_plan)
-        page = max(1, new_plan.page_tokens)
-        if self.max_seq % page:
-            page = next(p for p in range(page, 0, -1)
-                        if self.max_seq % p == 0)
+        page = self._plan_page_tokens(new_plan)
         if page != self.page_tokens:
             raise ValueError(
                 f"re-plan changes page geometry ({page} != "

@@ -93,9 +93,10 @@ def test_pack_kv_pools_layout_invariants():
     # reconstruct
     for b in range(B):
         for i in range(NP):
-            pool = kcold if int(tier[b, i]) else kh
+            pool = kcold if int(tier[b, i]) else kh      # (n, KVH, page, D)
             assert jnp.array_equal(pool[int(tab[b, i])],
-                                   kc[b, i * page:(i + 1) * page])
+                                   kc[b, i * page:(i + 1) * page]
+                                   .transpose(1, 0, 2))
 
 
 def test_ops_dispatch_paged():

@@ -168,16 +168,17 @@ def test_pool_decode_writes_land_in_physical_pages(setup):
     b.submit(jnp.arange(5, dtype=jnp.int32), 4)
     for _ in range(3):
         b.step()
+    view = b.pool.forward_tree(stage=b.pool.has_cold())
     logits_ref, _, _ = model.forward(
-        params, cfg_k, {"tokens": b.last_tok[:, None]}, caches=b.pool.tree,
+        params, cfg_k, {"tokens": b.last_tok[:, None]}, caches=view,
         cache_index=b.lengths, decode=True,
         paged_view=b.pool.paged_view(b._active_mask))
     # zero slot 0's first physical hot page -> attention must change
-    entry = b.pool.tree["slots"][0]
+    entry = view["slots"][0]
     phys = b.ptable.table[0][0]
     wiped = {**entry, "k_hot": entry["k_hot"].at[:, phys].set(0.0)}
-    tree = {"prologue": list(b.pool.tree["prologue"]),
-            "slots": [wiped] + list(b.pool.tree["slots"][1:])}
+    tree = {"prologue": list(view["prologue"]),
+            "slots": [wiped] + list(view["slots"][1:])}
     logits_wiped, _, _ = model.forward(
         params, cfg_k, {"tokens": b.last_tok[:, None]}, caches=tree,
         cache_index=b.lengths, decode=True,
@@ -232,6 +233,62 @@ def test_shared_prefix_slots_bit_identical_and_cheaper(setup):
     b_u.ptable.check()
 
 
+def _cold_kinds(pool):
+    return {e[k].sharding.memory_kind for _, e in pool._attn_entries()
+            for k in ("k_cold", "v_cold")}
+
+
+def test_pool_cold_arrays_stay_in_host_memory(setup):
+    """The cold tier's arrays stay in host memory through admit, demote and
+    CoW — every transfer is explicit — and a 20% plan's tokens equal the
+    all-HBM engine's."""
+    cfg, params = setup
+    cfg_k = dataclasses.replace(cfg, use_paged_decode=True)
+    max_seq, slots, page = 32, 2, 4
+    host = kvcache.host_memory_kind()
+    trace = engine.serve_trace_for(get_config("smollm-360m"),
+                                   [(11, 6), (12, 6)], slots=slots,
+                                   layer_group=8)
+    plan = runtime.plan(trace, TPU_V5E, 0.2 * trace.peak_kv_bytes())
+    plan = dataclasses.replace(plan, hot_window=4, slot_hot_windows=None,
+                               page_tokens=page)
+    sys_p = jax.random.randint(jax.random.PRNGKey(7), (9,), 0,
+                               cfg.vocab_size).astype(jnp.int32)
+    reqs = [(jnp.concatenate([sys_p, jnp.arange(2 + i, dtype=jnp.int32)]),
+             6) for i in range(2)]
+
+    def drive(c, p, paged):
+        b = engine.ContinuousBatcher(params, c, slots, max_seq, plan=p,
+                                     paged=paged)
+        for t, d in reqs:
+            b.submit(t, d, prefix_key="sys" if paged else None)
+        return b.run(), b
+
+    want, _ = drive(cfg, None, False)
+    b = engine.ContinuousBatcher(params, cfg_k, slots, max_seq, plan=plan,
+                                 paged=True)
+    assert _cold_kinds(b.pool) == {host}
+    for t, d in reqs:
+        b.submit(t, d, prefix_key="sys")
+    b._admit()                                     # admit + demotions
+    assert b.pool.stats["page_copies"] > 0
+    assert _cold_kinds(b.pool) == {host}
+    # CoW of a cold page: the sharer's first page is cold and shared
+    assert b.ptable.tier[1][0] == 1 and b.ptable.is_shared(1, 0)
+    src = b.ptable.table[1][0]
+    assert b.pool.cow_for_write(1, 0)
+    new = b.ptable.table[1][0]
+    assert new != src
+    assert _cold_kinds(b.pool) == {host}
+    for _, e in b.pool._attn_entries():
+        assert jnp.array_equal(kvcache.to_device(e["k_cold"][new]),
+                               kvcache.to_device(e["k_cold"][src]))
+    got = b.run()
+    assert _cold_kinds(b.pool) == {host}
+    assert got == want
+    b.ptable.check()
+
+
 def test_shared_prefix_logits_bit_identical_one_step(setup):
     """One decode step, logits only: slot 1 sharing slot 0's prefix pages
     produces exactly the logits of a private-pages run (same values read
@@ -252,8 +309,8 @@ def test_shared_prefix_logits_bit_identical_one_step(setup):
         pv = b.pool.paged_view(b._active_mask)
         logits, _, _ = model.forward(
             params, cfg_k, {"tokens": b.last_tok[:, None]},
-            caches=b.pool.tree, cache_index=b.lengths, decode=True,
-            paged_view=pv)
+            caches=b.pool.forward_tree(stage=b.pool.has_cold()),
+            cache_index=b.lengths, decode=True, paged_view=pv)
         return logits, b
 
     l_shared, b_shared = one(True)

@@ -1,0 +1,162 @@
+"""Compiles for a described TPU v5e, with no chip attached: the serving
+path's kernels and host-tier page ops at smollm-360m widths.  What the
+chip's compiler would refuse, it refuses here.
+
+The topology is described inside a module fixture, never at import: only
+one process at a time may load the TPU library, and the test workers
+import every test file.
+"""
+import dataclasses
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+# smollm-360m: 15 query / 5 KV heads of 64; 8 slots of 2048 tokens in
+# 16-token pages; 32 layers
+B, H, KVH, D, PAGE, MAX_SEQ, LAYERS = 8, 15, 5, 64, 16, 2048, 32
+NP = MAX_SEQ // PAGE
+N = B * NP
+
+
+@pytest.fixture(scope="module")
+def chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:                       # no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return topo.devices[0]
+
+
+@pytest.fixture
+def compile_for(chip):
+    """``compile_for(fn, *shapes)``: compile ``fn`` for the described chip
+    with JAX's persistent cache off (it cannot read back a compile for a
+    device that is not attached)."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+
+    def run(fn, *args, **jit_kw):
+        return jax.jit(fn, **jit_kw).lower(*args).compile()
+    yield run
+    jax.config.update("jax_enable_compilation_cache", prev)
+
+
+@pytest.fixture
+def shapes(chip):
+    dev = SingleDeviceSharding(chip)
+    host = SingleDeviceSharding(chip, memory_kind="pinned_host")
+
+    def sds(shape, dtype=jnp.bfloat16, where=dev):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=where)
+    return sds, host
+
+
+def _kernel_args(sds, host, cold_host):
+    """Kernel operands; with ``cold_host`` the cold pools are the engine's
+    page-major host storage (n, R, 128) in pinned_host."""
+    cold = sds((N, KVH * PAGE * D // 128, 128), where=host) if cold_host \
+        else sds((N, KVH, PAGE, D))
+    return (sds((B, H, D)), sds((N + 1, KVH, PAGE, D)),
+            sds((N + 1, KVH, PAGE, D)), cold, cold,
+            sds((B, NP), jnp.int32), sds((B, NP), jnp.int32),
+            sds((B,), jnp.int32))
+
+
+@pytest.mark.parametrize("case", [
+    "page_tables_in_smem",        # was: (1, NP) / (1,) SMEM blocks
+    "per_head_page_slices",       # was: a (page, D=64) slice of (n,page,KVH,D)
+    "cold_pools_in_pinned_host",  # was: ANY memory space on a host operand
+])
+def test_paged_decode_compiles_for_v5e(compile_for, shapes, case):
+    """The decode kernel at smollm-360m widths compiles to a Mosaic custom
+    call; each case is one refusal the earlier kernel met on this chip."""
+    from repro.kernels.paged_decode import paged_decode_attention
+    from repro.models import kvcache
+    sds, host = shapes
+    cold_host = case == "cold_pools_in_pinned_host"
+
+    def step(q, kh, vh, kc, vc, table, tier, lengths):
+        if cold_host:                    # the engine's staging step
+            kc = kvcache._cold_stage(kc, (KVH, PAGE, D))
+            vc = kvcache._cold_stage(vc, (KVH, PAGE, D))
+        return paged_decode_attention(q, kh, vh, kc, vc, table, tier,
+                                      lengths, window=0, interpret=False)
+
+    compiled = compile_for(step, *_kernel_args(sds, host, cold_host))
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_flash_attention_compiles_at_prefill_widths(compile_for, shapes):
+    from repro.kernels.flash_attention import flash_attention
+    sds, _ = shapes
+    q = sds((1, H, 1536, D))
+    kv = sds((1, KVH, 1536, D))
+    compiled = compile_for(
+        lambda q, k, v: flash_attention(q, k, v, causal=True,
+                                        interpret=False), q, kv, kv)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("op", ["demote_put", "page_get", "stage"])
+def test_cold_page_ops_compile_for_v5e(compile_for, shapes, chip, op):
+    """The pools' host-memory page ops on the full page-major cold pool:
+    a demotion writes one page in place, a read fetches one page, the
+    staging step brings the pool into HBM in the kernel's layout."""
+    from repro.models import kvcache
+    sds, host = shapes
+    rows = (KVH * PAGE * D) // 128
+    cold = sds((N, LAYERS, rows, 128), where=host)
+    page = sds((1, LAYERS, rows, 128))
+    if op == "demote_put":
+        compiled = kvcache._cold_put_fn(chip).lower(
+            cold, page, sds((), jnp.int32)).compile()
+    elif op == "page_get":
+        compiled = compile_for(kvcache.cold_page_get.__wrapped__, cold,
+                               sds((), jnp.int32))
+    else:
+        compiled = compile_for(kvcache._cold_stage.__wrapped__, cold,
+                               (KVH, PAGE, D), static_argnums=(1,))
+    assert compiled.as_text()
+
+
+def test_decode_step_compiles_at_published_width(compile_for, shapes,
+                                                 monkeypatch):
+    """One whole decode forward of smollm-360m through the page pools, with
+    the compiled kernel (steered in this test: the CPU backend would pick
+    the jnp reference)."""
+    from repro.configs.base import get_config
+    from repro.kernels import ops
+    from repro.models import model
+    from repro.models.layers import split_params
+    sds, _ = shapes
+    monkeypatch.setitem(ops._FORCE, "pallas", True)
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    cfg = dataclasses.replace(get_config("smollm-360m"),
+                              use_paged_decode=True)
+    params = jax.tree.map(lambda a: sds(a.shape, a.dtype), jax.eval_shape(
+        lambda k: split_params(model.init_params(k, cfg))[0],
+        jax.random.PRNGKey(0)))
+    hot = sds((LAYERS, N + 1, KVH, PAGE, D))
+    cold = sds((LAYERS, N, KVH, PAGE, D))
+    caches = {"prologue": [], "slots": [{"k_hot": hot, "v_hot": hot,
+                                         "k_cold": cold, "v_cold": cold}]}
+
+    def step(params, tok, caches, lengths, table, tier, active):
+        pv = {"page_table": table, "page_tier": tier, "active": active,
+              "page_tokens": PAGE, "garbage_page": N}
+        return model.forward(params, cfg, {"tokens": tok}, caches=caches,
+                             cache_index=lengths, decode=True,
+                             paged_view=pv)[:2]
+
+    compiled = compile_for(step, params, sds((B, 1), jnp.int32), caches,
+                           sds((B,), jnp.int32), sds((B, NP), jnp.int32),
+                           sds((B, NP), jnp.int32), sds((B,), jnp.bool_))
+    assert "tpu_custom_call" in compiled.as_text()
