@@ -33,24 +33,6 @@ SLOTS, MAX_SEQ, N_REQUESTS, DECODE_TOKENS = 8, 2048, 12, 32
 PROMPT_LENS = (512, 1024, 1536)
 
 
-class Compiles:
-    """Backend compile seconds and persistent-cache hits, from JAX's own
-    monitoring events."""
-
-    def __init__(self):
-        self.seconds, self.cache_hits = 0.0, 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, event, duration, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.seconds += duration
-
-    def _event(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
-
-
 def check(cond, what):
     if not cond:
         raise SystemExit(f"chip_smoke: check failed: {what}")
@@ -90,18 +72,25 @@ def decode_step_text(b) -> str:
 
 def serve(make, label):
     """Build an engine with ``make()``, run it to completion; returns
-    (engine, outputs, seconds)."""
+    (engine, outputs, seconds).  The whole of it is one ``smoke.serve``
+    span, so the programs it compiled or loaded from the persistent cache
+    are those counted on its records."""
+    from repro import obs
     t0 = time.perf_counter()
-    b = make()
-    outs = b.run()
-    jax.block_until_ready(b.last_tok)
+    with obs.span("smoke.serve", run=label):
+        b = make()
+        outs = b.run()
+        jax.block_until_ready(b.last_tok)
     dt = time.perf_counter() - t0
+    recs = obs.records(int(t0 * 1e9), time.perf_counter_ns())
     print(f"  {label}: {len(outs)} requests, "
-          f"{sum(len(o) for o in outs)} tokens in {dt:.3f} s (smoke timing)")
+          f"{sum(len(o) for o in outs)} tokens in {dt:.3f} s (smoke timing); "
+          f"programs compiled {sum(r.built for r in recs)}, loaded from the "
+          f"persistent cache {sum(r.loaded for r in recs)}")
     return b, outs, dt
 
 
-def one_chip(cfg, params, requests, hw, compiles):
+def one_chip(cfg, params, requests, hw):
     from repro.launch.serve import tiered_server
 
     def make(all_fast):
@@ -109,11 +98,8 @@ def one_chip(cfg, params, requests, hw, compiles):
                                      max_seq=MAX_SEQ, fast_frac=0.2, hw=hw,
                                      all_fast=all_fast)[0]
 
-    c0 = compiles.seconds
     tiered, out_t, dt_t = serve(make(False), "tiered (20% fast)")
-    c1 = compiles.seconds
     fast, out_f, dt_f = serve(make(True), "all-fast")
-    c2 = compiles.seconds
     ct = tiered.counters()
     dev = jax.devices()[0]
     placement = pool_placement(tiered.pool)
@@ -126,9 +112,6 @@ def one_chip(cfg, params, requests, hw, compiles):
           f"{sorted(k for _, k in placement if k)}")
     print(f"  decode step contains tpu_custom_call: "
           f"{'tpu_custom_call' in text}")
-    print(f"  compile seconds (smoke timing): tiered run {c1 - c0:.3f}, "
-          f"all-fast run {c2 - c1:.3f}; persistent cache hits "
-          f"{compiles.cache_hits}")
     print(f"  run seconds (smoke timing, compiles included): tiered "
           f"{dt_t:.3f}, all-fast {dt_f:.3f}")
     check(len(out_t) == len(requests)
@@ -222,7 +205,6 @@ def main(argv=None) -> int:
     from repro.models.layers import split_params
 
     cache_dir = setup_compile_cache()
-    compiles = Compiles()
     cfg = get_config("smollm-360m")
     print(f"device {dev.device_kind} x{len(jax.devices())}, "
           f"jax {jax.__version__}, compile cache {cache_dir}")
@@ -240,7 +222,7 @@ def main(argv=None) -> int:
     if args.chips == 4:
         four_chips(cfg, params, requests, hw)
     else:
-        one_chip(cfg, params, requests, hw, compiles)
+        one_chip(cfg, params, requests, hw)
         stats = dev.memory_stats() or {}
         print(f"  peak_bytes_in_use {stats.get('peak_bytes_in_use')}")
     print(json.dumps({"ok": True, "device": {

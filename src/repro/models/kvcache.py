@@ -20,6 +20,7 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.configs.base import ATTN, LOCAL, LSTM, MAMBA, MLA, MLSTM, SHARED_ATTN, SLSTM
 from repro.models import ssm as ssm_mod
 from repro.models import xlstm as xlstm_mod
@@ -977,7 +978,8 @@ class PagedKVPools:
         """(page_table, page_tier) device arrays, re-uploaded only when the
         PageTable mutated since the last call (incremental layout deltas)."""
         if self._cached_version != self.table.version:
-            self._cached_arrays = self.table.as_arrays()
+            with obs.span("sentinel.table"):
+                self._cached_arrays = self.table.as_arrays()
             self._cached_version = self.table.version
             self.stats["table_uploads"] += 1
         return self._cached_arrays
@@ -1009,7 +1011,8 @@ class PagedKVPools:
         """The cache tree a forward consumes: hot pools and dense caches,
         plus — with ``stage`` — the staged cold pools.  Without ``stage`` no
         page the table maps may be cold, and attention reads the hot pools
-        only.  The host pools are never handed to the forward."""
+        only.  The host pools are never handed to the forward.  Staging is
+        one ``sentinel.stage_cold`` span with the bytes it staged."""
         def view(entry, kind):
             if kind not in ATTN_KINDS:
                 return entry
@@ -1018,12 +1021,21 @@ class PagedKVPools:
                 out["k_cold"], out["v_cold"] = self.stage_cold(entry)
             return out
 
-        if stage:
+        def tree():
+            return {"prologue": [view(e, k) for e, k in
+                                 zip(self.tree["prologue"],
+                                     self.cfg.prologue)],
+                    "slots": [view(e, k) for e, k in
+                              zip(self.tree["slots"], self.cfg.period)]}
+
+        if not stage:
+            return tree()
+        with obs.span("sentinel.stage_cold") as attrs:
+            staged = self.stats["cold_stage_bytes"]
             self.stats["cold_stages"] += 1
-        return {"prologue": [view(e, k) for e, k in
-                             zip(self.tree["prologue"], self.cfg.prologue)],
-                "slots": [view(e, k) for e, k in
-                          zip(self.tree["slots"], self.cfg.period)]}
+            out = tree()
+            attrs["bytes"] = self.stats["cold_stage_bytes"] - staged
+        return out
 
     def adopt(self, new_tree) -> None:
         """Take a forward's updated caches back: the hot pools of attention

@@ -61,6 +61,7 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.launch.mesh import disagg_groups
 from repro.models import kvcache, model
 from repro.runtime.plan import validate_slot_devices
@@ -248,10 +249,9 @@ class DisaggregatedEngine(ContinuousBatcher):
         fresh = jax.device_put(fresh, self.decode_devices[d])
         pool.admit_rows(fresh, slot, range(shared_pages, n))
         pool.splice_other(fresh, slot)
-        target = self._slot_cold_target(slot, S)
-        while table.cold_tokens(slot) < target:
-            if pool.demote_boundary(slot):
-                self.sim_migration_bytes += pg * self._row_bytes
+        with obs.span("sentinel.demote") as attrs:
+            attrs["pages"] = self._demote_to(
+                slot, self._slot_cold_target(slot, S), pool)
 
     # -------------------------------------------------------------- decode --
     def _pool_decode_step(self):
@@ -273,21 +273,23 @@ class DisaggregatedEngine(ContinuousBatcher):
             view = {"page_table": table_arr[idx], "page_tier": tier_arr[idx],
                     "page_tokens": pg, "active": jnp.asarray(act, bool),
                     "garbage_page": pool.garbage}
-            logits, new_tree, _ = model.forward(
-                self.params_shards[d], self.cfg,
-                {"tokens": self.last_tok[idx][:, None]},
-                caches=pool.forward_tree(stage=pool.has_cold()),
-                cache_index=self.lengths[idx], decode=True, paged_view=view)
+            tokens = {"tokens": self.last_tok[idx][:, None]}
+            caches = pool.forward_tree(stage=pool.has_cold())
+            with obs.span("sentinel.forward", shard=d):
+                logits, new_tree, _ = model.forward(
+                    self.params_shards[d], self.cfg, tokens, caches=caches,
+                    cache_index=self.lengths[idx], decode=True,
+                    paged_view=view)
+            del caches          # the staged cold pools outlive no call
             pool.adopt(new_tree)
             outs.append((idxs, logits))
-        for s in range(self.B):
-            if not self.active[s]:
-                continue
-            pool = self.pools[self.slot_devices[s]]
-            target = self._slot_cold_target(s, self._host_len[s] + 1)
-            while pool.table.cold_tokens(s) < target:
-                if pool.demote_boundary(s):
-                    self.sim_migration_bytes += pg * self._row_bytes
+        with obs.span("sentinel.demote") as attrs:
+            attrs["pages"] = 0
+            for s in range(self.B):
+                if self.active[s]:
+                    attrs["pages"] += self._demote_to(
+                        s, self._slot_cold_target(s, self._host_len[s] + 1),
+                        self.pools[self.slot_devices[s]])
         self._note_tenant_pages()
         tok = jax.device_get(self.last_tok).copy()
         for idxs, logits in outs:
@@ -325,15 +327,14 @@ class DisaggregatedEngine(ContinuousBatcher):
         if tenants:
             self.slot_tenants = list(tenants)
         mig0 = self.sim_migration_bytes
-        for s in range(self.B):
-            if not self.active[s]:
-                continue                       # freed on its next admit
-            pool = self.pools[self.slot_devices[s]]
-            target = self._slot_cold_target(s, self._host_len[s])
-            while pool.table.cold_tokens(s) < target:
-                if pool.demote_boundary(s):
-                    self.sim_migration_bytes += \
-                        self.page_tokens * self._row_bytes
+        # inactive slots are freed on their next admit
+        with obs.span("sentinel.demote") as attrs:
+            attrs["pages"] = 0
+            for s in range(self.B):
+                if self.active[s]:
+                    attrs["pages"] += self._demote_to(
+                        s, self._slot_cold_target(s, self._host_len[s]),
+                        self.pools[self.slot_devices[s]])
         rehome = 0.0
         new_sd = getattr(new_plan, "slot_devices", None)
         if new_sd is not None:

@@ -38,9 +38,16 @@ Three tiered layouts:
 (cold re-hosting), so the layouts' migration traffic is directly comparable
 (benchmarks/bench_serve.py --paged gates paged <= concat; --shared-prefix
 gates shared < unshared).
+
+Every ``step()`` is one tree of ``repro.obs`` spans (``sentinel.step`` over
+``sentinel.admit``, ``sentinel.prefill``, ``sentinel.decode``,
+``sentinel.forward``, ``sentinel.demote``, ``sentinel.sync`` and the page
+manager's ``sentinel.stage_cold`` / ``sentinel.table``), and every finished
+request one ``sentinel.request`` record under the id ``submit`` returned.
 """
 from __future__ import annotations
 
+import time
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, Optional, Sequence
@@ -48,6 +55,7 @@ from typing import Any, Optional, Sequence
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.models import kvcache, model
 
 
@@ -191,6 +199,8 @@ class ContinuousBatcher:
         # FIFO order so cross-tenant arrival order is preserved exactly.
         self._queues: dict = {}
         self._qseq = 0
+        # slot -> [rid, submit, bind, first token (ns), prompt length]
+        self._requests: dict = {}
         # host-side mirrors: per-slot lengths and the active set, kept in
         # lockstep with the device arrays so per-step bookkeeping (page
         # targets, boundary advances) never reads a device array back
@@ -238,7 +248,10 @@ class ContinuousBatcher:
         on the pools layout their common full pages map to the same physical
         pages, refcounted, with copy-on-write past the fork point.
         ``tenant`` restricts admission to the tenant's own slots when the
-        plan carries ``slot_tenants`` (untagged requests admit anywhere)."""
+        plan carries ``slot_tenants`` (untagged requests admit anywhere).
+        Returns the request's id, increasing in submission order: the
+        ``rid`` of its ``sentinel.prefill`` spans and ``sentinel.request``
+        record."""
         if tenant is not None and self.slot_tenants and \
                 tenant not in self.slot_tenants:
             # an unknown tag would never match a slot: the request would sit
@@ -248,9 +261,12 @@ class ContinuousBatcher:
         # host mirror cached once here: admissions (LCP against the donor)
         # and the chunker never re-run jax.device_get on the prompt
         tok_host = tuple(int(t) for t in jax.device_get(tokens))
+        rid = self._qseq
         self._queues.setdefault(tenant, deque()).append(
-            (self._qseq, tokens, tok_host, num_tokens, prefix_key, tenant))
+            (rid, tokens, tok_host, num_tokens, prefix_key, tenant,
+             time.perf_counter_ns()))
         self._qseq += 1
+        return rid
 
     @property
     def queue(self) -> list:
@@ -260,7 +276,7 @@ class ContinuousBatcher:
         per-tenant deques directly."""
         items = sorted((it for q in self._queues.values() for it in q),
                        key=lambda it: it[0])
-        return [(t, n, pk, tn) for _, t, _, n, pk, tn in items]
+        return [(t, n, pk, tn) for _, t, _, n, pk, tn, _ in items]
 
     def _slot_tenant(self, slot: int):
         return self.slot_tenants[slot] if self.slot_tenants else None
@@ -354,10 +370,21 @@ class ContinuousBatcher:
         self.pool.splice_other(fresh, slot)
         # cold boundary: demote page by page toward the plan's target (shared
         # pages already cold, or deduped through a twin, move zero bytes)
-        target = self._slot_cold_target(slot, S)
-        while self.ptable.cold_tokens(slot) < target:
-            if self.pool.demote_boundary(slot):
-                self.sim_migration_bytes += pg * self._row_bytes
+        with obs.span("sentinel.demote") as attrs:
+            attrs["pages"] = self._demote_to(
+                slot, self._slot_cold_target(slot, S))
+
+    def _demote_to(self, slot: int, target: int, pool=None) -> int:
+        """Advance ``slot``'s cold boundary page by page to ``target``
+        tokens in ``pool`` (default: the engine's own).  Returns the pages
+        whose data moved; twin-deduped shared pages move none."""
+        pool = pool or self.pool
+        copied = 0
+        while pool.table.cold_tokens(slot) < target:
+            if pool.demote_boundary(slot):
+                copied += 1
+                self.sim_migration_bytes += self.page_tokens * self._row_bytes
+        return copied
 
     def _alloc_admit_pages(self, slot: int, n: int) -> None:
         """Grow ``slot`` to ``n`` hot pages for an admission.  The seam the
@@ -382,8 +409,10 @@ class ContinuousBatcher:
             item = self._pop_for_slot(slot)
             if item is None:
                 continue                   # no queued request for this tenant
-            _, tokens, tok_host, budget, prefix_key, tenant = item
+            rid, tokens, tok_host, budget, prefix_key, tenant, t_sub = item
             S = int(tokens.shape[-1])
+            self._requests[slot] = [rid, t_sub, time.perf_counter_ns(), None,
+                                    S]
             if self._pool_prefill_ok:
                 self._jobs[slot] = _PrefillJob(tokens, tok_host, S, budget,
                                                prefix_key, tenant)
@@ -398,32 +427,34 @@ class ContinuousBatcher:
         """Legacy one-shot admission: dense full-prompt prefill, then a
         layout-specific cache splice (async dispatch: overlaps with
         in-flight decode work)."""
-        last, fresh = self._prefill(self.params,
-                                    {"tokens": tokens[None]})
-        if self.pool is not None:
-            self._admit_pool(slot, tok_host, fresh, S, prefix_key)
-        elif self.paged is not None:
-            # per-slot boundary: only THIS slot's cold pages are re-hosted
-            cold = self._slot_cold_target(slot, S)
-            self.ptable.splice_slot(slot, S, cold)
-            self.paged.hot = kvcache.splice_slot(self.paged.hot, fresh,
-                                                 slot, self.B)
-            self.paged.set_boundary(slot, 0)
-            if cold:
-                self.paged.demote_rows(slot, cold)
-            self.sim_migration_bytes += cold * self._row_bytes
-        elif self.tiered is not None:
-            fc, fh = kvcache.split_seq_cache(fresh, self.max_seq,
-                                             self.cold_len)
-            self.tiered.cold = kvcache.to_host(kvcache.splice_slot(
-                kvcache.to_device(self.tiered.cold), fc, slot, self.B))
-            self.tiered.hot = kvcache.splice_slot(
-                self.tiered.hot, fh, slot, self.B)
-            # global boundary: the full cold prefix re-hosts on refill
-            self.sim_migration_bytes += self.cold_len * self._row_bytes
-        else:
-            self.caches = kvcache.splice_slot(self.caches, fresh, slot,
-                                              self.B)
+        with obs.span("sentinel.prefill", rid=self._requests[slot][0],
+                      slot=slot, start=0, end=S):
+            last, fresh = self._prefill(self.params,
+                                        {"tokens": tokens[None]})
+            if self.pool is not None:
+                self._admit_pool(slot, tok_host, fresh, S, prefix_key)
+            elif self.paged is not None:
+                # per-slot boundary: only THIS slot's cold pages re-host
+                cold = self._slot_cold_target(slot, S)
+                self.ptable.splice_slot(slot, S, cold)
+                self.paged.hot = kvcache.splice_slot(self.paged.hot, fresh,
+                                                     slot, self.B)
+                self.paged.set_boundary(slot, 0)
+                if cold:
+                    self.paged.demote_rows(slot, cold)
+                self.sim_migration_bytes += cold * self._row_bytes
+            elif self.tiered is not None:
+                fc, fh = kvcache.split_seq_cache(fresh, self.max_seq,
+                                                 self.cold_len)
+                self.tiered.cold = kvcache.to_host(kvcache.splice_slot(
+                    kvcache.to_device(self.tiered.cold), fc, slot, self.B))
+                self.tiered.hot = kvcache.splice_slot(
+                    self.tiered.hot, fh, slot, self.B)
+                # global boundary: the full cold prefix re-hosts on refill
+                self.sim_migration_bytes += self.cold_len * self._row_bytes
+            else:
+                self.caches = kvcache.splice_slot(self.caches, fresh, slot,
+                                                  self.B)
         self.prefill_compute_tokens += S
         self._step_prefill += S
         self._activate(slot, S, last, budget)
@@ -438,6 +469,7 @@ class ContinuousBatcher:
         self.active[slot] = True
         self.budget[slot] = budget
         self.outputs[slot] = [int(self.last_tok[slot])]
+        self._requests[slot][3] = time.perf_counter_ns()
         self.budget[slot] -= 1
         self._refresh_active()
         self._note_tenant_pages()
@@ -512,22 +544,25 @@ class ContinuousBatcher:
         the rows are bit-identical to the same rows of a one-shot dense
         prefill."""
         pg = self.page_tokens
-        first = self.ptable.n_pages[slot]
-        n = -(-end // pg)
-        self._alloc_admit_pages(slot, n)
-        self.pool.stats["admit_page_writes"] += n - first
-        table, tier = self.pool.arrays()
-        view = {"page_table": table[slot][None], "page_tier": tier[slot][None],
-                "page_tokens": pg, "active": None, "prefill": True}
-        # the chunk reads this slot's row only: stage the cold tier iff the
-        # row maps a cold page (a shared prefix the donor already demoted)
-        caches = self.pool.forward_tree(
-            stage=self.ptable.cold_pages(slot) > 0)
-        job.last, new_tree = model.prefill_suffix(
-            self.params, self.cfg,
-            {"tokens": job.tokens[job.done:end][None]},
-            caches=caches, start=job.done, paged_view=view)
-        self.pool.adopt(new_tree)
+        with obs.span("sentinel.prefill", rid=self._requests[slot][0],
+                      slot=slot, start=job.done, end=end):
+            first = self.ptable.n_pages[slot]
+            n = -(-end // pg)
+            self._alloc_admit_pages(slot, n)
+            self.pool.stats["admit_page_writes"] += n - first
+            table, tier = self.pool.arrays()
+            view = {"page_table": table[slot][None],
+                    "page_tier": tier[slot][None], "page_tokens": pg,
+                    "active": None, "prefill": True}
+            # the chunk reads this slot's row only: stage the cold tier iff
+            # the row maps a cold page (a shared prefix the donor demoted)
+            caches = self.pool.forward_tree(
+                stage=self.ptable.cold_pages(slot) > 0)
+            job.last, new_tree = model.prefill_suffix(
+                self.params, self.cfg,
+                {"tokens": job.tokens[job.done:end][None]},
+                caches=caches, start=job.done, paged_view=view)
+            self.pool.adopt(new_tree)
         self.prefill_compute_tokens += end - job.done
         self._step_prefill += end - job.done
         job.done = end
@@ -537,10 +572,9 @@ class ContinuousBatcher:
         *current* plan's target (re-plans adopted mid-prefill land here) and
         flip the slot active."""
         del self._jobs[slot]
-        target = self._slot_cold_target(slot, job.S)
-        while self.ptable.cold_tokens(slot) < target:
-            if self.pool.demote_boundary(slot):
-                self.sim_migration_bytes += self.page_tokens * self._row_bytes
+        with obs.span("sentinel.demote") as attrs:
+            attrs["pages"] = self._demote_to(
+                slot, self._slot_cold_target(slot, job.S))
         self._activate(slot, job.S, job.last, job.budget)
 
     def _pool_decode_step(self):
@@ -556,21 +590,21 @@ class ContinuousBatcher:
             if self.active[s]:
                 self.pool.ensure_write_page(s, self._host_len[s])
         paged_view = self.pool.paged_view(self._active_mask)
-        logits, new_caches, _ = model.forward(
-            self.params, self.cfg, {"tokens": self.last_tok[:, None]},
-            caches=self.pool.forward_tree(stage=self.pool.has_cold()),
-            cache_index=self.lengths, decode=True, paged_view=paged_view)
+        tokens = {"tokens": self.last_tok[:, None]}
+        caches = self.pool.forward_tree(stage=self.pool.has_cold())
+        with obs.span("sentinel.forward"):
+            logits, new_caches, _ = model.forward(
+                self.params, self.cfg, tokens, caches=caches,
+                cache_index=self.lengths, decode=True, paged_view=paged_view)
+        del caches              # the staged cold pools outlive no call
         self.pool.adopt(new_caches)
         # advance each grown slot's own cold boundary by whole pages;
         # twin-deduped shared pages advance the boundary with zero copy
-        for s in range(self.B):
-            if not self.active[s]:
-                continue
-            target = self._slot_cold_target(s, self._host_len[s] + 1)
-            while self.ptable.cold_tokens(s) < target:
-                if self.pool.demote_boundary(s):
-                    self.sim_migration_bytes += \
-                        self.page_tokens * self._row_bytes
+        with obs.span("sentinel.demote") as attrs:
+            attrs["pages"] = sum(
+                self._demote_to(s, self._slot_cold_target(
+                    s, self._host_len[s] + 1))
+                for s in range(self.B) if self.active[s])
         self._note_tenant_pages()
         return jnp.argmax(logits[:, -1, :self.cfg.vocab_size], axis=-1) \
             .astype(jnp.int32)
@@ -584,9 +618,17 @@ class ContinuousBatcher:
         pools, the page-table arrays are cached until the table mutates, and
         all boundary/length bookkeeping runs on host-side mirrors.  Layout
         work happens only at events (admit, a slot growing into a new page,
-        a boundary advance)."""
+        a boundary advance).  The step is one ``sentinel.step`` span."""
+        with obs.span("sentinel.step",
+                      step=len(self.step_prefill_tokens)) as attrs:
+            return self._step(attrs)
+
+    def _step(self, attrs: dict):
         self._step_prefill = 0
-        self._admit()
+        with obs.span("sentinel.admit"):
+            self._admit()
+        attrs.update(active=sum(self.active),
+                     prefill_tokens=self._step_prefill)
         if not any(self.active):
             if self._jobs:
                 # prefill-only step: the chunk budget ran but no slot is
@@ -598,58 +640,13 @@ class ContinuousBatcher:
                 self._mig_accounted = self.sim_migration_bytes
                 return True
             return False
-        if self.pool is not None:
-            tok = self._pool_decode_step()
-        else:
-            if self.paged is not None:
-                caches = self.paged.merged()
-            elif self.tiered is not None:
-                caches = self.tiered.merged()
-            else:
-                caches = self.caches
-            logits, new_caches, _ = model.forward(
-                self.params, self.cfg, {"tokens": self.last_tok[:, None]},
-                caches=caches, cache_index=self.lengths, decode=True)
-            if self.paged is not None:
-                self.paged.hot = new_caches
-                # advance each active slot's own boundary: when the new
-                # length pushes a page out of the slot's hot window, demote
-                # just that page (hot -> cold pool in the table, rows
-                # re-hosted)
-                for s in range(self.B):
-                    if not self.active[s]:
-                        continue
-                    new_len = self._host_len[s] + 1
-                    while self.ptable.n_pages[s] * self.page_tokens < new_len:
-                        self.ptable.alloc(s, 0)    # decode grew into a new page
-                    target = self._slot_cold_target(s, new_len)
-                    moved = self.paged.demote_rows(s, target)
-                    while self.ptable.cold_tokens(s) < target:
-                        self.ptable.demote(s, self.ptable.cold_pages(s))
-                    self.sim_migration_bytes += moved * self._row_bytes
-                self._note_tenant_pages()
-            elif self.tiered is not None:
-                _, hot = kvcache.split_seq_cache(new_caches, self.max_seq,
-                                                 self.cold_len)
-                self.tiered.hot = hot
-                # this step's KV writes land at each slot's length; a write
-                # inside the prefix (short slots) re-hosts only that slot's
-                # row, not a re-split of the whole batch cache
-                for s in range(self.B):
-                    if self.active[s] and self._host_len[s] < self.cold_len:
-                        pos = self._host_len[s]
-                        self.tiered.cold = kvcache.to_host(
-                            kvcache.copy_slot_rows(
-                                kvcache.to_device(self.tiered.cold),
-                                new_caches, s, pos, pos + 1, self.max_seq))
-                        self.sim_migration_bytes += self._row_bytes
-            else:
-                self.caches = new_caches
-            tok = jnp.argmax(logits[:, -1, :self.cfg.vocab_size], axis=-1) \
-                .astype(jnp.int32)
+        with obs.span("sentinel.decode"):
+            tok = self._pool_decode_step() if self.pool is not None \
+                else self._dense_decode_step()
         self.last_tok = tok
         self.lengths = self.lengths + self._active_inc
-        tok_host = jax.device_get(tok)         # the decoded tokens themselves
+        with obs.span("sentinel.sync"):
+            tok_host = jax.device_get(tok)     # the decoded tokens themselves
         was_active = list(self.active)
         for slot in range(self.B):
             if not was_active[slot]:
@@ -660,6 +657,7 @@ class ContinuousBatcher:
             if self.budget[slot] <= 0 or \
                     int(tok_host[slot]) == self.scfg.eos_id:
                 self.active[slot] = False
+                self._finish_request(slot)
         if self.active != was_active:
             self._refresh_active()
         self.step_prefill_tokens.append(self._step_prefill)
@@ -667,6 +665,65 @@ class ContinuousBatcher:
             self.sim_migration_bytes - self._mig_accounted)
         self._mig_accounted = self.sim_migration_bytes
         return True
+
+    def _finish_request(self, slot: int):
+        """The slot's request delivered its last token: one
+        ``sentinel.request`` record from submission to now."""
+        rid, t_submit, t_bind, t_first, S = self._requests.pop(slot)
+        obs.add("sentinel.request", t_submit, time.perf_counter_ns(),
+                rid=rid, slot=slot, bind_ns=t_bind, first_token_ns=t_first,
+                prompt_tokens=S)
+
+    def _dense_decode_step(self):
+        """One decode forward on the dense, concat or paged layouts (every
+        layout but the persistent pools).  Returns the decoded tokens."""
+        if self.paged is not None:
+            caches = self.paged.merged()
+        elif self.tiered is not None:
+            caches = self.tiered.merged()
+        else:
+            caches = self.caches
+        with obs.span("sentinel.forward"):
+            logits, new_caches, _ = model.forward(
+                self.params, self.cfg, {"tokens": self.last_tok[:, None]},
+                caches=caches, cache_index=self.lengths, decode=True)
+        if self.paged is not None:
+            self.paged.hot = new_caches
+            # advance each active slot's own boundary: when the new
+            # length pushes a page out of the slot's hot window, demote
+            # just that page (hot -> cold pool in the table, rows
+            # re-hosted)
+            for s in range(self.B):
+                if not self.active[s]:
+                    continue
+                new_len = self._host_len[s] + 1
+                while self.ptable.n_pages[s] * self.page_tokens < new_len:
+                    self.ptable.alloc(s, 0)    # decode grew into a new page
+                target = self._slot_cold_target(s, new_len)
+                moved = self.paged.demote_rows(s, target)
+                while self.ptable.cold_tokens(s) < target:
+                    self.ptable.demote(s, self.ptable.cold_pages(s))
+                self.sim_migration_bytes += moved * self._row_bytes
+            self._note_tenant_pages()
+        elif self.tiered is not None:
+            _, hot = kvcache.split_seq_cache(new_caches, self.max_seq,
+                                             self.cold_len)
+            self.tiered.hot = hot
+            # this step's KV writes land at each slot's length; a write
+            # inside the prefix (short slots) re-hosts only that slot's
+            # row, not a re-split of the whole batch cache
+            for s in range(self.B):
+                if self.active[s] and self._host_len[s] < self.cold_len:
+                    pos = self._host_len[s]
+                    self.tiered.cold = kvcache.to_host(
+                        kvcache.copy_slot_rows(
+                            kvcache.to_device(self.tiered.cold),
+                            new_caches, s, pos, pos + 1, self.max_seq))
+                    self.sim_migration_bytes += self._row_bytes
+        else:
+            self.caches = new_caches
+        return jnp.argmax(logits[:, -1, :self.cfg.vocab_size], axis=-1) \
+            .astype(jnp.int32)
 
     def apply_plan(self, new_plan):
         """Adopt a re-plan (or an incremental ``runtime.PlanDelta``) on the
@@ -704,14 +761,12 @@ class ContinuousBatcher:
         if tenants:
             self.slot_tenants = list(tenants)
         mig0 = self.sim_migration_bytes
-        for s in range(self.B):
-            if not self.active[s]:
-                continue                       # freed on its next admit
-            target = self._slot_cold_target(s, self._host_len[s])
-            while self.ptable.cold_tokens(s) < target:
-                if self.pool.demote_boundary(s):
-                    self.sim_migration_bytes += \
-                        self.page_tokens * self._row_bytes
+        # inactive slots are freed on their next admit
+        with obs.span("sentinel.demote") as attrs:
+            attrs["pages"] = sum(
+                self._demote_to(s, self._slot_cold_target(
+                    s, self._host_len[s]))
+                for s in range(self.B) if self.active[s])
         # tenancy may have moved without a table event — force a resample
         self._tenant_note_version = -1
         self._note_tenant_pages()
