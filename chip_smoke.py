@@ -53,21 +53,11 @@ def pool_placement(pool) -> set:
 
 
 def decode_step_text(b) -> str:
-    """StableHLO of one decode forward as the engine runs it (lowered, not
-    run), to show which kernel the step calls."""
-    from repro.models import model
-    pv = b.pool.paged_view(b._active_mask)
-    static = {k: pv[k] for k in ("page_tokens", "garbage_page")}
-    arrays = {k: pv[k] for k in ("page_table", "page_tier", "active")}
-
-    def step(params, tok, caches, lengths, arrays):
-        return model.forward(params, b.cfg, {"tokens": tok}, caches=caches,
-                             cache_index=lengths, decode=True,
-                             paged_view={**arrays, **static})[0]
-
+    """StableHLO of the engine's compiled decode step (lowered, not run),
+    to show which kernel the step calls."""
     caches = b.pool.forward_tree(stage=b.pool.has_cold())
-    return jax.jit(step).lower(b.params, b.last_tok[:, None], caches,
-                               b.lengths, arrays).as_text()
+    return b._decode_step.lower(b.params, b.last_tok, caches, b.lengths,
+                                *b.pool.arrays(), b._active_mask).as_text()
 
 
 def serve(make, label):

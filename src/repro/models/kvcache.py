@@ -909,8 +909,9 @@ class PagedKVPools:
         assert max_seq % page_tokens == 0, (max_seq, page_tokens)
         # the device that holds the hot pools and whose host memory holds
         # the cold ones (None: the default device) — a sharded engine gives
-        # each shard's pool its own device
-        self.device = device
+        # each shard's pool its own device.  Every array is committed to it,
+        # so a compiled step sees one placement from its first call on
+        self.device = device if device is not None else jax.devices()[0]
         self.cfg, self.num_slots = cfg, slots
         self.max_seq, self.page_tokens = max_seq, page_tokens
         self.num_pages = max_seq // page_tokens
@@ -935,11 +936,11 @@ class PagedKVPools:
         if kind not in ATTN_KINDS:
             one = init_layer_cache(self.cfg, kind, self.num_slots,
                                    self.max_seq, dtype)
-            if not lead:
-                return one
-            return jax.tree.map(
-                lambda a: jnp.broadcast_to(a[None], lead + a.shape).copy(),
-                one)
+            if lead:
+                one = jax.tree.map(
+                    lambda a: jnp.broadcast_to(a[None], lead + a.shape)
+                    .copy(), one)
+            return jax.device_put(one, self.device)
 
         def hot():
             return jnp.zeros(lead + (self.table.n_hot + 1,) + self.page_shape,

@@ -60,10 +60,11 @@ from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro import obs
 from repro.launch.mesh import disagg_groups
-from repro.models import kvcache, model
+from repro.models import kvcache
 from repro.runtime.plan import validate_slot_devices
 from repro.runtime.policies import simulate
 from repro.runtime.tiergraph import TierGraph
@@ -162,8 +163,6 @@ class DisaggregatedEngine(ContinuousBatcher):
         base_prefill = self._prefill           # the jitted model.prefill
         self.tp_prefill = bool(tp_prefill) and len(self.prefill_devices) > 1
         if self.tp_prefill:
-            import numpy as np
-
             from repro import sharding as shd
             from repro.launch import specs
             pmesh = jax.sharding.Mesh(np.asarray(self.prefill_devices),
@@ -257,7 +256,6 @@ class DisaggregatedEngine(ContinuousBatcher):
     def _pool_decode_step(self):
         if self.n_shards == 1:
             return super()._pool_decode_step()
-        pg = self.page_tokens
         outs = []
         for d in range(self.n_shards):
             pool = self.pools[d]
@@ -269,20 +267,19 @@ class DisaggregatedEngine(ContinuousBatcher):
                 if self.active[s]:
                     pool.ensure_write_page(s, self._host_len[s])
             table_arr, tier_arr = pool.arrays()
-            idx = jnp.asarray(idxs, jnp.int32)
-            view = {"page_table": table_arr[idx], "page_tier": tier_arr[idx],
-                    "page_tokens": pg, "active": jnp.asarray(act, bool),
-                    "garbage_page": pool.garbage}
-            tokens = {"tokens": self.last_tok[idx][:, None]}
+            idx = np.asarray(idxs)
+            # the shard's sub-batch, committed to its device: the one
+            # compiled step builds once per device
+            last_tok, lengths, table, tier, active = jax.device_put(
+                (self.last_tok[idx], self.lengths[idx], table_arr[idx],
+                 tier_arr[idx], np.asarray(act)), pool.device)
             caches = pool.forward_tree(stage=pool.has_cold())
             with obs.span("sentinel.forward", shard=d):
-                logits, new_tree, _ = model.forward(
-                    self.params_shards[d], self.cfg, tokens, caches=caches,
-                    cache_index=self.lengths[idx], decode=True,
-                    paged_view=view)
-            del caches          # the staged cold pools outlive no call
+                tok, new_tree = self._decode_step(
+                    self.params_shards[d], last_tok, caches, lengths, table,
+                    tier, active)
             pool.adopt(new_tree)
-            outs.append((idxs, logits))
+            outs.append((idxs, tok))
         with obs.span("sentinel.demote") as attrs:
             attrs["pages"] = 0
             for s in range(self.B):
@@ -292,9 +289,7 @@ class DisaggregatedEngine(ContinuousBatcher):
                         self.pools[self.slot_devices[s]])
         self._note_tenant_pages()
         tok = jax.device_get(self.last_tok).copy()
-        for idxs, logits in outs:
-            td = jax.device_get(jnp.argmax(
-                logits[:, -1, :self.cfg.vocab_size], axis=-1))
+        for idxs, td in jax.device_get(outs):
             for i, s in enumerate(idxs):
                 tok[s] = int(td[i])
         return jnp.asarray(tok, jnp.int32)

@@ -99,6 +99,29 @@ def make_serve_step(cfg):
     return jax.jit(step, donate_argnums=(2,))
 
 
+def make_pool_decode_step(cfg, page_tokens: int, garbage_page: int):
+    """The decode step on the persistent page pools as one compiled program:
+    ``(params, last_tok (B,), caches, lengths (B,), page_table, page_tier,
+    active (B,)) -> (next_tok int32 (B,), new hot-pool tree)``.
+
+    ``caches`` is ``PagedKVPools.forward_tree``: the hot pools, plus the
+    cold pools staged into HBM when any page is cold (two variants of the
+    program).  It is donated, so the hot pools are updated in place and the
+    staged copies die with the call.  The page geometry is static; the
+    greedy token is taken inside, so the logits never leave the program."""
+    def step(params, last_tok, caches, lengths, page_table, page_tier,
+             active):
+        view = {"page_table": page_table, "page_tier": page_tier,
+                "page_tokens": page_tokens, "active": active,
+                "garbage_page": garbage_page}
+        logits, new_caches, _ = model.forward(
+            params, cfg, {"tokens": last_tok[:, None]}, caches=caches,
+            cache_index=lengths, decode=True, paged_view=view)
+        tok = jnp.argmax(logits[:, -1, :cfg.vocab_size], axis=-1)
+        return tok.astype(jnp.int32), new_caches
+    return jax.jit(step, donate_argnums=(2,))
+
+
 def sample(logits, key, temperature: float):
     if temperature <= 0.0:
         return jnp.argmax(logits, axis=-1)
@@ -178,6 +201,8 @@ class ContinuousBatcher:
                 self.pool = kvcache.PagedKVPools(cfg, batch_slots, max_seq,
                                                  page, dt, device=device)
                 self.ptable = self.pool.table
+                self._decode_step = make_pool_decode_step(
+                    cfg, page, self.pool.garbage)
             else:
                 self.paged = kvcache.init_paged_cache(cfg, batch_slots,
                                                       max_seq, page, dt)
@@ -589,14 +614,12 @@ class ContinuousBatcher:
         for s in range(self.B):
             if self.active[s]:
                 self.pool.ensure_write_page(s, self._host_len[s])
-        paged_view = self.pool.paged_view(self._active_mask)
-        tokens = {"tokens": self.last_tok[:, None]}
+        table, tier = self.pool.arrays()
         caches = self.pool.forward_tree(stage=self.pool.has_cold())
         with obs.span("sentinel.forward"):
-            logits, new_caches, _ = model.forward(
-                self.params, self.cfg, tokens, caches=caches,
-                cache_index=self.lengths, decode=True, paged_view=paged_view)
-        del caches              # the staged cold pools outlive no call
+            tok, new_caches = self._decode_step(
+                self.params, self.last_tok, caches, self.lengths, table, tier,
+                self._active_mask)
         self.pool.adopt(new_caches)
         # advance each grown slot's own cold boundary by whole pages;
         # twin-deduped shared pages advance the boundary with zero copy
@@ -606,8 +629,7 @@ class ContinuousBatcher:
                     s, self._host_len[s] + 1))
                 for s in range(self.B) if self.active[s])
         self._note_tenant_pages()
-        return jnp.argmax(logits[:, -1, :self.cfg.vocab_size], axis=-1) \
-            .astype(jnp.int32)
+        return tok
 
     def step(self):
         """One lockstep decode step across all active slots — each slot writes

@@ -118,6 +118,68 @@ def test_requests_match_the_engine(served):
                    for p in prefill)
 
 
+def test_compiled_decode_builds_nothing_after_warm_up(setup):
+    """Past a warm-up, the compiled decode step is built or loaded on no
+    step, through admissions, page-boundary demotions and a copy-on-write
+    of a shared prefix page: one program in all.  Each step donates the hot
+    pools it was handed, and no later work reads them."""
+    cfg, params = setup
+    max_seq, page = 32, 4
+    trace = engine.serve_trace_for(get_config("smollm-360m"),
+                                   [(7, 6), (9, 5)], slots=2, layer_group=8)
+    plan = dataclasses.replace(
+        runtime.plan(trace, TPU_V5E, 0.2 * trace.peak_kv_bytes()),
+        hot_window=4, slot_hot_windows=[4, 4], page_tokens=page)
+    b = engine.ContinuousBatcher(params, cfg, 2, max_seq, plan=plan,
+                                 paged=True)
+    handed = []                    # the hot pools of every decode call
+    compiled = b._decode_step
+
+    def decode_step(params, last_tok, caches, *rest):
+        handed.append([e[k] for e in caches["slots"]
+                       for k in ("k_hot", "v_hot")])
+        return compiled(params, last_tok, caches, *rest)
+    b._decode_step = decode_step
+
+    t_warm = time.perf_counter_ns()
+    b.submit(jnp.arange(9, dtype=jnp.int32), 3)           # warm-up
+    while b.queue or b._jobs or any(b.active):
+        assert b.step()
+    assert b.pool.has_cold()
+    warm = len(handed)
+
+    t0 = time.perf_counter_ns()
+    system = jnp.arange(20, 29, dtype=jnp.int32) % cfg.vocab_size
+    for n, budget in ((2, 6), (3, 5), (1, 4)):
+        b.submit(jnp.concatenate([system, jnp.arange(n, dtype=jnp.int32)]),
+                 budget, prefix_key="system")
+    steps = 0
+    while b.queue or b._jobs or any(b.active):
+        if steps == 1:
+            # the sharer's first page is the donor's, demoted and shared
+            assert b.ptable.is_shared(1, 0)
+            copies = b.pool.stats["page_copies"]
+            assert b.pool.cow_for_write(1, 0)
+            assert b.pool.stats["page_copies"] == copies + 1
+        assert b.step()
+        steps += 1
+        assert all(a.is_deleted() for a in handed[-1])
+        assert not any(a.is_deleted() for _, e in b.pool._attn_entries()
+                       for a in e.values())
+    assert len(handed) == warm + steps
+    recs = obs.records(t0, time.perf_counter_ns())
+    forwards = [r for r in recs if r.name == "sentinel.forward"]
+    assert len(forwards) == steps
+    assert [r.built + r.loaded for r in forwards] == [0] * steps
+    assert sum(r.name == "sentinel.request" for r in recs) == 3
+    # one program in all: every step had staged cold pages, and the
+    # inputs kept one placement from the first call on
+    assert sum(r.built + r.loaded for r in obs.records(t_warm)
+               if r.name == "sentinel.forward") == 1
+    assert sum(r.attrs["pages"] for r in recs
+               if r.name == "sentinel.demote") > 0
+
+
 def test_ring_stays_at_its_bound():
     first = obs.add("probe.first", 0, 1)
     for _ in range(obs.RING_RECORDS):

@@ -127,15 +127,16 @@ def test_cold_page_ops_compile_for_v5e(compile_for, shapes, chip, op):
     assert compiled.as_text()
 
 
-def test_decode_step_compiles_at_published_width(compile_for, shapes,
-                                                 monkeypatch):
-    """One whole decode forward of smollm-360m through the page pools, with
-    the compiled kernel (steered in this test: the CPU backend would pick
-    the jnp reference)."""
+@pytest.mark.usefixtures("compile_for")     # for its persistent cache off
+def test_decode_step_compiles_at_published_width(shapes, monkeypatch):
+    """The engine's compiled decode step of smollm-360m through the page
+    pools, hot pools donated, with the compiled kernel (steered in this
+    test: the CPU backend would pick the jnp reference)."""
     from repro.configs.base import get_config
     from repro.kernels import ops
     from repro.models import model
     from repro.models.layers import split_params
+    from repro.serve import engine
     sds, _ = shapes
     monkeypatch.setitem(ops._FORCE, "pallas", True)
     monkeypatch.setattr(ops, "_interpret", lambda: False)
@@ -149,14 +150,11 @@ def test_decode_step_compiles_at_published_width(compile_for, shapes,
     caches = {"prologue": [], "slots": [{"k_hot": hot, "v_hot": hot,
                                          "k_cold": cold, "v_cold": cold}]}
 
-    def step(params, tok, caches, lengths, table, tier, active):
-        pv = {"page_table": table, "page_tier": tier, "active": active,
-              "page_tokens": PAGE, "garbage_page": N}
-        return model.forward(params, cfg, {"tokens": tok}, caches=caches,
-                             cache_index=lengths, decode=True,
-                             paged_view=pv)[:2]
-
-    compiled = compile_for(step, params, sds((B, 1), jnp.int32), caches,
-                           sds((B,), jnp.int32), sds((B, NP), jnp.int32),
-                           sds((B, NP), jnp.int32), sds((B,), jnp.bool_))
-    assert "tpu_custom_call" in compiled.as_text()
+    step = engine.make_pool_decode_step(cfg, PAGE, N)
+    compiled = step.lower(params, sds((B,), jnp.int32), caches,
+                          sds((B,), jnp.int32), sds((B, NP), jnp.int32),
+                          sds((B, NP), jnp.int32),
+                          sds((B,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "input_output_alias" in text        # the hot pools, in place
