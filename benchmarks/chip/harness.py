@@ -6,11 +6,12 @@ The system under test is the program's tiered serving path as
 the persistent page pools (``paged=True``, ``use_paged_decode=True``) with
 a plan from ``runtime.plan`` over ``engine.serve_trace_for`` of a small
 profiling sample.  The measured window drives ``ContinuousBatcher.step()``
-and nothing else of the program.
+and nothing else of the program.  The configuration's family module
+(``families/<family>.py``, which ``spec`` loads) gives the program's
+configuration, the seeded weights and the plain reference.
 """
 from __future__ import annotations
 
-import dataclasses
 import gc
 import json
 import shutil
@@ -19,12 +20,13 @@ import tempfile
 import time
 from collections import deque
 from dataclasses import dataclass, field
+from types import ModuleType
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmarks.chip import reference, spec, traffic, weights
+from benchmarks.chip import reference, spec, traffic
 from benchmarks.chip import trace as xtrace
 from benchmarks.chip.compiles import Compiles
 
@@ -32,13 +34,6 @@ REHEARSAL_SCALE = (16, 4)       # prompt and output lengths divided by these
 REHEARSAL_MAX_SEQ = 256
 SERVED_TOKENS_COMPARED = 300    # the correctness sample's size, at least
 MAX_REQUESTS_COMPARED = 8
-
-HF_KEYS = {"hidden_size": "d_model", "intermediate_size": "d_ff",
-           "num_hidden_layers": "num_layers",
-           "num_attention_heads": "num_heads",
-           "num_key_value_heads": "num_kv_heads", "vocab_size": "vocab_size",
-           "rope_theta": "rope_theta",
-           "tie_word_embeddings": "tie_embeddings", "hidden_act": "act"}
 
 
 def log(msg: str) -> None:
@@ -74,7 +69,8 @@ class Step:
 @dataclass
 class Run:
     """What a metric reader reads."""
-    shape: weights.Shape
+    family: ModuleType          # families/<family>.py of the configuration
+    shape: object               # the family's Shape
     peak: dict                  # the device's peaks (peaks.json), or None
     setup_s: float
     t_open: float
@@ -185,27 +181,6 @@ class ClosedLoop:
 
 # ------------------------------------------------------------- set-up ----
 
-def program_config(cell: spec.Cell, rehearse: bool):
-    """The program's registered configuration, checked key by key against
-    the cell's configuration file (which holds it as it is run), with the
-    file's ``rms_norm_eps``, a setting the registry leaves at its default."""
-    from repro.configs.base import get_config
-    c = cell.config
-    cfg = get_config(c["arch"])
-    for key, attr in HF_KEYS.items():
-        if key in c and c[key] != getattr(cfg, attr):
-            raise SystemExit(f"{cell.config_name}: {key}={c[key]!r} but the "
-                             f"program runs {attr}={getattr(cfg, attr)!r}")
-    if cfg.head_dim != c["hidden_size"] // c["num_attention_heads"]:
-        raise SystemExit(f"{cell.config_name}: head_dim {cfg.head_dim}")
-    if cfg.dtype != c["serving"]["dtype"]:
-        raise SystemExit(f"{cell.config_name}: dtype {cfg.dtype}")
-    cfg = dataclasses.replace(cfg, norm_eps=float(c["rms_norm_eps"]))
-    if rehearse:
-        cfg = dataclasses.replace(cfg.reduced(), dtype=cfg.dtype)
-    return cfg
-
-
 def instrument() -> None:
     """Host spans around the program's layers, for a traced run's gap
     attribution: wrappers installed from here, the program unchanged."""
@@ -241,8 +216,8 @@ def peaks_for(kind: str) -> dict:
 
 # ------------------------------------------------------- correctness ----
 
-def compare(shape, seed: int, finished: list, length: int, n_pos: int,
-            control: bool = False) -> dict:
+def compare(family: ModuleType, shape, seed: int, finished: list,
+            length: int, n_pos: int, control: bool = False) -> dict:
     """The widest gap by which a served token's logit lies below the
     reference's best, over a seeded sample of finished requests that holds
     the longest; with ``control`` also that of the token the fp8 control
@@ -262,16 +237,16 @@ def compare(shape, seed: int, finished: list, length: int, n_pos: int,
             break
         picked.append(rest[i])
         n += len(rest[i].out)
-    w = weights.make(shape, seed)
+    w = family.make(shape, seed)
     worst, worst_c = 0.0, 0.0
     for r in picked:
         seq = np.concatenate([r.prompt, np.asarray(r.out[:-1], np.int32)])
         pos = r.prompt_len - 1 + np.arange(len(r.out))
-        ref = reference.logits_at(shape, w, seq, pos, length, n_pos)
+        ref = family.logits_at(shape, w, seq, pos, length, n_pos)
         worst = max(worst, float(reference.gaps(ref, r.out).max()))
         if control:
-            c = reference.logits_at(shape, w, seq, pos, length, n_pos,
-                                    fp8=True)
+            c = family.logits_at(shape, w, seq, pos, length, n_pos,
+                                 fp8=True)
             worst_c = max(worst_c,
                           float(reference.gaps(ref, c.argmax(-1)).max()))
     out.update(requests_compared=len(picked), served_tokens_compared=n,
@@ -304,8 +279,9 @@ def run(args, t_start: float, compiles: Compiles = None,
     compiles = compiles or Compiles()
     t_init = time.perf_counter()
 
-    cfg = program_config(cell, args.rehearse)
-    shape = weights.Shape.of(cfg)
+    family = cell.family
+    cfg = family.program_config(cell.config, args.rehearse)
+    shape = family.Shape.of(cfg)
     serving = cell.config["serving"]
     slots = int(serving["slots"])
     max_seq = REHEARSAL_MAX_SEQ if args.rehearse else int(serving["max_seq"])
@@ -313,8 +289,8 @@ def run(args, t_start: float, compiles: Compiles = None,
     # committed to the chip from the start, as the pools are: an eager op
     # whose inputs change from uncommitted to committed builds a new
     # program, which would otherwise happen in the window's first steps
-    plain = jax.device_put(weights.make(shape, args.seed), dev)
-    params = weights.to_program(plain, shape)
+    plain = jax.device_put(family.make(shape, args.seed), dev)
+    params = family.to_program(plain, shape)
     jax.block_until_ready(params)
     t_weights = time.perf_counter()
 
@@ -396,7 +372,8 @@ def run(args, t_start: float, compiles: Compiles = None,
 
     mix_out = cell.mix["output_tokens"]["values"]
     n_pos = max(max(2, o // scale[1]) for o in mix_out)
-    checks = compare(shape, args.seed, finished, max_seq, n_pos, control)
+    checks = compare(family, shape, args.seed, finished, max_seq, n_pos,
+                     control)
     limit = float(cell.config["correct"]["max_logit_gap"])
 
     def decide(gap: float) -> bool:
@@ -407,7 +384,7 @@ def run(args, t_start: float, compiles: Compiles = None,
     gap = checks["control_max_logit_gap" if control else "max_logit_gap"]
     correct = decide(gap)
 
-    record = Run(shape=shape, peak=None if args.rehearse
+    record = Run(family=family, shape=shape, peak=None if args.rehearse
                  else peaks_for(dev.device_kind), setup_s=setup_s,
                  t_open=t_open, t_close=t_close, steps=steps,
                  requests=requests, trace=summary)

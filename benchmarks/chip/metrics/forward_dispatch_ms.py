@@ -1,8 +1,8 @@
 """Host time a step inside the decode forward's call (the program's
 ``sentinel.forward`` spans in the window, over its ``sentinel.step``
-spans): tracing, lowering, program loads and dispatch of the eager
-forward, with the device idle under it.  Nothing to read from a program
-without these spans."""
+spans): the dispatch of the one compiled decode program
+(``engine.make_pool_decode_step``), which traces, lowers and loads nothing
+after warm-up.  Nothing to read from a program without these spans."""
 UNIT = "ms/step"
 BETTER = "lower"
 SOURCE = "host_clock"

@@ -1,8 +1,9 @@
 """The ``paged_decode`` kernel's share of its roofline over the traced
 window: the least time of every call (the larger of its operations over
-peak bf16 FLOP/s and its bytes over HBM bandwidth, from ``flops.py``: the
-valid tokens of the slots that decode, plus query and output) over the
-kernel's device time."""
+peak bf16 FLOP/s and its bytes over HBM bandwidth, from the family's
+``paged_decode_step``: the valid tokens of the slots that decode, plus
+query and output, in every call of the step) over the kernel's device
+time."""
 from benchmarks.chip import flops
 
 UNIT = "%"
@@ -21,6 +22,6 @@ def read(run):
     least = 0.0
     for s in run.steps:
         if s.decode_lengths:
-            f, b = flops.paged_decode_call(run.shape, s.decode_lengths)
-            least += run.shape.layers * flops.least_seconds(f, b, run.peak)[0]
+            f, b = run.family.paged_decode_step(run.shape, s.decode_lengths)
+            least += flops.least_seconds(f, b, run.peak)[0]
     return 100.0 * least / t

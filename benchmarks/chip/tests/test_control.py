@@ -10,6 +10,8 @@ batch, so those faults have no place here."""
 import argparse
 import time
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -77,8 +79,12 @@ def test_decode_step_that_keeps_its_state_is_not_correct(compiles,
     decode = ContinuousBatcher._pool_decode_step
 
     def unchanged(self):
-        # the forward's new KV is never taken back into the pools
-        self.pool.adopt = lambda new_tree: None
+        # the forward's new KV is never taken back into the pools: they
+        # get back a copy of themselves from before the step (the step
+        # donates what it is handed, so the originals are gone)
+        before = jax.tree.map(jnp.copy, self.pool.forward_tree(stage=False))
+        adopt = self.pool.adopt
+        self.pool.adopt = lambda new_tree: adopt(before)
         try:
             return decode(self)
         finally:

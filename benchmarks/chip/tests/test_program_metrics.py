@@ -14,8 +14,8 @@ S = 1000 * MS
 
 
 def _run(t_open: float, t_close: float) -> harness.Run:
-    return harness.Run(shape=None, peak=None, setup_s=0.0, t_open=t_open,
-                       t_close=t_close, steps=[], requests=[])
+    return harness.Run(family=None, shape=None, peak=None, setup_s=0.0,
+                       t_open=t_open, t_close=t_close, steps=[], requests=[])
 
 
 def _reader(name: str):

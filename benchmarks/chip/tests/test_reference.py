@@ -1,8 +1,8 @@
-"""The plain reference against the program's ``model.forward`` on the same
-seeded weights, at a small size on the CPU, in float32.  Both share only
-the weights, which ``weights.py`` makes; the tolerance is float32 rounding
-over two layers (sums in another order), far below any gap the benchmark
-compares."""
+"""The ``gqa`` family's plain reference against the program's
+``model.forward`` on the same seeded weights, at a small size on the CPU,
+in float32.  Both share only the weights, which the family's ``make``
+makes; the tolerance is float32 rounding over two layers (sums in another
+order), far below any gap the benchmark compares."""
 import dataclasses
 
 import jax
@@ -10,7 +10,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmarks.chip import reference, weights
+from benchmarks.chip import reference, spec
+
+gqa = spec.family("gqa", "test_reference.py")
 
 
 def _tiny(arch):
@@ -27,8 +29,8 @@ def test_tree_matches_the_program(arch):
     cfg = dataclasses.replace(_tiny(arch), dtype="bfloat16")
     want = jax.eval_shape(lambda k: split_params(model.init_params(k, cfg))[0],
                           jax.random.PRNGKey(0))
-    s = weights.Shape.of(cfg)
-    got = jax.eval_shape(lambda: weights.to_program(weights._make(
+    s = gqa.Shape.of(cfg)
+    got = jax.eval_shape(lambda: gqa.to_program(gqa._make(
         jax.random.PRNGKey(0), s), s))
     assert jax.tree.structure(got) == jax.tree.structure(want)
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
@@ -39,19 +41,19 @@ def test_tree_matches_the_program(arch):
 def test_reference_matches_model_forward(arch):
     from repro.models import model
     cfg = _tiny(arch)
-    s = weights.Shape.of(cfg)
-    w = weights.make(s, 2**31 + 12345)
+    s = gqa.Shape.of(cfg)
+    w = gqa.make(s, 2**31 + 12345)
     T = 40
     tokens = np.random.default_rng(0).integers(0, s.vocab, T).astype(np.int32)
     with jax.default_matmul_precision("highest"):
-        want = model.forward(weights.to_program(w, s), cfg,
+        want = model.forward(gqa.to_program(w, s), cfg,
                              {"tokens": jnp.asarray(tokens)[None]})[0]
     want = np.asarray(want[0, :, :s.vocab])
     pos = np.arange(T)
-    got = reference.logits_at(s, w, tokens, pos, length=64, n_pos=T)
+    got = gqa.logits_at(s, w, tokens, pos, length=64, n_pos=T)
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
     # padding past the sequence changes nothing before it
-    got_long = reference.logits_at(s, w, tokens, pos, length=128, n_pos=48)
+    got_long = gqa.logits_at(s, w, tokens, pos, length=128, n_pos=48)
     np.testing.assert_allclose(got_long, got, rtol=1e-5, atol=1e-5)
 
 
@@ -62,11 +64,11 @@ def test_gaps():
 
 def test_fp8_control_differs_from_reference():
     cfg = _tiny("smollm-360m")
-    s = weights.Shape.of(cfg)
-    w = weights.make(s, 5)
+    s = gqa.Shape.of(cfg)
+    w = gqa.make(s, 5)
     tokens = np.arange(30, dtype=np.int32)
     pos = np.arange(30)
-    ref = reference.logits_at(s, w, tokens, pos, 32, 30)
-    ctl = reference.logits_at(s, w, tokens, pos, 32, 30, fp8=True)
+    ref = gqa.logits_at(s, w, tokens, pos, 32, 30)
+    ctl = gqa.logits_at(s, w, tokens, pos, 32, 30, fp8=True)
     err = np.abs(ctl - ref).max()
     assert 1e-3 < err < 10 * np.abs(ref).max()
