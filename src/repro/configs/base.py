@@ -33,6 +33,34 @@ class MoEConfig:
     router_aux_weight: float = 0.01
     group_size: int = 512          # tokens per dispatch group (GShard-style);
                                    # dispatch memory ~ T * group_size * k * factor
+    norm_topk_prob: bool = True    # renormalise the k chosen gate weights
+                                   # (False: the softmax over all experts,
+                                   # as DeepSeek-V2 publishes it)
+    dropless: bool = False         # every routed token reaches its expert
+                                   # (the held-expert layer, moe.held_moe)
+                                   # instead of the capacity dispatch
+    first_expert: int = 0          # this chip holds experts
+    num_held: int = 0              # [first_expert, first_expert + num_held);
+                                   # 0: all of them (no expert parallelism)
+
+    @property
+    def held(self) -> int:
+        """Routed experts whose weights this chip holds."""
+        return self.num_held or self.num_experts
+
+
+@dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rotary scaling (``rope_scaling`` of type ``yarn``, as
+    DeepSeek-V2 publishes it): the rotary frequencies blend interpolated and
+    extrapolated ones between the correction dims of ``beta_fast`` and
+    ``beta_slow``, and the attention scale grows by ``mscale_all_dim``."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -80,6 +108,8 @@ class ModelConfig:
     qk_nope_dim: int = 0
     qk_rope_dim: int = 0
     v_head_dim: int = 0
+    kv_norm: bool = False         # RMSNorm on the latent (kv_a_layernorm)
+    rope_yarn: Optional[YarnConfig] = None   # YaRN scaling of the rope dims
 
     moe: Optional[MoEConfig] = None
     ssm: Optional[SSMConfig] = None
@@ -154,7 +184,8 @@ class ModelConfig:
             # prefill/decode parity holds exactly (capacity dropping is
             # batch-shape-dependent by construction)
             moe=dataclasses.replace(self.moe, num_experts=4, experts_per_token=2,
-                                    d_ff=64, capacity_factor=4.0)
+                                    d_ff=64, capacity_factor=4.0,
+                                    first_expert=0, num_held=0)
             if self.moe else None,
             ssm=dataclasses.replace(self.ssm, state_dim=16, head_dim=8, num_heads=0,
                                     chunk=8) if self.ssm else None,

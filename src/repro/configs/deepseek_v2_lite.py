@@ -1,8 +1,14 @@
 """deepseek-v2-lite-16b [moe] — MLA (kv_lora=512) + 64 routed top-6 + 2 shared.
 
 [arXiv:2405.04434; hf] — layer 0 is dense (d_ff=10944), layers 1..26 MoE.
+The latent is RMS-normed (kv_a_layernorm), the rope dims are YaRN-scaled
+(factor 40 over 4096 positions), and routing is the published gate: a
+softmax over all 64 experts, the top 6 taken greedily, not renormalised,
+every routed token computed (dropless).  Expert parallelism holds a slice
+of the experts per chip: ``MoEConfig.first_expert`` / ``num_held``.
 """
-from repro.configs.base import MLA, ModelConfig, MoEConfig, register
+from repro.configs.base import (MLA, ModelConfig, MoEConfig, YarnConfig,
+                                register)
 
 CONFIG = register(ModelConfig(
     name="deepseek-v2-lite-16b",
@@ -21,8 +27,12 @@ CONFIG = register(ModelConfig(
     qk_nope_dim=128,
     qk_rope_dim=64,
     v_head_dim=128,
+    kv_norm=True,
+    rope_yarn=YarnConfig(factor=40.0, original_max_position=4096,
+                         beta_fast=32.0, beta_slow=1.0, mscale=0.707,
+                         mscale_all_dim=0.707),
     moe=MoEConfig(num_experts=64, experts_per_token=6, num_shared_experts=2,
-                  d_ff=1408),
+                  d_ff=1408, norm_topk_prob=False, dropless=True),
     act="silu",
     tie_embeddings=False,
 ))

@@ -63,6 +63,51 @@ def paged_decode_attention(q, k_hot, v_hot, k_cold, v_cold, page_table,
         window=window, softcap_val=softcap_val)
 
 
+def latent_decode_attention(q_lat, q_rope, ckv_hot, kr_hot, ckv_cold,
+                            kr_cold, page_table, page_tier, lengths, *,
+                            scale):
+    """Flash-decode of absorbed MLA queries over paged, tiered latent pools
+    (one 576-wide latent head read by every query head).  See
+    kernels/paged_decode.py::latent_decode_attention."""
+    if _pallas_enabled():
+        from repro.kernels import paged_decode as pd
+        return pd.latent_decode_attention(
+            q_lat, q_rope, ckv_hot, kr_hot, ckv_cold, kr_cold, page_table,
+            page_tier, lengths, scale=scale, interpret=_interpret())
+    return _ref.latent_decode_attention_ref(
+        q_lat, q_rope, ckv_hot, kr_hot, ckv_cold, kr_cold, page_table,
+        page_tier, lengths, scale=scale)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _held_experts_pallas(x, comb, wg, wi, wo, act):
+    from repro.kernels import held_experts as he
+    return he.held_experts(x, comb, wg, wi, wo, act=act,
+                           interpret=_interpret())
+
+
+def _held_fwd(x, comb, wg, wi, wo, act):
+    return _held_experts_pallas(x, comb, wg, wi, wo, act), \
+        (x, comb, wg, wi, wo)
+
+
+def _held_bwd(act, res, g):
+    _, vjp = jax.vjp(partial(_ref.held_experts_ref, act=act), *res)
+    return vjp(g)
+
+
+_held_experts_pallas.defvjp(_held_fwd, _held_bwd)
+
+
+def held_experts(x, comb, wg, wi, wo, *, act="silu"):
+    """The held experts' gated MLPs over every row, each row weighted by
+    its combine weight per expert (kernels/held_experts.py); the gradient
+    is the oracle's."""
+    if _pallas_enabled():
+        return _held_experts_pallas(x, comb, wg, wi, wo, act)
+    return _ref.held_experts_ref(x, comb, wg, wi, wo, act=act)
+
+
 def ssd(x, dt, A, Bm, Cm, *, chunk=256, h0=None):
     if _pallas_enabled():
         from repro.kernels import mamba2 as m2

@@ -46,6 +46,22 @@ neither compute nor fetch.
 Oracle: repro.kernels.ref.paged_decode_attention_ref — the same page loop in
 pure jnp, bit-exact against this kernel in interpret mode (same op sequence,
 see kernels/decode_attention.masked_scores).
+
+``latent_decode`` is the variant for multi-head latent attention (MLA): one
+latent "head" of ``C + R`` lanes per token (``kv_lora_rank`` latent plus
+the shared rope key), read by every query head.  Its pools are two, each
+page-addressed by the same table as above:
+
+  ckv_hot / ckv_cold  (n, page, C)   the latent rows, also the values
+  kr_hot / kr_cold    (n, page, R)   the shared rope key rows
+
+The queries come absorbed into the latent (``q_nope @ w_uk^T``, done by
+models/attention.py before the call), so a score is ``q_lat . ckv +
+q_rope . kr`` and the output ``softmax(scores) @ ckv`` stays in the latent
+for the caller's ``w_uv``.  The grid is (batch, logical page): one page of
+both pools per step for all heads at once, with the same index maps and
+length skipping as ``paged_decode``.  Oracle:
+repro.kernels.ref.latent_decode_attention_ref (bit-exact in interpret mode).
 """
 from __future__ import annotations
 
@@ -163,6 +179,103 @@ def paged_decode_attention(q, k_hot, v_hot, k_cold, v_cold, page_table,
         name="paged_decode",
     )(hot_blk, cold_blk, page_tier, lengths, qg, k_hot, v_hot, k_cold, v_cold)
     return out.reshape(B, H, D)
+
+
+def latent_scores(q_lat, q_rope, ckv, kr, base, length, scale: float):
+    """Scores of absorbed MLA queries against one latent page,
+    ``(q_lat . ckv + q_rope . kr) * scale``, masked beyond ``length``.
+
+    q_lat (..., H, C), q_rope (..., H, R); ckv (..., page, C), kr (...,
+    page, R); base: the page's first key position.  Shared by the
+    ``latent_decode`` kernel and its oracle, so the two run one op
+    sequence."""
+    nd = q_lat.ndim
+    dims = (((nd - 1,), (nd - 1,)), (tuple(range(nd - 2)),) * 2)
+    s = jax.lax.dot_general(q_lat, ckv, dims,
+                            preferred_element_type=jnp.float32) \
+        + jax.lax.dot_general(q_rope, kr, dims,
+                              preferred_element_type=jnp.float32)
+    s = s * scale
+    kpos = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, s.ndim - 1)
+    length = jnp.asarray(length)
+    length = length.reshape(length.shape + (1,) * (s.ndim - length.ndim))
+    return jnp.where(kpos < length, s, NEG_INF)
+
+
+def _latent_kernel(hot_blk, cold_blk, tier_ref, len_ref, ql_ref, qr_ref,
+                   ch_ref, kh_ref, cc_ref, kc_ref, o_ref, acc, m_s, l_s, *,
+                   page, scale):
+    b, i = pl.program_id(0), pl.program_id(1)
+    del hot_blk, cold_blk                  # consumed by the index maps
+    length = len_ref[b]
+
+    @pl.when(i == 0)
+    def _init():
+        acc[...] = jnp.zeros_like(acc)
+        m_s[...] = jnp.full_like(m_s, NEG_INF)
+        l_s[...] = jnp.zeros_like(l_s)
+
+    @pl.when(i < pl.cdiv(length, page))
+    def _page():
+        hot = tier_ref[b, i] == 0
+        ckv = jnp.where(hot, ch_ref[...], cc_ref[...]).astype(jnp.float32)
+        kr = jnp.where(hot, kh_ref[...], kc_ref[...]).astype(jnp.float32)
+        s = latent_scores(ql_ref[...].astype(jnp.float32),
+                          qr_ref[...].astype(jnp.float32), ckv, kr,
+                          i * page, length, scale)
+        acc[...], m_s[...], l_s[...] = online_softmax_update(
+            s, ckv, acc[...], m_s[...], l_s[...])
+
+    @pl.when(i == pl.num_programs(1) - 1)
+    def _final():
+        o_ref[...] = (acc[...] / jnp.maximum(l_s[...], 1e-30)[:, None]
+                      ).astype(o_ref.dtype)
+
+
+def latent_decode_attention(q_lat, q_rope, ckv_hot, kr_hot, ckv_cold,
+                            kr_cold, page_table, page_tier, lengths, *,
+                            scale: float, interpret: bool = False):
+    """q_lat (B, H, C), q_rope (B, H, R); pools ckv (n, page, C), kr (n,
+    page, R); page_table / page_tier (B, NP); lengths (B,) valid tokens per
+    slot (>= 1).  Returns the attention output in the latent, (B, H, C)."""
+    B, H, C = q_lat.shape
+    R = q_rope.shape[-1]
+    page = ckv_hot.shape[1]
+    NP = page_table.shape[1]
+    page_table = page_table.astype(jnp.int32)
+    page_tier = page_tier.astype(jnp.int32)
+    lengths = lengths.astype(jnp.int32)
+    hot_blk, cold_blk = _block_indices(page_table, page_tier, lengths, page,
+                                       0, ckv_hot.shape[0],
+                                       ckv_cold.shape[0])
+
+    def pool_spec(width, blk):
+        return pl.BlockSpec(
+            (None, page, width),
+            lambda b, i, hb, cb, tr, ln: ((hb if blk == 0 else cb)[b, i],
+                                          0, 0))
+
+    def row_spec(width):
+        return pl.BlockSpec((None, H, width), lambda b, i, *_: (b, 0, 0))
+
+    return pl.pallas_call(
+        functools.partial(_latent_kernel, page=page, scale=scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(B, NP),
+            in_specs=[row_spec(C), row_spec(R), pool_spec(C, 0),
+                      pool_spec(R, 0), pool_spec(C, 1), pool_spec(R, 1)],
+            out_specs=row_spec(C),
+            scratch_shapes=[pltpu.VMEM((H, C), jnp.float32),
+                            pltpu.VMEM((H,), jnp.float32),
+                            pltpu.VMEM((H,), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((B, H, C), q_lat.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name="latent_decode",
+    )(hot_blk, cold_blk, page_tier, lengths, q_lat, q_rope, ckv_hot, kr_hot,
+      ckv_cold, kr_cold)
 
 
 def pool_layout(cold_tokens, num_pages: int, page_tokens: int):

@@ -113,6 +113,64 @@ def paged_decode_attention_ref(q, k_hot, v_hot, k_cold, v_cold, page_table,
     return out.astype(q.dtype).reshape(B, H, D)
 
 
+def latent_decode_attention_ref(q_lat, q_rope, ckv_hot, kr_hot, ckv_cold,
+                                kr_cold, page_table, page_tier, lengths, *,
+                                scale: float):
+    """Oracle for kernels/paged_decode.py::latent_decode_attention: the same
+    page loop in pure jnp, vectorized over the batch (see
+    ``paged_decode_attention_ref`` for why out-of-range pages contribute
+    exactly nothing).
+
+    q_lat: (B, H, C) queries absorbed into the latent; q_rope: (B, H, R);
+    pools ckv (n, page, C), kr (n, page, R); page_table / page_tier
+    (B, NP); lengths (B,).  Returns (B, H, C)."""
+    from repro.kernels.paged_decode import latent_scores
+    from repro.kernels.decode_attention import (NEG_INF,
+                                                online_softmax_update)
+    B, H, C = q_lat.shape
+    page = ckv_hot.shape[1]
+    NP = page_table.shape[1]
+    ql = q_lat.astype(jnp.float32)
+    qr = q_rope.astype(jnp.float32)
+    lengths = lengths.astype(jnp.int32)
+
+    def gather(pool_hot, pool_cold, i):
+        phys = page_table[:, i]
+        hot = pool_hot[jnp.clip(phys, 0, pool_hot.shape[0] - 1)]
+        cold = pool_cold[jnp.clip(phys, 0, pool_cold.shape[0] - 1)]
+        pg = jnp.where(page_tier[:, i][:, None, None] == 0, hot, cold)
+        return pg.astype(jnp.float32)                     # (B, page, ·)
+
+    def body(i, carry):
+        acc, m, l = carry
+        ckv = gather(ckv_hot, ckv_cold, i)
+        kr = gather(kr_hot, kr_cold, i)
+        s = latent_scores(ql, qr, ckv, kr, i * page, lengths, scale)
+        return online_softmax_update(s, ckv, acc, m, l)
+
+    acc, m, l = jax.lax.fori_loop(
+        0, NP, body,
+        (jnp.zeros((B, H, C), jnp.float32),
+         jnp.full((B, H), NEG_INF, jnp.float32),
+         jnp.zeros((B, H), jnp.float32)))
+    out = acc / jnp.maximum(l, 1e-30)[..., None]
+    return out.astype(q_lat.dtype)
+
+
+# ---------------------------------------------------------- held experts ----
+
+def held_experts_ref(x, comb, wg, wi, wo, *, act: str = "silu"):
+    """Oracle for kernels/held_experts.py: x (T, d), comb (T, E), banks
+    wg / wi (E, d, F), wo (E, F, d) -> (T, d) in x's dtype."""
+    from repro.models.layers import act_fn
+    f32 = jnp.float32
+    g = jnp.einsum("td,edf->etf", x, wg, preferred_element_type=f32)
+    u = jnp.einsum("td,edf->etf", x, wi, preferred_element_type=f32)
+    h = (act_fn(act)(g) * u).astype(x.dtype)
+    y = jnp.einsum("etf,efd->etd", h, wo, preferred_element_type=f32)
+    return jnp.einsum("te,etd->td", comb.astype(f32), y).astype(x.dtype)
+
+
 # ------------------------------------------------------------ mamba2 SSD ----
 
 def ssd_ref(x, dt, A, Bm, Cm, *, h0=None):

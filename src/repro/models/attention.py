@@ -13,7 +13,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ATTN, LOCAL, MLA
-from repro.models.layers import Param, apply_rope, dense_init, rmsnorm, softcap
+from repro.models.layers import (Param, apply_rope, dense_init, rmsnorm,
+                                 softcap, yarn_softmax_scale)
 from repro.sharding import constrain
 
 NEG_INF = -2.0 ** 20
@@ -296,7 +297,7 @@ def init_mla(key, cfg, dtype=jnp.float32):
     d, H = cfg.d_model, cfg.num_heads
     dn, dr, dv, dc = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim, cfg.kv_lora_rank
     ks = jax.random.split(key, 6)
-    return {
+    p = {
         "wq": dense_init(ks[0], d, H * (dn + dr), ("embed", "heads"), dtype),
         "w_dkv": dense_init(ks[1], d, dc, ("embed", None), dtype),
         "w_krope": dense_init(ks[2], d, dr, ("embed", None), dtype),
@@ -304,24 +305,57 @@ def init_mla(key, cfg, dtype=jnp.float32):
         "w_uv": dense_init(ks[4], dc, H * dv, (None, "heads"), dtype),
         "wo": dense_init(ks[5], H * dv, d, ("heads", "embed"), dtype),
     }
+    if cfg.kv_norm:
+        p["kv_norm"] = Param(jnp.zeros((dc,), dtype), (None,))
+    return p
+
+
+def mla_softmax_scale(cfg) -> float:
+    """``(qk_nope + qk_rope) ** -0.5``, times YaRN's ``mscale ** 2``."""
+    return (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5 \
+        * yarn_softmax_scale(cfg.rope_yarn)
 
 
 def mla_attention(params, cfg, x, positions, *, cache: Optional[dict] = None,
-                  cache_index=None, **_) -> Tuple[jax.Array, Optional[dict]]:
+                  cache_index=None, paged_view: Optional[dict] = None,
+                  **_) -> Tuple[jax.Array, Optional[dict]]:
     """DeepSeek-V2 MLA. Cache holds the *compressed* latent (B, S, dc) + shared
     rope key (B, S, dr) — the paper's KV-cache compression; K/V are expanded
-    from the latent at use time."""
+    from the latent at use time.  The latent is RMS-normed when
+    ``cfg.kv_norm`` (kv_a_layernorm); the rope dims are YaRN-scaled when
+    ``cfg.rope_yarn``.
+
+    On the persistent page pools (a cache with ``ckv_hot``,
+    kvcache.PagedKVPools) the latent pages are the cache: prefill writes
+    through the slot's page-table row and attends over the gathered row
+    (``_mla_pool_prefill``); decode absorbs ``w_uk`` into the query and
+    reads the pools through the ``latent_decode`` kernel
+    (``_mla_pool_decode``)."""
     B, Sq, d = x.shape
     H = cfg.num_heads
-    dn, dr, dv, dc = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim, cfg.kv_lora_rank
+    dn, dr = cfg.qk_nope_dim, cfg.qk_rope_dim
 
     q = (x @ params["wq"]).reshape(B, Sq, H, dn + dr)
     q_nope, q_rope = q[..., :dn], q[..., dn:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = apply_rope(q_rope, positions, cfg.rope_theta, cfg.rope_yarn)
 
     ckv = x @ params["w_dkv"]                           # (B,Sq,dc)
+    if cfg.kv_norm:
+        ckv = rmsnorm(ckv, params["kv_norm"], cfg.norm_eps, plus_one=True)
     krope = apply_rope((x @ params["w_krope"])[:, :, None, :], positions,
-                       cfg.rope_theta)[:, :, 0, :]      # (B,Sq,dr) shared head
+                       cfg.rope_theta, cfg.rope_yarn)[:, :, 0, :]  # shared head
+
+    if cache is not None and "ckv_hot" in cache:
+        if paged_view is not None and paged_view.get("prefill"):
+            out, new_cache = _mla_pool_prefill(params, cfg, q_nope, q_rope,
+                                               ckv, krope, cache, cache_index,
+                                               paged_view, positions)
+        else:
+            out, new_cache = _mla_pool_decode(params, cfg, q_nope, q_rope,
+                                              ckv, krope, cache, cache_index,
+                                              paged_view)
+        out = constrain(out, ("batch", "seq", "heads"))
+        return out @ params["wo"], new_cache
 
     if cache is not None:
         if cache_index is not None and getattr(cache_index, "ndim", 0) >= 1:
@@ -343,18 +377,100 @@ def mla_attention(params, cfg, x, positions, *, cache: Optional[dict] = None,
         ckv_all, kr_all, new_cache = ckv, krope, None
         kv_pos = positions[0] if positions.ndim > 1 else positions
 
+    out = _mla_core(params, cfg, q_nope, q_rope, ckv_all, kr_all,
+                    attn_bias(positions, kv_pos))
+    out = constrain(out, ("batch", "seq", "heads"))
+    return out @ params["wo"], new_cache
+
+
+def _mla_core(params, cfg, q_nope, q_rope, ckv_all, kr_all, bias):
+    """Attention in the expanded form: ``k_nope`` and ``v`` from the whole
+    latent (B, Skv, dc), scores against ``[k_nope, krope]``.  Returns
+    (B, Sq, H * dv)."""
+    B, Sq, H, dn = q_nope.shape
+    dv = cfg.v_head_dim
     Skv = ckv_all.shape[1]
     k_nope = (ckv_all @ params["w_uk"]).reshape(B, Skv, H, dn)
     v = (ckv_all @ params["w_uv"]).reshape(B, Skv, H, dv)
 
-    scale = (dn + dr) ** -0.5
+    scale = mla_softmax_scale(cfg)
     scores = (jnp.einsum("bqhd,bshd->bhqs", q_nope.astype(jnp.float32),
                          k_nope.astype(jnp.float32)) +
               jnp.einsum("bqhd,bsd->bhqs", q_rope.astype(jnp.float32),
                          kr_all.astype(jnp.float32))) * scale
-    bias = attn_bias(positions, kv_pos)
     scores = scores + (bias[None] if bias.ndim == 2 else bias[:, None])
     probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bhqs,bshd->bqhd", probs.astype(v.dtype), v).reshape(B, Sq, H * dv)
-    out = constrain(out, ("batch", "seq", "heads"))
-    return out @ params["wo"], new_cache
+    return jnp.einsum("bhqs,bshd->bqhd", probs.astype(v.dtype), v).reshape(
+        B, Sq, H * dv)
+
+
+def _mla_pool_prefill(params, cfg, q_nope, q_rope, ckv, krope, cache,
+                      cache_index, paged_view, positions):
+    """Suffix/chunk prefill of one slot (B == 1) straight into the latent
+    page pools — ``_pool_prefill_core``'s contract for the latent: the new
+    rows are scattered into the slot's physical hot pages through its
+    page-table row, then attention reads the FULL row back (hot or staged
+    cold pool by tier), so every shape matches the dense prefill's max_seq
+    buffer.  Returns (out (1, Sq, H * dv), the hot pools)."""
+    B, Sq = ckv.shape[:2]
+    assert B == 1, "pool prefill admits one slot at a time"
+    page = paged_view["page_tokens"]
+    table = paged_view["page_table"]           # (1, max_pages) this slot
+    tier = paged_view["page_tier"]
+    pos = jnp.asarray(cache_index, jnp.int32) \
+        + jnp.arange(Sq, dtype=jnp.int32)
+    phys = table[0, pos // page]
+    off = pos % page
+    ckv_hot = cache["ckv_hot"].at[phys, off].set(ckv[0])
+    kr_hot = cache["krope_hot"].at[phys, off].set(krope[0])
+    ckv_all, kr_all = ckv_hot[table[0]], kr_hot[table[0]]
+    if "ckv_cold" in cache:
+        sel = (tier[0] != 1)[:, None, None]
+        ckv_all = jnp.where(sel, ckv_all, cache["ckv_cold"][table[0]])
+        kr_all = jnp.where(sel, kr_all, cache["krope_cold"][table[0]])
+    Skv = ckv_all.shape[0] * page
+    out = _mla_core(params, cfg, q_nope, q_rope,
+                    ckv_all.reshape(1, Skv, -1), kr_all.reshape(1, Skv, -1),
+                    attn_bias(positions, jnp.arange(Skv)))
+    return out, {"ckv_hot": ckv_hot, "krope_hot": kr_hot}
+
+
+def _mla_pool_decode(params, cfg, q_nope, q_rope, ckv, krope, cache,
+                     cache_index, paged_view):
+    """Absorbed decode over the latent page pools.  The new token's latent
+    row and rope key are scattered into each slot's physical write page
+    (inactive slots into the garbage page, as ``_pool_decode_core``); the
+    query is absorbed into the latent, ``q_lat = q_nope @ w_uk^T`` per
+    head, so the ``latent_decode`` kernel scores ``q_lat . ckv + q_rope .
+    krope`` and returns ``softmax @ ckv``, which ``w_uv`` takes out of the
+    latent.  Nothing is expanded from the cache.  Returns (out (B, 1,
+    H * dv), the hot pools)."""
+    from repro.kernels import ops as kernel_ops
+
+    B, Sq, H, dn = q_nope.shape
+    assert Sq == 1 and paged_view is not None and cache_index is not None, \
+        "pool-form caches are decode-only (the engine prefills densely)"
+    dc, dv = cfg.kv_lora_rank, cfg.v_head_dim
+    page = paged_view["page_tokens"]
+    table_arr = paged_view["page_table"]
+    tier_arr = paged_view["page_tier"]
+    ci = jnp.asarray(cache_index, jnp.int32)
+    ci = ci if ci.ndim >= 1 else jnp.broadcast_to(ci, (B,))
+    phys = table_arr[jnp.arange(B), ci // page]
+    active = paged_view.get("active")
+    if active is not None:
+        phys = jnp.where(active, phys, paged_view["garbage_page"])
+    off = ci % page
+    ckv_hot = cache["ckv_hot"].at[phys, off].set(ckv[:, 0])
+    kr_hot = cache["krope_hot"].at[phys, off].set(krope[:, 0])
+    f32 = jnp.float32
+    q_lat = jnp.einsum("bhn,chn->bhc", q_nope[:, 0],
+                       params["w_uk"].reshape(dc, H, dn),
+                       preferred_element_type=f32).astype(q_nope.dtype)
+    o_lat = kernel_ops.latent_decode_attention(
+        q_lat, q_rope[:, 0], ckv_hot, kr_hot,
+        cache.get("ckv_cold", ckv_hot), cache.get("krope_cold", kr_hot),
+        table_arr, tier_arr, ci + 1, scale=mla_softmax_scale(cfg))
+    out = jnp.einsum("bhc,chv->bhv", o_lat, params["w_uv"].reshape(dc, H, dv),
+                     preferred_element_type=f32).astype(q_nope.dtype)
+    return out.reshape(B, 1, H * dv), {"ckv_hot": ckv_hot, "krope_hot": kr_hot}

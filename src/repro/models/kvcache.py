@@ -154,22 +154,38 @@ def cold_page_get(cold, phys):
 @functools.partial(jax.jit, static_argnums=(1,))
 def _cold_stage(cold, page_shape):
     """The page-major host pool (n, [P,] R, C) as an HBM pool in the
-    kernel's layout ([P,] n, KVH, page, D)."""
+    kernel's layout ([P,] n, *page_shape): (KVH, page, D) for attention,
+    (page, width) for a latent pool."""
     x = jax.device_put(cold, jax.memory.Space.Device)
     x = x.reshape(x.shape[:-2] + page_shape)
-    return jnp.moveaxis(x, 0, -4)
+    return jnp.moveaxis(x, 0, -1 - len(page_shape))
+
+
+def pool_widths(cfg) -> tuple:
+    """Per-token widths of the page pools a pools-layout cache holds
+    (``pool_pages``): ``KVH * D`` for attention, ``kv_lora_rank`` and
+    ``qk_rope_dim`` for the latent pools of MLA layers."""
+    out = []
+    for kind in tuple(cfg.prologue) + tuple(cfg.period):
+        for page in pool_pages(cfg, kind, 1).values():
+            if math.prod(page) not in out:
+                out.append(math.prod(page))
+    return tuple(out)
 
 
 def pool_page_tokens(cfg, requested: int, max_seq: int, device=None) -> int:
     """The page size the persistent pools use for a plan's ``page_tokens``:
     the largest size <= the request that divides ``max_seq``.  On a TPU it
-    is first rounded up to a multiple of ``128 / gcd(128, KVH * D)`` tokens,
-    so that one cold page of one layer fills whole 128-lane rows — the
-    layout a TPU program can write into host memory in place."""
+    is first rounded up to a multiple of ``128 / gcd(128, width)`` tokens
+    for every pool's per-token width (``pool_widths``: ``KVH * D``, or the
+    latent's two), so that one cold page of one layer fills whole 128-lane
+    rows — the layout a TPU program can write into host memory in place."""
     dev = device if device is not None else jax.devices()[0]
     quantum = 1
     if dev.platform == "tpu":
-        quantum = 128 // math.gcd(128, cfg.num_kv_heads * cfg.head_dim)
+        for w in pool_widths(cfg):
+            q = 128 // math.gcd(128, w)
+            quantum = quantum * q // math.gcd(quantum, q)
     page = -(-max(1, int(requested)) // quantum) * quantum
     for p in range(page, 0, -quantum):
         if max_seq % p == 0:
@@ -862,6 +878,22 @@ def init_paged_cache(cfg, batch: int, max_seq: int, page_tokens: int,
 # ------------------------------------------------- persistent pools (serve) --
 
 ATTN_KINDS = (ATTN, LOCAL, SHARED_ATTN)
+POOL_KINDS = ATTN_KINDS + (MLA,)     # layer kinds whose KV lives in pools
+
+
+def pool_pages(cfg, kind: str, page_tokens: int) -> dict:
+    """The page pools of one layer kind, by name, each with the shape of
+    one hot page: attention's ``k`` / ``v`` head-major ``(KVH, page, D)``,
+    the layout ``paged_decode`` reads; MLA's ``ckv`` / ``krope`` (the
+    latent and the shared rope key) ``(page, width)``, one block that every
+    query head reads.  Empty for a kind whose cache is not pooled."""
+    if kind == MLA:
+        return {"ckv": (page_tokens, cfg.kv_lora_rank),
+                "krope": (page_tokens, cfg.qk_rope_dim)}
+    if kind in ATTN_KINDS:
+        page = (cfg.num_kv_heads, page_tokens, cfg.head_dim)
+        return {"k": page, "v": page}
+    return {}
 
 
 class PagedKVPools:
@@ -874,9 +906,16 @@ class PagedKVPools:
       k_hot / v_hot    ([P,] n_hot+1, KVH, page, D)   device memory (HBM)
       k_cold / v_cold  (n_cold, [P,] R, C)            host memory
 
+    and per MLA layer two latent pools of the same form, each page one
+    ``(page, width)`` block read by every query head:
+
+      ckv_hot / krope_hot    ([P,] n_hot+1, page, kv_lora_rank | qk_rope)
+      ckv_cold / krope_cold  (n_cold, [P,] R, C)
+
     addressed through one layer-independent :class:`PageTable` (``[P,]`` is
-    the scanned period axis of stacked layers).  The hot pools are
-    head-major, the layout the kernel reads.  The cold pools are
+    the scanned period axis of stacked layers).  ``pool_pages`` gives each
+    layer kind's pools and their page layout: attention's hot pools are
+    head-major, the layout ``paged_decode`` reads.  The cold pools are
     *page-major*: one cold page of every stacked layer is one contiguous
     lane-dense block of ``R x C`` (``C = 128`` when ``KVH * page * D``
     tiles it), which is what lets a demotion write just that page into host
@@ -884,8 +923,10 @@ class PagedKVPools:
     (models/attention.py resolves each slot's write position to a physical
     hot page); admit / demote / free are incremental per-page deltas;
     ``as_arrays`` uploads of the table happen only when the table's
-    ``version`` changes.  Non-attention layer caches (stateful kinds, MLA)
-    keep their dense batched form inside ``tree``.
+    ``version`` changes.  Every pool of every layer is addressed by the one
+    table, so a page's admit / demote / stage / CoW moves that page of each
+    pool.  Stateful layer caches keep their dense batched form inside
+    ``tree``.
 
     The forward never sees the host pools: ``forward_tree`` hands it the hot
     pools plus, when any page is cold, the cold pools staged into HBM in the
@@ -918,11 +959,17 @@ class PagedKVPools:
         self.table = PageTable(slots, self.num_pages, page_tokens,
                                hot_pages, cold_pages)
         self.garbage = self.table.n_hot          # scratch page, never mapped
-        KVH, D = cfg.num_kv_heads, cfg.head_dim
-        self.page_shape = (KVH, page_tokens, D)
-        width = KVH * page_tokens * D
-        lanes = 128 if width % 128 == 0 else width
-        self.cold_rows = (width // lanes, lanes)
+        # one page of each pool the config's layer kinds use: its hot
+        # block, and its cold rows (lane-dense 128-wide where the page's
+        # elements tile them)
+        self.page_shapes = {}
+        for kind in tuple(cfg.prologue) + tuple(cfg.period):
+            self.page_shapes.update(pool_pages(cfg, kind, page_tokens))
+        self.cold_rows = {}
+        for name, shape in self.page_shapes.items():
+            width = math.prod(shape)
+            lanes = 128 if width % 128 == 0 else width
+            self.cold_rows[name] = (width // lanes, lanes)
         self.tree = self._init_tree(dtype)
         self._cached_arrays = None
         self._cached_version = -1
@@ -933,7 +980,7 @@ class PagedKVPools:
 
     # --------------------------------------------------------- construction --
     def _pool_layer(self, kind: str, dtype, lead: tuple):
-        if kind not in ATTN_KINDS:
+        if kind not in POOL_KINDS:
             one = init_layer_cache(self.cfg, kind, self.num_slots,
                                    self.max_seq, dtype)
             if lead:
@@ -942,16 +989,18 @@ class PagedKVPools:
                     .copy(), one)
             return jax.device_put(one, self.device)
 
-        def hot():
-            return jnp.zeros(lead + (self.table.n_hot + 1,) + self.page_shape,
-                             dtype, device=self.device)
+        def hot(name):
+            return jnp.zeros(lead + (self.table.n_hot + 1,)
+                             + self.page_shapes[name], dtype,
+                             device=self.device)
 
-        def cold():                       # its own buffer: writes donate it
+        def cold(name):                   # its own buffer: writes donate it
             return to_host(jnp.zeros((self.table.n_cold,) + lead
-                                     + self.cold_rows, dtype,
+                                     + self.cold_rows[name], dtype,
                                      device=self.device), self.device)
-        return {"k_hot": hot(), "v_hot": hot(), "k_cold": cold(),
-                "v_cold": cold()}
+        names = pool_pages(self.cfg, kind, self.page_tokens)
+        return {**{f"{n}_hot": hot(n) for n in names},
+                **{f"{n}_cold": cold(n) for n in names}}
 
     def _init_tree(self, dtype):
         cfg = self.cfg
@@ -960,14 +1009,20 @@ class PagedKVPools:
                 "slots": [self._pool_layer(k, dtype, (cfg.num_periods,))
                           for k in cfg.period]}
 
+    def _names(self, entry) -> tuple:
+        """The pools of one pooled layer's entry: ``k``, ``v`` or ``ckv``,
+        ``krope``."""
+        return tuple(n for n in self.page_shapes if f"{n}_hot" in entry)
+
     def _attn_entries(self, *others):
-        """Yield (stacked, pool_entry[, other_entry...]) per attention layer."""
+        """Yield (stacked, pool_entry[, other_entry...]) per pooled layer
+        (attention and MLA)."""
         for i, kind in enumerate(self.cfg.prologue):
-            if kind in ATTN_KINDS:
+            if kind in POOL_KINDS:
                 yield (False, self.tree["prologue"][i],
                        *(o["prologue"][i] for o in others))
         for s, kind in enumerate(self.cfg.period):
-            if kind in ATTN_KINDS:
+            if kind in POOL_KINDS:
                 yield (True, self.tree["slots"][s],
                        *(o["slots"][s] for o in others))
 
@@ -1000,13 +1055,13 @@ class PagedKVPools:
         return len(self.table.cold_free) < self.table.n_cold
 
     def stage_cold(self, entry) -> tuple:
-        """One attention layer's cold pools staged from host memory into HBM
-        in the kernel's layout — the named (``jit(_cold_stage)`` in a
-        trace), counted cold read of a step."""
-        k = _cold_stage(entry["k_cold"], self.page_shape)
-        v = _cold_stage(entry["v_cold"], self.page_shape)
-        self.stats["cold_stage_bytes"] += int(k.nbytes) + int(v.nbytes)
-        return k, v
+        """One pooled layer's two cold pools (k, v or ckv, krope) staged
+        from host memory into HBM in the kernel's layout — the named
+        (``jit(_cold_stage)`` in a trace), counted cold read of a step."""
+        out = tuple(_cold_stage(entry[f"{n}_cold"], self.page_shapes[n])
+                    for n in self._names(entry))
+        self.stats["cold_stage_bytes"] += sum(int(a.nbytes) for a in out)
+        return out
 
     def forward_tree(self, stage: bool):
         """The cache tree a forward consumes: hot pools and dense caches,
@@ -1015,11 +1070,13 @@ class PagedKVPools:
         only.  The host pools are never handed to the forward.  Staging is
         one ``sentinel.stage_cold`` span with the bytes it staged."""
         def view(entry, kind):
-            if kind not in ATTN_KINDS:
+            if kind not in POOL_KINDS:
                 return entry
-            out = {"k_hot": entry["k_hot"], "v_hot": entry["v_hot"]}
+            names = self._names(entry)
+            out = {f"{n}_hot": entry[f"{n}_hot"] for n in names}
             if stage:
-                out["k_cold"], out["v_cold"] = self.stage_cold(entry)
+                out.update(zip((f"{n}_cold" for n in names),
+                               self.stage_cold(entry)))
             return out
 
         def tree():
@@ -1039,49 +1096,50 @@ class PagedKVPools:
         return out
 
     def adopt(self, new_tree) -> None:
-        """Take a forward's updated caches back: the hot pools of attention
+        """Take a forward's updated caches back: the hot pools of pooled
         layers (their cold pools stay where they are), every other layer's
         cache whole."""
         for part, kinds in (("prologue", self.cfg.prologue),
                             ("slots", self.cfg.period)):
             for i, kind in enumerate(kinds):
-                if kind in ATTN_KINDS:
+                if kind in POOL_KINDS:
                     self.tree[part][i] = dict(
-                        self.tree[part][i], k_hot=new_tree[part][i]["k_hot"],
-                        v_hot=new_tree[part][i]["v_hot"])
+                        self.tree[part][i],
+                        **{f"{n}_hot": new_tree[part][i][f"{n}_hot"]
+                           for n in self._names(self.tree[part][i])})
                 else:
                     self.tree[part][i] = new_tree[part][i]
 
     # ------------------------------------------------------ page transfers --
     def hot_page(self, entry, stacked: bool, phys: int) -> tuple:
-        """(k, v) of one hot page: ([P,] KVH, page, D) device arrays."""
-        if stacked:
-            return entry["k_hot"][:, phys], entry["v_hot"][:, phys]
-        return entry["k_hot"][phys], entry["v_hot"][phys]
+        """One hot page of the entry's two pools (k, v: ([P,] KVH, page,
+        D); ckv, krope: ([P,] page, width)) as device arrays."""
+        return tuple(entry[f"{n}_hot"][:, phys] if stacked
+                     else entry[f"{n}_hot"][phys]
+                     for n in self._names(entry))
 
-    def set_hot_page(self, entry, stacked: bool, phys: int, k, v) -> None:
-        if stacked:
-            entry["k_hot"] = entry["k_hot"].at[:, phys].set(k)
-            entry["v_hot"] = entry["v_hot"].at[:, phys].set(v)
-        else:
-            entry["k_hot"] = entry["k_hot"].at[phys].set(k)
-            entry["v_hot"] = entry["v_hot"].at[phys].set(v)
+    def set_hot_page(self, entry, stacked: bool, phys: int, *pages) -> None:
+        for n, page in zip(self._names(entry), pages):
+            key = f"{n}_hot"
+            entry[key] = entry[key].at[:, phys].set(page) if stacked \
+                else entry[key].at[phys].set(page)
 
     def cold_page(self, entry, phys: int) -> tuple:
-        """(k, v) of one cold page, brought to the device in storage form
-        (1, [P,] R, C)."""
-        return (cold_page_get(entry["k_cold"], phys),
-                cold_page_get(entry["v_cold"], phys))
+        """One cold page of the entry's two pools, brought to the device in
+        storage form (1, [P,] R, C)."""
+        return tuple(cold_page_get(entry[f"{n}_cold"], phys)
+                     for n in self._names(entry))
 
-    def set_cold_page(self, entry, phys: int, k, v) -> None:
-        """Write one cold page (device arrays in storage form) into host
-        memory."""
-        entry["k_cold"] = cold_page_put(entry["k_cold"], k, phys)
-        entry["v_cold"] = cold_page_put(entry["v_cold"], v, phys)
+    def set_cold_page(self, entry, phys: int, *pages) -> None:
+        """Write one cold page of each of the entry's pools (device arrays
+        in storage form) into host memory."""
+        for n, page in zip(self._names(entry), pages):
+            entry[f"{n}_cold"] = cold_page_put(entry[f"{n}_cold"], page, phys)
 
-    def _cold_form(self, page):
-        """A hot page ([P,] KVH, page, D) in cold storage form."""
-        return page.reshape((1,) + page.shape[:-3] + self.cold_rows)
+    def _cold_form(self, page, name: str):
+        """A hot page of pool ``name`` in cold storage form."""
+        lead = page.shape[:page.ndim - len(self.page_shapes[name])]
+        return page.reshape((1,) + lead + self.cold_rows[name])
 
     # ----------------------------------------------------------- mutations --
     def free_slot(self, slot: int) -> int:
@@ -1134,28 +1192,29 @@ class PagedKVPools:
             "admit writes land in the hot pool"
         phys = [self.table.table[slot][i] for i in pages]
         pg = self.page_tokens
-        KVH, _, D = self.page_shape
 
-        def head_major(rows):            # ([P,] pg, KVH*D) -> ([P,] KVH, pg, D)
+        def to_page(name, rows):
+            """Fresh rows ([P,] pg, width) as one page of pool ``name``."""
+            if len(self.page_shapes[name]) == 2:     # (pg, width) as it is
+                return rows
+            KVH, _, D = self.page_shapes[name]       # head-major
             rows = rows.reshape(rows.shape[:-1] + (KVH, D))
             return jnp.swapaxes(rows, -3, -2)
 
-        for entry in self._attn_entries(fresh):
-            stacked, pool, fr = entry
+        for stacked, pool, fr in self._attn_entries(fresh):
+            names = self._names(pool)
             for i, ph in zip(pages, phys):
                 lo = i * pg
-                if stacked:                  # fresh (P, 1, S, KVH*D)
-                    k, v = fr["k"][:, 0, lo:lo + pg], fr["v"][:, 0, lo:lo + pg]
-                else:                        # fresh (1, S, KVH*D)
-                    k, v = fr["k"][0, lo:lo + pg], fr["v"][0, lo:lo + pg]
-                self.set_hot_page(pool, stacked, ph, head_major(k),
-                                  head_major(v))
+                # fresh (P, 1, S, width) when stacked, else (1, S, width)
+                self.set_hot_page(pool, stacked, ph, *(
+                    to_page(n, fr[n][:, 0, lo:lo + pg] if stacked
+                            else fr[n][0, lo:lo + pg]) for n in names))
         self.stats["admit_page_writes"] += len(pages)
 
     def splice_other(self, fresh, slot: int) -> None:
-        """Row-splice the non-attention layer caches (stateful kinds, MLA) of
-        a fresh batch-1 cache into the pool tree — same semantics as
-        ``splice_slot`` on the dense layout."""
+        """Row-splice the stateful layer caches (every kind but the pooled
+        ones) of a fresh batch-1 cache into the pool tree — same semantics
+        as ``splice_slot`` on the dense layout."""
         def one(stacked):
             def f(big, small):
                 if big is None:
@@ -1166,12 +1225,12 @@ class PagedKVPools:
             return f
 
         for i, kind in enumerate(self.cfg.prologue):
-            if kind not in ATTN_KINDS:
+            if kind not in POOL_KINDS:
                 self.tree["prologue"][i] = jax.tree.map(
                     one(False), self.tree["prologue"][i],
                     fresh["prologue"][i])
         for s, kind in enumerate(self.cfg.period):
-            if kind not in ATTN_KINDS:
+            if kind not in POOL_KINDS:
                 self.tree["slots"][s] = jax.tree.map(
                     one(True), self.tree["slots"][s], fresh["slots"][s])
 
@@ -1188,9 +1247,10 @@ class PagedKVPools:
         self._note()
         if copied:
             for stacked, entry in self._attn_entries():
-                k, v = self.hot_page(entry, stacked, src)
-                self.set_cold_page(entry, cold_phys, self._cold_form(k),
-                                   self._cold_form(v))
+                pages = self.hot_page(entry, stacked, src)
+                self.set_cold_page(entry, cold_phys, *(
+                    self._cold_form(p, n)
+                    for p, n in zip(pages, self._names(entry))))
             self.stats["page_copies"] += 1
         return copied
 

@@ -68,15 +68,58 @@ def act_fn(name: str):
 
 # ---------------------------------------------------------------- rotary ----
 
-def rope_freqs(head_dim: int, theta: float):
-    return theta ** (-jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim)
+def rope_freqs(head_dim: int, theta: float, yarn=None):
+    """Rotary inverse frequencies (head_dim/2,); with ``yarn``
+    (configs.base.YarnConfig) the YaRN blend of interpolated (``/ factor``)
+    and extrapolated frequencies across its correction range."""
+    freqs = theta ** (-jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim)
+    if yarn is None:
+        return freqs
+    low, high = yarn_correction_range(yarn, head_dim, theta)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    extra = 1.0 - ramp              # 1: keep the dim's own frequency
+    return freqs / yarn.factor * (1.0 - extra) + freqs * extra
 
 
-def apply_rope(x, positions, theta: float):
-    """x: (..., seq, heads, head_dim); positions: (..., seq)."""
-    freqs = rope_freqs(x.shape[-1], theta)                      # (hd/2,)
+def yarn_correction_range(yarn, head_dim: int, theta: float) -> tuple:
+    """The dims ``[low, high]`` between which YaRN ramps from extrapolated
+    to interpolated frequencies (``beta_fast`` / ``beta_slow`` rotations
+    over the original context)."""
+    def dim(rotations):
+        return (head_dim * math.log(yarn.original_max_position
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+    return (max(math.floor(dim(yarn.beta_fast)), 0),
+            min(math.ceil(dim(yarn.beta_slow)), head_dim - 1))
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_softmax_scale(yarn) -> float:
+    """The factor YaRN multiplies the attention's softmax scale by:
+    ``yarn_mscale(factor, mscale_all_dim) ** 2`` (1 without YaRN)."""
+    if yarn is None or not yarn.mscale_all_dim:
+        return 1.0
+    return yarn_mscale(yarn.factor, yarn.mscale_all_dim) ** 2
+
+
+def apply_rope(x, positions, theta: float, yarn=None):
+    """x: (..., seq, heads, head_dim); positions: (..., seq).  With
+    ``yarn`` the frequencies are YaRN's and cos/sin scale by
+    ``mscale(mscale) / mscale(mscale_all_dim)``."""
+    freqs = rope_freqs(x.shape[-1], theta, yarn)                # (hd/2,)
     ang = positions[..., None].astype(jnp.float32) * freqs      # (..., seq, hd/2)
     cos, sin = jnp.cos(ang)[..., None, :], jnp.sin(ang)[..., None, :]
+    if yarn is not None:
+        m = yarn_mscale(yarn.factor, yarn.mscale) / \
+            yarn_mscale(yarn.factor, yarn.mscale_all_dim)
+        if m != 1.0:
+            cos, sin = cos * m, sin * m
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)

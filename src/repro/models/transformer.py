@@ -106,7 +106,8 @@ def apply_block(params, cfg, kind: str, x, positions, *, cache=None,
         h = constrain(h, ("batch", "seq", "embed"))
         if kind == MLA:
             a, new_cache = attn_mod.mla_attention(
-                params["mla"], cfg, h, positions, cache=cache, cache_index=cache_index)
+                params["mla"], cfg, h, positions, cache=cache,
+                cache_index=cache_index, paged_view=paged_view)
         else:
             a, new_cache = attn_mod.attention(
                 params["attn"], cfg, h, positions,

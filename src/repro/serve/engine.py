@@ -242,21 +242,22 @@ class ContinuousBatcher:
         self.prefill_compute_tokens = 0        # prompt rows actually run
         self.prefill_skipped_tokens = 0        # rows skipped via shared pages
         # pool-direct prefill (suffix compute straight into the physical
-        # pages) needs every layer's cache to be a pool entry: pure-attention
-        # stacks only.  Everything else keeps the legacy dense one-shot
-        # prefill + admit_rows splice, as does ``pool_prefill=False`` — the
-        # disaggregated engine's admission (on a TPU the two prefills attend
-        # through different kernels and are not bit-identical).
+        # pages) needs every layer's cache to be a pool entry: stacks of
+        # attention and MLA layers only.  Everything else keeps the legacy
+        # dense one-shot prefill + admit_rows splice, as does
+        # ``pool_prefill=False`` — the disaggregated engine's admission (on
+        # a TPU the two prefills attend through different kernels and are
+        # not bit-identical).
         kinds = tuple(cfg.prologue) + tuple(cfg.period)
         self._pool_prefill_ok = (
             pool_prefill and self.pool is not None
-            and all(k in kvcache.ATTN_KINDS for k in kinds)
+            and all(k in kvcache.POOL_KINDS for k in kinds)
             and not cfg.prefix_lm and not cfg.num_prefix_tokens
             and not cfg.num_codebooks)
         if self.prefill_chunk_tokens and not self._pool_prefill_ok:
             raise ValueError(
                 "prefill_chunk_tokens requires pool-direct prefill: the "
-                "persistent-pools layout with a pure-attention stack, on a "
+                "persistent-pools layout with an attention / MLA stack, on a "
                 "colocated engine (the DisaggregatedEngine prefills whole "
                 "prompts on its prefill group)")
 

@@ -158,3 +158,104 @@ def test_decode_step_compiles_at_published_width(shapes, monkeypatch):
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert "input_output_alias" in text        # the hot pools, in place
+
+
+# DeepSeek-V2-Lite: 16 heads absorbed into a 512-wide latent plus a 64-wide
+# shared rope key; 8 held experts of width 1408 (one chip of an 8-chip
+# expert-parallel group), d_model 2048; 27 layers, the first dense
+DS_H, DS_C, DS_R, DS_D, DS_F, DS_HELD, DS_MOE = 16, 512, 64, 2048, 1408, 8, 26
+
+
+@pytest.mark.parametrize("cold_host", [False, True], ids=["hbm", "staged"])
+def test_latent_decode_compiles_for_v5e(compile_for, shapes, cold_host):
+    """The latent kernel at DeepSeek-V2-Lite widths, its cold pools read
+    from HBM or staged from the page-major host storage."""
+    from repro.kernels.paged_decode import latent_decode_attention
+    from repro.models import kvcache
+    sds, host = shapes
+    if cold_host:
+        cold = (sds((N, PAGE * DS_C // 128, 128), where=host),
+                sds((N, PAGE * DS_R // 128, 128), where=host))
+    else:
+        cold = (sds((N, PAGE, DS_C)), sds((N, PAGE, DS_R)))
+
+    def step(ql, qr, ch, kh, cc, kc, table, tier, lengths):
+        if cold_host:
+            cc = kvcache._cold_stage(cc, (PAGE, DS_C))
+            kc = kvcache._cold_stage(kc, (PAGE, DS_R))
+        return latent_decode_attention(ql, qr, ch, kh, cc, kc, table, tier,
+                                       lengths, scale=0.1147,
+                                       interpret=False)
+
+    compiled = compile_for(step, sds((B, DS_H, DS_C)), sds((B, DS_H, DS_R)),
+                           sds((N + 1, PAGE, DS_C)), sds((N + 1, PAGE, DS_R)),
+                           *cold, sds((B, NP), jnp.int32),
+                           sds((B, NP), jnp.int32), sds((B,), jnp.int32))
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("rows", [B, 1536], ids=["decode", "prefill"])
+def test_held_experts_compiles_for_v5e(compile_for, shapes, rows):
+    from repro.kernels.held_experts import held_experts
+    sds, _ = shapes
+    bank_in = sds((DS_HELD, DS_D, DS_F))
+    compiled = compile_for(
+        lambda x, c, wg, wi, wo: held_experts(x, c, wg, wi, wo,
+                                              interpret=False),
+        sds((rows, DS_D)), sds((rows, DS_HELD), jnp.float32), bank_in,
+        bank_in, sds((DS_HELD, DS_F, DS_D)))
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("op", ["demote_put", "stage"])
+@pytest.mark.parametrize("width", [DS_C, DS_R], ids=["ckv", "krope"])
+def test_latent_cold_page_ops_compile_for_v5e(compile_for, shapes, chip, op,
+                                              width):
+    from repro.models import kvcache
+    sds, host = shapes
+    rows = PAGE * width // 128
+    cold = sds((N, DS_MOE, rows, 128), where=host)
+    if op == "demote_put":
+        compiled = kvcache._cold_put_fn(chip).lower(
+            cold, sds((1, DS_MOE, rows, 128)), sds((), jnp.int32)).compile()
+    else:
+        compiled = compile_for(kvcache._cold_stage.__wrapped__, cold,
+                               (PAGE, width), static_argnums=(1,))
+    assert compiled.as_text()
+
+
+@pytest.mark.usefixtures("compile_for")
+def test_mla_decode_step_compiles_at_published_width(shapes, monkeypatch):
+    """The engine's compiled decode step of DeepSeek-V2-Lite (8 of 64
+    experts held) through the latent pools: one ``latent_decode`` and one
+    ``held_experts`` kernel in it, the hot pools donated."""
+    from repro.configs.base import get_config
+    from repro.kernels import ops
+    from repro.models import model
+    from repro.models.layers import split_params
+    from repro.serve import engine
+    sds, _ = shapes
+    monkeypatch.setitem(ops._FORCE, "pallas", True)
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    base = get_config("deepseek-v2-lite-16b")
+    cfg = dataclasses.replace(base, use_paged_decode=True, moe=dataclasses.
+                              replace(base.moe, num_held=DS_HELD))
+    params = jax.tree.map(lambda a: sds(a.shape, a.dtype), jax.eval_shape(
+        lambda k: split_params(model.init_params(k, cfg))[0],
+        jax.random.PRNGKey(0)))
+
+    def pools(lead):
+        return {"ckv_hot": sds(lead + (N + 1, PAGE, DS_C)),
+                "krope_hot": sds(lead + (N + 1, PAGE, DS_R)),
+                "ckv_cold": sds(lead + (N, PAGE, DS_C)),
+                "krope_cold": sds(lead + (N, PAGE, DS_R))}
+    caches = {"prologue": [pools(())], "slots": [pools((DS_MOE,))]}
+    step = engine.make_pool_decode_step(cfg, PAGE, N)
+    compiled = step.lower(params, sds((B,), jnp.int32), caches,
+                          sds((B,), jnp.int32), sds((B, NP), jnp.int32),
+                          sds((B, NP), jnp.int32),
+                          sds((B,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert "%latent_decode" in text and "%held_experts" in text
+    assert "%paged_decode" not in text
+    assert "input_output_alias" in text
