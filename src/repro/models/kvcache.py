@@ -13,6 +13,7 @@ back in host memory explicitly (``to_host`` / ``cold_page_put``).
 from __future__ import annotations
 
 import functools
+import heapq
 import math
 from dataclasses import dataclass
 from typing import Any, Dict, Optional
@@ -151,14 +152,37 @@ def cold_page_get(cold, phys):
                           jax.memory.Space.Device)
 
 
-@functools.partial(jax.jit, static_argnums=(1,))
-def _cold_stage(cold, page_shape):
-    """The page-major host pool (n, [P,] R, C) as an HBM pool in the
-    kernel's layout ([P,] n, *page_shape): (KVH, page, D) for attention,
-    (page, width) for a latent pool."""
-    x = jax.device_put(cold, jax.memory.Space.Device)
-    x = x.reshape(x.shape[:-2] + page_shape)
-    return jnp.moveaxis(x, 0, -1 - len(page_shape))
+STAGE_CHUNK = 64     # pages of a cold pool one staging copy moves at most
+
+
+def stage_chunk(n_pages: int) -> int:
+    """Pages one staging copy of an ``n_pages`` cold pool moves: the
+    largest divisor of ``n_pages`` up to ``STAGE_CHUNK``, so whole chunks
+    tile the pool."""
+    return next(c for c in range(min(STAGE_CHUNK, n_pages), 0, -1)
+                if n_pages % c == 0)
+
+
+@functools.partial(jax.jit, static_argnums=(2,))
+def _cold_stage(cold, chunks, page_shape):
+    """The first ``chunks`` chunks (``stage_chunk`` pages each) of the
+    page-major host pool (n, [P,] R, C), as an HBM pool of the whole pool's
+    shape in the kernel's layout ([P,] n, *page_shape): (KVH, page, D) for
+    attention, (page, width) for a latent pool.  Every page keeps its
+    index; those past the staged prefix read zero.  ``chunks`` is a runtime
+    scalar, so one program stages every prefix: each chunk is one host
+    slice along the most-major axis, written into the HBM pool in place."""
+    n, lead = cold.shape[0], cold.shape[1:-2]
+    chunk, axis = stage_chunk(n), len(lead)
+
+    def body(i, out):
+        part = jax.lax.dynamic_slice_in_dim(cold, i * chunk, chunk, 0)
+        part = jax.device_put(part, jax.memory.Space.Device)
+        part = part.reshape(part.shape[:-2] + page_shape)
+        return jax.lax.dynamic_update_slice_in_dim(
+            out, jnp.moveaxis(part, 0, axis), i * chunk, axis)
+    out = jnp.zeros(lead + (n,) + page_shape, cold.dtype)
+    return jax.lax.fori_loop(0, chunks, body, out)
 
 
 def pool_widths(cfg) -> tuple:
@@ -351,6 +375,12 @@ class PageTable:
     ``version`` increments on every mutation; callers caching ``as_arrays``
     output re-upload only when it changes (incremental layout deltas, never
     per-step rebuilds).
+
+    Cold pages are handed out lowest free index first (``cold_free`` is a
+    min-heap), and ``cold_high`` is 1 + the highest cold index in use, so
+    the cold pages in use stay packed below a low mark that the staging
+    step copies up to (``PagedKVPools.stage_cold``).  Hot pages come from a
+    LIFO free list.
     """
 
     FREE = -1
@@ -364,7 +394,8 @@ class PageTable:
         self.n_hot = hot_pages or n
         self.n_cold = cold_pages or n
         self.hot_free = list(range(self.n_hot - 1, -1, -1))
-        self.cold_free = list(range(self.n_cold - 1, -1, -1))
+        self.cold_free = list(range(self.n_cold))     # a min-heap
+        self.cold_high = 0
         self.hot_ref = [0] * self.n_hot
         self.cold_ref = [0] * self.n_cold
         self.table = [[self.FREE] * pages_per_slot for _ in range(slots)]
@@ -414,7 +445,11 @@ class PageTable:
         pool = self._free(tier)
         if not pool:
             raise ValueError(f"{'cold' if tier else 'hot'} pool exhausted")
-        phys = pool.pop()
+        if tier == 1:
+            phys = heapq.heappop(pool)
+            self.cold_high = max(self.cold_high, phys + 1)
+        else:
+            phys = pool.pop()
         self._refs(tier)[phys] = 1
         return phys
 
@@ -423,14 +458,17 @@ class PageTable:
         refs[phys] -= 1
         assert refs[phys] >= 0, f"tier {tier} page {phys}: negative refcount"
         if refs[phys] == 0:
-            self._free(tier).append(phys)
             if tier == 0:
+                self.hot_free.append(phys)
                 # hot page gone: its cold twin (if any) lives on through its
                 # own refs, but can no longer be reached for dedup
                 twin = self.cold_twin.pop(phys, None)
                 if twin is not None:
                     self._twin_of.pop(twin, None)
             else:
+                heapq.heappush(self.cold_free, phys)
+                while self.cold_high and not refs[self.cold_high - 1]:
+                    self.cold_high -= 1
                 src = self._twin_of.pop(phys, None)
                 if src is not None:
                     self.cold_twin.pop(src, None)
@@ -562,6 +600,11 @@ class PageTable:
                     f"tier {tier}: page {phys} refcount {r} != " \
                     f"{used.get(phys, 0)} references (double alloc / leak)"
             assert not (set(used) & set(pool)), f"tier {tier}: used page free"
+        assert all(self.cold_free[(i - 1) // 2] <= self.cold_free[i]
+                   for i in range(1, len(self.cold_free))), "cold heap order"
+        assert self.cold_high == 1 + max(
+            (p for p, r in enumerate(self.cold_ref) if r), default=-1), \
+            f"cold high-water mark {self.cold_high}"
         for s in range(self.slots):
             n, nc = self.n_pages[s], self.cold_pages(s)
             assert all(self.tier[s][i] == 1 for i in range(nc))
@@ -930,8 +973,9 @@ class PagedKVPools:
 
     The forward never sees the host pools: ``forward_tree`` hands it the hot
     pools plus, when any page is cold, the cold pools staged into HBM in the
-    kernel's layout (``stage_cold``, counted in ``cold_stage_bytes``), and
-    ``adopt`` takes back the updated hot pools.
+    kernel's layout (``stage_cold``: the prefix of each cold pool up to the
+    table's high-water mark, counted in ``cold_stage_bytes`` and
+    ``cold_stage_pages``), and ``adopt`` takes back the updated hot pools.
 
     The extra hot page at index ``garbage`` (= n_hot) absorbs the lockstep
     writes of inactive batch slots, so a finished slot can never scribble
@@ -941,7 +985,9 @@ class PagedKVPools:
     zero: ``repacks`` (dense->pool re-packs — never in this design),
     ``table_uploads`` (layout deltas), ``page_copies`` (demote/CoW data
     movement), ``admit_page_writes`` (prefill landing in the pools), and
-    the staging step's ``cold_stages`` / ``cold_stage_bytes``.
+    the staging steps' ``cold_stages``, ``cold_stage_pages`` (page indices
+    staged, summed over steps) and ``cold_stage_bytes`` (bytes read from
+    host memory, summed over pools).
     """
 
     def __init__(self, cfg, slots: int, max_seq: int, page_tokens: int,
@@ -975,7 +1021,7 @@ class PagedKVPools:
         self._cached_version = -1
         self.stats = {"repacks": 0, "table_uploads": 0, "page_copies": 0,
                       "admit_page_writes": 0, "cold_stages": 0,
-                      "cold_stage_bytes": 0}
+                      "cold_stage_pages": 0, "cold_stage_bytes": 0}
         self.peak_pages = 0
 
     # --------------------------------------------------------- construction --
@@ -1052,15 +1098,27 @@ class PagedKVPools:
     # ------------------------------------------------- the forward's view --
     def has_cold(self) -> bool:
         """Whether any physical cold page is in use."""
-        return len(self.table.cold_free) < self.table.n_cold
+        return self.table.cold_high > 0
+
+    def staged_pages(self) -> int:
+        """Cold page indices a staging step copies: the table's high-water
+        mark rounded up to whole ``stage_chunk`` chunks — every cold page
+        the table maps lies below it."""
+        chunk = stage_chunk(self.table.n_cold)
+        return -(-self.table.cold_high // chunk) * chunk
 
     def stage_cold(self, entry) -> tuple:
         """One pooled layer's two cold pools (k, v or ckv, krope) staged
-        from host memory into HBM in the kernel's layout — the named
-        (``jit(_cold_stage)`` in a trace), counted cold read of a step."""
-        out = tuple(_cold_stage(entry[f"{n}_cold"], self.page_shapes[n])
-                    for n in self._names(entry))
-        self.stats["cold_stage_bytes"] += sum(int(a.nbytes) for a in out)
+        from host memory into HBM in the kernel's layout, up to
+        ``staged_pages`` — the named (``jit(_cold_stage)`` in a trace),
+        counted cold read of a step."""
+        names = self._names(entry)
+        pages = self.staged_pages()
+        chunks = pages // stage_chunk(self.table.n_cold)
+        out = tuple(_cold_stage(entry[f"{n}_cold"], chunks,
+                                self.page_shapes[n]) for n in names)
+        self.stats["cold_stage_bytes"] += pages * sum(
+            entry[f"{n}_cold"].nbytes // self.table.n_cold for n in names)
         return out
 
     def forward_tree(self, stage: bool):
@@ -1068,7 +1126,8 @@ class PagedKVPools:
         plus — with ``stage`` — the staged cold pools.  Without ``stage`` no
         page the table maps may be cold, and attention reads the hot pools
         only.  The host pools are never handed to the forward.  Staging is
-        one ``sentinel.stage_cold`` span with the bytes it staged."""
+        one ``sentinel.stage_cold`` span with the bytes read from host
+        memory, the page indices staged and the cold pages the table maps."""
         def view(entry, kind):
             if kind not in POOL_KINDS:
                 return entry
@@ -1090,9 +1149,13 @@ class PagedKVPools:
             return tree()
         with obs.span("sentinel.stage_cold") as attrs:
             staged = self.stats["cold_stage_bytes"]
+            pages = self.staged_pages()
             self.stats["cold_stages"] += 1
+            self.stats["cold_stage_pages"] += pages
             out = tree()
             attrs["bytes"] = self.stats["cold_stage_bytes"] - staged
+            attrs["pages"] = pages
+            attrs["mapped"] = self.table.n_cold - len(self.table.cold_free)
         return out
 
     def adopt(self, new_tree) -> None:

@@ -7,6 +7,7 @@ one process at a time may load the TPU library, and the test workers
 import every test file.
 """
 import dataclasses
+import math
 import os
 
 import jax
@@ -83,14 +84,15 @@ def test_paged_decode_compiles_for_v5e(compile_for, shapes, case):
     sds, host = shapes
     cold_host = case == "cold_pools_in_pinned_host"
 
-    def step(q, kh, vh, kc, vc, table, tier, lengths):
+    def step(q, kh, vh, kc, vc, table, tier, lengths, chunks):
         if cold_host:                    # the engine's staging step
-            kc = kvcache._cold_stage(kc, (KVH, PAGE, D))
-            vc = kvcache._cold_stage(vc, (KVH, PAGE, D))
+            kc = kvcache._cold_stage(kc, chunks, (KVH, PAGE, D))
+            vc = kvcache._cold_stage(vc, chunks, (KVH, PAGE, D))
         return paged_decode_attention(q, kh, vh, kc, vc, table, tier,
                                       lengths, window=0, interpret=False)
 
-    compiled = compile_for(step, *_kernel_args(sds, host, cold_host))
+    compiled = compile_for(step, *_kernel_args(sds, host, cold_host),
+                           sds((), jnp.int32))
     assert "tpu_custom_call" in compiled.as_text()
 
 
@@ -109,7 +111,8 @@ def test_flash_attention_compiles_at_prefill_widths(compile_for, shapes):
 def test_cold_page_ops_compile_for_v5e(compile_for, shapes, chip, op):
     """The pools' host-memory page ops on the full page-major cold pool:
     a demotion writes one page in place, a read fetches one page, the
-    staging step brings the pool into HBM in the kernel's layout."""
+    staging step brings the pool's occupied prefix into HBM in the
+    kernel's layout."""
     from repro.models import kvcache
     sds, host = shapes
     rows = (KVH * PAGE * D) // 128
@@ -123,8 +126,40 @@ def test_cold_page_ops_compile_for_v5e(compile_for, shapes, chip, op):
                                sds((), jnp.int32))
     else:
         compiled = compile_for(kvcache._cold_stage.__wrapped__, cold,
-                               (KVH, PAGE, D), static_argnums=(1,))
+                               sds((), jnp.int32), (KVH, PAGE, D),
+                               static_argnums=(2,))
     assert compiled.as_text()
+
+
+# internlm2-1.8b: 8 KV heads of 128, 24 layers
+IL_KVH, IL_D, IL_LAYERS = 8, 128, 24
+
+
+@pytest.mark.parametrize("pool", ["smollm", "internlm2", "deepseek_ckv",
+                                  "deepseek_krope", "deepseek_dense_ckv"])
+def test_cold_stage_copies_chunks_from_host(compile_for, shapes, pool):
+    """The staging program at each cell's pool shapes (8 slots x 128
+    pages of 16 tokens): a loop of host slices of one chunk each, run as
+    many times as a runtime scalar says, into an HBM pool of the whole
+    pool's shape; besides that pool it takes HBM for about one chunk, where
+    a copy of the whole pool took as much again as the pool."""
+    from repro.models import kvcache
+    sds, host = shapes
+    lead, page = {
+        "smollm": ((LAYERS,), (KVH, PAGE, D)),
+        "internlm2": ((IL_LAYERS,), (IL_KVH, PAGE, IL_D)),
+        "deepseek_ckv": ((DS_MOE,), (PAGE, DS_C)),
+        "deepseek_krope": ((DS_MOE,), (PAGE, DS_R)),
+        "deepseek_dense_ckv": ((), (PAGE, DS_C))}[pool]
+    cold = sds((N,) + lead + (math.prod(page) // 128, 128), where=host)
+    compiled = compile_for(kvcache._cold_stage.__wrapped__, cold,
+                           sds((), jnp.int32), page, static_argnums=(2,))
+    text = compiled.as_text()
+    assert "dynamic-slice-start" in text and " while(" in text
+    mem = compiled.memory_analysis()
+    chunk = cold.size // N * kvcache.stage_chunk(N) * 2
+    assert mem.output_size_in_bytes == cold.size * 2
+    assert mem.temp_size_in_bytes < 2 * chunk
 
 
 @pytest.mark.usefixtures("compile_for")     # for its persistent cache off
@@ -179,10 +214,10 @@ def test_latent_decode_compiles_for_v5e(compile_for, shapes, cold_host):
     else:
         cold = (sds((N, PAGE, DS_C)), sds((N, PAGE, DS_R)))
 
-    def step(ql, qr, ch, kh, cc, kc, table, tier, lengths):
+    def step(ql, qr, ch, kh, cc, kc, table, tier, lengths, chunks):
         if cold_host:
-            cc = kvcache._cold_stage(cc, (PAGE, DS_C))
-            kc = kvcache._cold_stage(kc, (PAGE, DS_R))
+            cc = kvcache._cold_stage(cc, chunks, (PAGE, DS_C))
+            kc = kvcache._cold_stage(kc, chunks, (PAGE, DS_R))
         return latent_decode_attention(ql, qr, ch, kh, cc, kc, table, tier,
                                        lengths, scale=0.1147,
                                        interpret=False)
@@ -190,7 +225,8 @@ def test_latent_decode_compiles_for_v5e(compile_for, shapes, cold_host):
     compiled = compile_for(step, sds((B, DS_H, DS_C)), sds((B, DS_H, DS_R)),
                            sds((N + 1, PAGE, DS_C)), sds((N + 1, PAGE, DS_R)),
                            *cold, sds((B, NP), jnp.int32),
-                           sds((B, NP), jnp.int32), sds((B,), jnp.int32))
+                           sds((B, NP), jnp.int32), sds((B,), jnp.int32),
+                           sds((), jnp.int32))
     assert "tpu_custom_call" in compiled.as_text()
 
 
@@ -220,7 +256,8 @@ def test_latent_cold_page_ops_compile_for_v5e(compile_for, shapes, chip, op,
             cold, sds((1, DS_MOE, rows, 128)), sds((), jnp.int32)).compile()
     else:
         compiled = compile_for(kvcache._cold_stage.__wrapped__, cold,
-                               (PAGE, width), static_argnums=(1,))
+                               sds((), jnp.int32), (PAGE, width),
+                               static_argnums=(2,))
     assert compiled.as_text()
 
 
