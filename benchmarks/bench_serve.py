@@ -1,5 +1,5 @@
 """Sentinel-Serve: simulated decode throughput, fast-memory fraction x batch
-slots x placement policy — plus the paged/per-slot engine smoke.
+slots x placement policy — plus gates on the real pools engine.
 
 The serving analogue of the paper's Fig. 10 sweep, dispatched entirely
 through the unified runtime API (``runtime.plan`` + the one policy
@@ -14,7 +14,7 @@ policy runs on every workload.
     PYTHONPATH=src python -m benchmarks.bench_serve \
         --arch smollm-360m --fracs 0.1,0.2 --slots 4 --policies sentinel,lru_page
     PYTHONPATH=src python -m benchmarks.bench_serve \
-        --objective latency --paged --shared-prefix --json BENCH_serve.json
+        --objective latency --shared-prefix --json BENCH_serve.json
 
 Exits non-zero if the Sentinel object policy loses to the best page-grain
 baseline at the paper's headline 20% fast-memory fraction — the CI smoke
@@ -25,16 +25,11 @@ from migration bytes to *simulated seconds* — at 20% fast memory
 ``sentinel`` must be at least as fast as ``lru_page`` in predicted time
 and within 8% of ``all_fast`` (the paper's headline parity claim), and the
 latency-objective planner must pick ``alpha_migration`` somewhere it beats
-the bytes-objective plan's predicted time.  ``--paged`` additionally runs the real ContinuousBatcher in the
-tiered layouts (global-boundary concat, per-slot paged, and the persistent
-page pools with ``use_paged_decode`` — attention writing into and reading
-from the physical pools through ``ops.paged_decode_attention``) on a
-reduced model and gates on the paged paths (a) reproducing the all-HBM
-tokens and (b) re-hosting strictly fewer simulated migration bytes than the
-concat path.  ``--shared-prefix`` runs the N-tenants x one-system-prompt
-workload shared vs unshared — simulator sweep plus the pool engine with
-``prefix_key`` sharing — and gates shared migration bytes AND peak pool
-bytes strictly below the unshared run at 20% fast memory.  ``--tenants``
+the bytes-objective plan's predicted time.  ``--shared-prefix`` runs the
+N-tenants x one-system-prompt workload shared vs unshared — simulator
+sweep plus the pool engine with ``prefix_key`` sharing — and gates shared
+migration bytes AND peak pool bytes strictly below the unshared run at 20%
+fast memory.  ``--tenants``
 runs the adversarial multi-tenant SLO mix and gates ``sentinel_slo`` at
 zero per-tenant quota violations (exactly where the tenant-blind
 ``sentinel`` violates at least one tenant's guarantee) with aggregate
@@ -243,7 +238,6 @@ def run_shared_prefix_engine(arch: str = ARCH):
 
     cfg = get_config(arch).reduced()
     params, _ = split_params(model.init_params(jax.random.PRNGKey(0), cfg))
-    cfg_k = dataclasses.replace(cfg, use_paged_decode=True)
     max_seq, slots = 32, 2
     sys_p = jax.random.randint(jax.random.PRNGKey(7), (9,), 0,
                                cfg.vocab_size).astype(jnp.int32)
@@ -260,9 +254,8 @@ def run_shared_prefix_engine(arch: str = ARCH):
     plan = dataclasses.replace(plan, hot_window=max_seq // 2,
                                slot_hot_windows=[4, 8], page_tokens=4)
 
-    def drive(c, p, paged, shared):
-        b = engine.ContinuousBatcher(params, c, slots, max_seq, plan=p,
-                                     paged=paged)
+    def drive(p, shared):
+        b = engine.ContinuousBatcher(params, cfg, slots, max_seq, plan=p)
         for t, d in reqs:
             b.submit(t, d, prefix_key="sys" if shared else None)
         out = b.run()
@@ -271,9 +264,9 @@ def run_shared_prefix_engine(arch: str = ARCH):
         page_bytes = b.page_tokens * b._row_bytes
         return out, b.sim_migration_bytes, b.pool.peak_pages * page_bytes
 
-    base, _, _ = drive(cfg, None, False, False)
-    out_s, mig_s, peak_s = drive(cfg_k, plan, True, True)
-    out_u, mig_u, peak_u = drive(cfg_k, plan, True, False)
+    base, _, _ = drive(None, False)
+    out_s, mig_s, peak_s = drive(plan, True)
+    out_u, mig_u, peak_u = drive(plan, False)
     match = base == out_s == out_u
     rows = [("bench_serve_shared_engine", "mode", "migration_kb", "peak_kb",
              "tokens_match"),
@@ -282,58 +275,6 @@ def run_shared_prefix_engine(arch: str = ARCH):
             ("bench_serve_shared_engine", "unshared", round(mig_u / 1e3, 3),
              round(peak_u / 1e3, 3), match)]
     return rows, (match, mig_s, mig_u, peak_s, peak_u)
-
-
-def run_paged_smoke(arch: str = ARCH):
-    """Real-engine comparison: concat (global cold boundary) vs paged
-    (per-slot boundaries) vs paged-kernel (attention reads the page pools
-    directly) tiering on a reduced model.  Returns rows and the
-    (tokens_match, paged_bytes, concat_bytes) verdict."""
-    import dataclasses
-
-    import jax
-    import jax.numpy as jnp
-
-    from repro.models import model
-    from repro.models.layers import split_params
-    from repro.serve import engine
-
-    cfg = get_config(arch).reduced()
-    params, _ = split_params(model.init_params(jax.random.PRNGKey(0), cfg))
-    max_seq, slots = 32, 2
-    requests = [(7, 6), (9, 5), (6, 7), (8, 6)]
-    trace = serve_trace_for(get_config(arch), requests, slots=slots,
-                            layer_group=8)
-    plan = runtime.plan(trace, TPU_V5E, 0.2 * trace.peak_kv_bytes())
-    # shrink the planned windows to the reduced max_seq so both layouts
-    # carry a real cold prefix (the full-size plan would keep everything hot)
-    plan = dataclasses.replace(plan, hot_window=max_seq // 2,
-                               slot_hot_windows=[4, 8], page_tokens=4)
-
-    def drive(c, p, paged=False):
-        b = engine.ContinuousBatcher(params, c, slots, max_seq, plan=p,
-                                     paged=paged)
-        key = jax.random.PRNGKey(3)
-        for plen, d in requests:
-            key, sub = jax.random.split(key)
-            b.submit(jax.random.randint(sub, (plen,), 0,
-                                        cfg.vocab_size).astype(jnp.int32), d)
-        return b.run(), b.sim_migration_bytes
-
-    base, _ = drive(cfg, None)
-    out_c, bytes_c = drive(cfg, plan)
-    out_p, bytes_p = drive(cfg, plan, paged=True)
-    cfg_kernel = dataclasses.replace(cfg, use_paged_decode=True)
-    out_k, bytes_k = drive(cfg_kernel, plan, paged=True)
-    match = base == out_c == out_p == out_k
-    rows = [("bench_serve_paged", "mode", "migration_mb", "tokens_match"),
-            ("bench_serve_paged", "concat", round(bytes_c / 1e6, 4), match),
-            ("bench_serve_paged", "paged", round(bytes_p / 1e6, 4), match),
-            ("bench_serve_paged", "paged_kernel", round(bytes_k / 1e6, 4),
-             match)]
-    # both paged variants must beat concat (the kernel path changes the read
-    # layout, never the demotion accounting — gate on the max of the two)
-    return rows, (match, max(bytes_p, bytes_k), bytes_c)
 
 
 def run_prefill(arch: str = ARCH):
@@ -368,17 +309,15 @@ def run_prefill(arch: str = ARCH):
     from repro.runtime.costmodel import StepTraffic
     from repro.serve import engine
 
-    cfg0 = get_config(arch).reduced()
-    cfg = dataclasses.replace(cfg0, use_paged_decode=True)
+    cfg = get_config(arch).reduced()
     params, _ = split_params(model.init_params(jax.random.PRNGKey(0), cfg))
     max_seq, slots = 32, 2
     cm = default_cost_model()
 
-    def drive(c, p, reqs, paged, chunk=0, keys=None):
+    def drive(p, reqs, chunk=0, keys=None):
         if p is not None:
             p = dataclasses.replace(p, prefill_chunk_tokens=chunk)
-        b = engine.ContinuousBatcher(params, c, slots, max_seq, plan=p,
-                                     paged=paged)
+        b = engine.ContinuousBatcher(params, cfg, slots, max_seq, plan=p)
         for i, (t, d) in enumerate(reqs):
             b.submit(t, d, prefix_key=keys[i] if keys else None)
         results, deltas, prev = [], [], 0
@@ -413,9 +352,9 @@ def run_prefill(arch: str = ARCH):
     splan = runtime.plan(strace, TPU_V5E, 0.2 * strace.peak_kv_bytes())
     splan = dataclasses.replace(splan, hot_window=max_seq // 2,
                                 slot_hot_windows=[4, 8], page_tokens=4)
-    base_s, _, _ = drive(cfg0, None, sreqs, False)
-    out_sh, _, cnt_sh = drive(cfg, splan, sreqs, True, keys=["sys"] * 4)
-    out_un, _, cnt_un = drive(cfg, splan, sreqs, True)
+    base_s, _, _ = drive(None, sreqs)
+    out_sh, _, cnt_sh = drive(splan, sreqs, keys=["sys"] * 4)
+    out_un, _, cnt_un = drive(splan, sreqs)
     match_skip = canon(base_s) == canon(out_sh) == canon(out_un)
     comp_sh = cnt_sh["prefill_compute_tokens"]
     comp_un = cnt_un["prefill_compute_tokens"]
@@ -433,9 +372,9 @@ def run_prefill(arch: str = ARCH):
     bplan = runtime.plan(btrace, TPU_V5E, 0.2 * btrace.peak_kv_bytes())
     bplan = dataclasses.replace(bplan, hot_window=max_seq // 2,
                                 slot_hot_windows=[4, 8], page_tokens=4)
-    base_b, _, _ = drive(cfg0, None, breqs, False)
-    out_1, d_1, c_1 = drive(cfg, bplan, breqs, True, chunk=0)
-    out_c, d_c, c_c = drive(cfg, bplan, breqs, True, chunk=8)
+    base_b, _, _ = drive(None, breqs)
+    out_1, d_1, c_1 = drive(bplan, breqs, chunk=0)
+    out_c, d_c, c_c = drive(bplan, breqs, chunk=8)
     match_chunk = canon(base_b) == canon(out_1) == canon(out_c)
 
     # per-step gap pricing: decode tokens (output-count delta) and prefill
@@ -511,8 +450,7 @@ def run_disagg(arch: str = ARCH):
     from repro.serve.disagg import DisaggregatedEngine, price_disagg
     from repro.serve.engine import predict_pool_counters
 
-    cfg = dataclasses.replace(get_config(arch).reduced(),
-                              use_paged_decode=True)
+    cfg = get_config(arch).reduced()
     params, _ = split_params(model.init_params(jax.random.PRNGKey(0), cfg))
     max_seq, slots = 32, 2
     requests = [(7, 6), (9, 5), (6, 7), (8, 6)]
@@ -531,7 +469,7 @@ def run_disagg(arch: str = ARCH):
                                         cfg.vocab_size).astype(jnp.int32), d)
         return b.run(), b
 
-    out_c, _ = drive(engine.ContinuousBatcher, paged=True)
+    out_c, _ = drive(engine.ContinuousBatcher)
     out_d, bd = drive(DisaggregatedEngine)
     match = out_c == out_d
     repacks = bd.counters()["repacks"]
@@ -582,8 +520,7 @@ def _sharded_scenario(arch: str, devs) -> dict:
     from repro.serve.disagg import DisaggregatedEngine
     from repro.serve.engine import predict_pool_counters
 
-    cfg = dataclasses.replace(get_config(arch).reduced(),
-                              use_paged_decode=True)
+    cfg = get_config(arch).reduced()
     params, _ = split_params(model.init_params(jax.random.PRNGKey(0), cfg))
     max_seq, slots = 64, 4
     requests = [(48, 12)] * 4        # prefill-heavy: long prompts, short gen
@@ -762,8 +699,6 @@ def main(argv=None):
                     choices=["bytes", "latency"],
                     help="latency: also run the time-domain sweep on the "
                          "default CostModel and gate on predicted seconds")
-    ap.add_argument("--paged", action="store_true",
-                    help="also run the paged-vs-concat engine smoke + gate")
     ap.add_argument("--shared-prefix", action="store_true",
                     help="also run the prefix-sharing sweep (simulator + "
                          "persistent-pool engine) and gate shared strictly "
@@ -854,22 +789,6 @@ def main(argv=None):
                        "status": "OK" if a_ok else "FAIL"})
         print(f"check,alpha_beats_bytes_plan,cells={len(alpha_wins)},"
               f"{'OK' if a_ok else 'FAIL'}")
-
-    paged_rows = []
-    if args.paged:
-        paged_rows, (match, bytes_p, bytes_c) = run_paged_smoke(args.arch)
-        for r in paged_rows:
-            print(",".join(map(str, r)))
-        paged_ok = match and bytes_p < bytes_c
-        ok &= paged_ok
-        checks.append({"check": "paged_vs_concat_migration_bytes",
-                       "tokens_match": match,
-                       "paged_mb": round(bytes_p / 1e6, 4),
-                       "concat_mb": round(bytes_c / 1e6, 4),
-                       "status": "OK" if paged_ok else "FAIL"})
-        print(f"check,paged,match={match},"
-              f"paged_mb={bytes_p / 1e6:.4f},concat_mb={bytes_c / 1e6:.4f},"
-              f"{'OK' if paged_ok else 'FAIL'}")
 
     shared_rows = []
     if args.shared_prefix:
@@ -1028,7 +947,7 @@ def main(argv=None):
     if args.json:
         with open(args.json, "w") as f:
             json.dump({"rows": [list(r) for r in
-                                rows + latency_rows + paged_rows
+                                rows + latency_rows
                                 + shared_rows + tenant_rows + prefill_rows
                                 + disagg_rows],
                        "checks": checks}, f, indent=2)

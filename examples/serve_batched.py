@@ -1,8 +1,9 @@
 """Batched serving example: prefill a batch of prompts, decode in lockstep,
 including a MusicGen-style 4-codebook stream and a PaliGemma-style
 image-prefix request — then Sentinel-Serve tiered continuous batching: the
-decode-phase planner picks a hot window, the cold KV prefix is held in host
-memory, and the tiered run reproduces the all-HBM outputs exactly.
+decode-phase planner picks a hot window, each slot's cold KV pages are held
+in host memory, and the run on the page pools reproduces the all-HBM
+outputs exactly.
 
     PYTHONPATH=src python examples/serve_batched.py
 
@@ -57,7 +58,7 @@ def demo(arch: str, num_tokens: int = 16):
 
 
 def demo_tiered(arch: str = "smollm-360m", slots: int = 2, max_seq: int = 48):
-    """Tiered continuous batching end-to-end: plan -> cold prefix on host ->
+    """Tiered continuous batching end-to-end: plan -> cold pages on host ->
     identical outputs to the all-HBM batcher."""
     cfg = get_config(arch).reduced()
     params, _ = split_params(model.init_params(jax.random.PRNGKey(0), cfg))
@@ -69,16 +70,14 @@ def demo_tiered(arch: str = "smollm-360m", slots: int = 2, max_seq: int = 48):
     fast = 0.2 * trace.peak_kv_bytes()
     plan = runtime.plan(trace, TPU_V5E, fast)
     print(f"[plan] hot_window={plan.hot_window} tokens, "
-          f"lookahead={plan.lookahead}, cold_len({max_seq})="
-          f"{plan.cold_len(max_seq)}")
+          f"lookahead={plan.lookahead}, page_tokens={plan.page_tokens}")
     for pol in ("prefer_fast", "lru_page", "sentinel", "sentinel_mi"):
         r = runtime.simulate(trace, TPU_V5E, fast, pol)
         print(f"[sim]  {pol:12s} {r.decode_throughput:9.1f} tok/s "
               f"(slowdown {r.slowdown:.3f}, {r.migrations} migrations)")
 
-    def run(p, paged=False):
-        b = engine.ContinuousBatcher(params, cfg, slots, max_seq, plan=p,
-                                     paged=paged)
+    def run(p):
+        b = engine.ContinuousBatcher(params, cfg, slots, max_seq, plan=p)
         key = jax.random.PRNGKey(7)
         for (plen, d) in requests:
             key, sub = jax.random.split(key)
@@ -87,24 +86,21 @@ def demo_tiered(arch: str = "smollm-360m", slots: int = 2, max_seq: int = 48):
             b.submit(toks, d)
         t0 = time.perf_counter()
         out = b.run()
-        return out, time.perf_counter() - t0, b.sim_migration_bytes
+        return out, time.perf_counter() - t0, b
 
-    # force a real cold prefix even if the planned window covers max_seq
+    # force a real cold prefix even if the planned window covers every
+    # request: a one-page window sends the longer requests' oldest page cold
     import dataclasses
-    tiered_plan = dataclasses.replace(
-        plan, hot_window=min(plan.hot_window, max_seq // 2),
-        slot_hot_windows=[min(w, max_seq // 2)
-                          for w in (plan.slot_hot_windows or [])] or None,
-        page_tokens=min(plan.page_tokens or 8, 8))
+    tiered_plan = dataclasses.replace(plan, hot_window=8, page_tokens=8,
+                                      slot_hot_windows=None)
     base, t_base, _ = run(None)
-    tier, t_tier, mig_c = run(tiered_plan)
-    pag, t_pag, mig_p = run(tiered_plan, paged=True)
-    match = base == tier == pag
-    print(f"[e2e]  all-HBM {t_base:5.2f}s | concat-tiered {t_tier:5.2f}s "
-          f"({mig_c / 1e6:.2f} MB re-hosted) | paged per-slot {t_pag:5.2f}s "
-          f"({mig_p / 1e6:.2f} MB re-hosted) | outputs match: {match}")
+    tier, t_tier, b = run(tiered_plan)
+    match = base == tier
+    print(f"[e2e]  all-HBM {t_base:5.2f}s | page pools {t_tier:5.2f}s "
+          f"({b.pool.stats['page_copies']} pages demoted, "
+          f"{b.sim_migration_bytes / 1e3:.1f} KB to host) | "
+          f"outputs match: {match}")
     assert match, "tiered decode diverged from the all-HBM reference"
-    assert mig_p <= mig_c, "per-slot paging moved more bytes than concat"
 
 
 def demo_disagg(arch: str = "smollm-360m", slots: int = 2,
@@ -120,8 +116,7 @@ def demo_disagg(arch: str = "smollm-360m", slots: int = 2,
     prefill_devs, decode_devs = disagg_groups()
     print(f"[mesh] {len(jax.devices())} devices: "
           f"prefill={prefill_devs} decode={decode_devs}")
-    cfg = dataclasses.replace(get_config(arch).reduced(),
-                              use_paged_decode=True)
+    cfg = get_config(arch).reduced()
     params, _ = split_params(model.init_params(jax.random.PRNGKey(0), cfg))
     requests = [(7, 6), (9, 5), (6, 7), (8, 6)]
     trace = engine.serve_trace_for(get_config(arch), requests, slots=slots,
@@ -142,7 +137,7 @@ def demo_disagg(arch: str = "smollm-360m", slots: int = 2,
         out = b.run()
         return out, time.perf_counter() - t0, b
 
-    base, t_base, _ = run(engine.ContinuousBatcher, paged=True)
+    base, t_base, _ = run(engine.ContinuousBatcher)
     dis, t_dis, bd = run(DisaggregatedEngine)
     match = base == dis
     print(f"[e2e]  single-device {t_base:5.2f}s | disaggregated "

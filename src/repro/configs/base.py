@@ -91,9 +91,6 @@ class ModelConfig:
     prologue_d_ff: int = 0
 
     # attention details
-    use_paged_decode: bool = False  # decode attention reads the tiered page
-                                    # pools via ops.paged_decode_attention
-                                    # (serve/engine passes the page view)
     sliding_window: int = 0
     attn_softcap: float = 0.0
     final_softcap: float = 0.0

@@ -1,7 +1,7 @@
 """Paged flash-decode over tiered KV pools (Pallas TPU).
 
-The serving-time replacement for the concat-based cold-KV read: instead of
-materializing `concat(cold_prefix, hot_window)` before attention, KV lives in
+The serving-time cold-KV read: instead of materializing one dense buffer of
+the cold prefix and the hot window before attention, KV lives in
 fixed-size sequence *pages* split across two physical pools, head-major so
 that one (page, head) block is a whole ``(page, D)`` tile —
 

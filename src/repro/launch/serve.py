@@ -56,7 +56,6 @@ def tiered_server(cfg, params, requests, *, slots: int, max_seq: int,
     under the same plan geometry (the all-HBM control of the same kernel).
     ``pool_prefill=False`` admits by dense prefill, as the disaggregated
     engine does.  Returns ``(batcher, plan)``."""
-    cfg = dataclasses.replace(cfg, use_paged_decode=True)
     trace = engine.serve_trace_for(cfg, [(len(t), d) for t, d in requests],
                                    slots=slots, params=params,
                                    layer_group=8)
@@ -65,7 +64,7 @@ def tiered_server(cfg, params, requests, *, slots: int, max_seq: int,
         plan = dataclasses.replace(plan, hot_window=max_seq,
                                    slot_hot_windows=None)
     b = engine.ContinuousBatcher(params, cfg, slots, max_seq, plan=plan,
-                                 paged=True, device=device,
+                                 device=device,
                                  pool_prefill=pool_prefill)
     for tokens, d in requests:
         b.submit(tokens, d)

@@ -79,12 +79,12 @@ def attention(params, cfg, x, positions, *, kind: str = ATTN,
 
     Returns (out, updated_cache). cache_index: scalar write offset (decode).
 
-    paged_view (decode only, ``cfg.use_paged_decode``): the serving engine's
-    page layout — {"boundaries": per-slot cold tokens (python ints),
-    "page_tokens": page size}.  The attention core then reads KV through
-    ``ops.paged_decode_attention``: the updated cache is packed into a
-    device-resident hot pool and a host-resident cold pool addressed by a
-    per-slot page table, instead of attending over the dense merged buffer.
+    A cache holding the persistent page pools (``"k_hot"``,
+    kvcache.PagedKVPools) selects the pools path: ``paged_view`` then
+    carries the serving engine's page table, tiers and page size, decode
+    reads KV through ``ops.paged_decode_attention`` (``_pool_decode_core``)
+    and a prefill chunk through a gather of the slot's table row
+    (``_pool_prefill_core``, ``paged_view["prefill"]``).
     """
     B, Sq, d = x.shape
     H, KV, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
@@ -136,13 +136,6 @@ def attention(params, cfg, x, positions, *, kind: str = ATTN,
         new_cache = {"k": constrain(k_all, ("batch", "kv_seq", "kv_heads")),
                      "v": constrain(v_all, ("batch", "kv_seq", "kv_heads"))}
         Smax = k_all.shape[1]
-        if (paged_view is not None and cfg.use_paged_decode and Sq == 1
-                and cache_index is not None and not cfg.prefix_lm):
-            out = _paged_decode_core(cfg, q, k_all, v_all, cache_index,
-                                     paged_view, window)
-            out = out.reshape(B, Sq, H * hd)
-            out = constrain(out, ("batch", "seq", "heads"))
-            return out @ params["wo"], new_cache
         k_use = k_all.reshape(B, Smax, KV, hd)
         v_use = v_all.reshape(B, Smax, KV, hd)
     else:
@@ -157,42 +150,6 @@ def attention(params, cfg, x, positions, *, kind: str = ATTN,
     out = out.reshape(B, Sq, H * hd)
     out = constrain(out, ("batch", "seq", "heads"))
     return out @ params["wo"], new_cache
-
-
-def _paged_decode_core(cfg, q, k_all, v_all, cache_index, paged_view, window):
-    """Decode attention through the tiered page pools (ROADMAP item: decode
-    consumes the page pools directly instead of the dense merged buffer).
-
-    The just-updated dense cache is split at each slot's cold boundary into
-    the hot/cold pool layout of kernels/paged_decode.py and read back through
-    the per-slot page table — on TPU the Pallas kernel streams each page
-    into a double-buffered VMEM window; on CPU the bit-equivalent jnp oracle
-    runs (dispatch in kernels/ops.py).  ``boundaries`` and
-    ``page_tokens`` must be concrete python ints (pool packing builds the
-    page table at trace time), which the serving engine guarantees; the
-    engine precomputes the layer-independent ``layout`` (page table, tier,
-    pool order) once per decode step so only the per-layer pool gathers run
-    here.
-    """
-    from repro.kernels import ops as kernel_ops
-    from repro.kernels.paged_decode import gather_pools, pool_layout
-
-    B, Sq, KV, G, hd = q.shape
-    Smax = k_all.shape[1]
-    page = paged_view["page_tokens"]
-    layout = paged_view.get("layout")
-    if layout is None:
-        layout = pool_layout(paged_view["boundaries"], Smax // page, page)
-    k4 = k_all.reshape(B, Smax, KV, hd)
-    v4 = v_all.reshape(B, Smax, KV, hd)
-    k_hot, v_hot, k_cold, v_cold = gather_pools(k4, v4, layout, page)
-    table, tier = layout[0], layout[1]
-    ci = jnp.asarray(cache_index, jnp.int32)
-    lengths = (ci if ci.ndim >= 1 else jnp.broadcast_to(ci, (B,))) + 1
-    out = kernel_ops.paged_decode_attention(
-        q.reshape(B, KV * G, hd), k_hot, v_hot, k_cold, v_cold, table, tier,
-        lengths, window=window, softcap_val=cfg.attn_softcap)
-    return out
 
 
 def _pool_prefill_core(cfg, q, k, v, cache, cache_index, paged_view, window,

@@ -1,21 +1,21 @@
 """Cache construction for serving: per-layer-kind cache buffers, stacked over
 periods to match the scanned layer stack.
 
-Tier-aware construction (Sentinel-Serve): a cache can be split along the KV
-sequence dimension into a *cold prefix* (old tokens, host/slow memory) and a
-*hot window* (recent tokens, HBM/fast memory), per the decode-phase
-``ServePlan``.  The cold tier lives in host memory — ``pinned_host`` on TPU,
-and on the CPU backend too, which exposes it beside its ``device`` memory —
-so every op that reads a cold array next to device arrays states its
-transfer (``to_device`` / ``PagedKVPools.stage_cold``) and every write lands
-back in host memory explicitly (``to_host`` / ``cold_page_put``).
+Tier-aware storage (Sentinel-Serve): ``PagedKVPools`` splits each slot's KV
+sequence, at page granularity, into a *cold prefix* (old tokens, host/slow
+memory) and a *hot window* (recent tokens, HBM/fast memory), per the
+decode-phase ``ServePlan``.  The cold tier lives in host memory —
+``pinned_host`` on TPU, and on the CPU backend too, which exposes it beside
+its ``device`` memory — so every op that reads a cold array next to device
+arrays states its transfer (``to_device`` / ``PagedKVPools.stage_cold``) and
+every write lands back in host memory explicitly (``to_host`` /
+``cold_page_put``).
 """
 from __future__ import annotations
 
 import functools
 import heapq
 import math
-from dataclasses import dataclass
 from typing import Any, Dict, Optional
 
 import jax
@@ -234,39 +234,10 @@ def kv_token_bytes(cfg, dtype_bytes: int = 2) -> float:
     return total / cfg.num_layers if cfg.num_layers else 0.0
 
 
-def _is_seq_leaf(leaf, max_seq: int) -> bool:
-    # KV buffers carry the sequence at axis -2: (B, S, H), (P, B, S, H),
-    # (B, S, rank).  Stateful caches (mamba/lstm conv+state) never match as
-    # long as no state dim equals max_seq — hold for every non-trivial
-    # max_seq in this repo.
-    return leaf.ndim >= 3 and leaf.shape[-2] == max_seq
-
-
-def split_seq_cache(caches, max_seq: int, cold_len: int):
-    """Split every seq-dim leaf at ``cold_len``: (cold_prefix, hot_window).
-    Non-seq leaves stay whole in the hot tree; their cold slot is None."""
-    cold = jax.tree.map(
-        lambda l: l[..., :cold_len, :] if _is_seq_leaf(l, max_seq) else None,
-        caches)
-    hot = jax.tree.map(
-        lambda l: l[..., cold_len:, :] if _is_seq_leaf(l, max_seq) else l,
-        caches)
-    return cold, hot
-
-
-def merge_seq_cache(cold, hot):
-    """Inverse of split_seq_cache.  A host-resident cold leaf is brought to
-    its device first — the cold-KV fetch, stated explicitly."""
-    return jax.tree.map(
-        lambda c, h: h if c is None else jnp.concatenate(
-            [to_device(c), h], axis=-2),
-        cold, hot, is_leaf=lambda x: x is None)
-
-
 def splice_slot(big_tree, one_tree, slot: int, batch: int):
     """Write a single-request cache (batch 1) into row ``slot`` of a batched
-    cache — the continuous-batching cache splice.  Works on full, cold, and
-    hot trees alike (None leaves pass through).  Dispatch is async: the copy
+    cache — the continuous-batching cache splice (None leaves pass
+    through).  Dispatch is async: the copy
     overlaps with whatever decode work is already enqueued.
 
     Batch-axis position is decided by cache *structure*, not leaf shapes:
@@ -301,56 +272,15 @@ def splice_slot(big_tree, one_tree, slot: int, batch: int):
     return jax.tree.map(guess, big_tree, one_tree, is_leaf=none_leaf)
 
 
-@dataclass
-class TieredCache:
-    """A cache split into a host-resident cold prefix and a fast hot window."""
-    cold: Any
-    hot: Any
-    cold_len: int
-    max_seq: int
-
-    def merged(self):
-        return merge_seq_cache(self.cold, self.hot)
-
-
-def init_tiered_cache(cfg, batch: int, max_seq: int, cold_len: int,
-                      dtype=jnp.bfloat16) -> TieredCache:
-    """Tier-aware cache construction: the cold KV prefix is placed in host
-    memory, the hot window (and all stateful caches) stay in device memory."""
-    cold_len = max(0, min(int(cold_len), max_seq))
-    full = init_cache(cfg, batch, max_seq, dtype)
-    cold, hot = split_seq_cache(full, max_seq, cold_len)
-    return TieredCache(to_host(cold), hot, cold_len, max_seq)
-
-
-def retier(caches, max_seq: int, cold_len: int) -> TieredCache:
-    """Split an existing full cache (e.g. fresh from prefill) into tiers."""
-    cold, hot = split_seq_cache(caches, max_seq, cold_len)
-    return TieredCache(to_host(cold), hot, cold_len, max_seq)
-
-
 # ------------------------------------------------------- paged (serve) ----
 #
-# Per-slot cold boundaries need a representation that splits the KV sequence
-# at a *different* point per batch row, which a single slice cannot express.
-# Two pieces:
-#
-#   PageTable        the metadata manager: logical (slot, page) -> physical
-#                    page in the hot or cold pool, with alloc/free/splice at
-#                    page granularity and the cold-prefix invariant (a slot's
-#                    cold pages are always a prefix of its logical pages).
-#                    This is the layout kernels/paged_decode.py consumes.
-#   PagedTieredCache the pytree storage consumed by the jnp model path on
-#                    CPU: full-size hot (device) and cold (host) trees with a
-#                    per-slot boundary vector; ``merged()`` is a masked
-#                    where-merge that reads cold rows below each slot's
-#                    boundary and hot rows above it — bit-identical to the
-#                    dense cache because every row was copied from the dense
-#                    values when it changed tier.
-#
-# On TPU the PageTable's pools are the real storage and the paged kernel
-# streams cold pages over PCIe; on CPU (CI) the two-buffer masked form is the
-# placement simulation, with migration bytes tracked by the serving engine.
+# Per-slot cold boundaries split the KV sequence at a *different* point per
+# batch row, at page granularity.  ``PageTable`` is the metadata manager:
+# logical (slot, page) -> physical page in the hot or cold pool, with
+# alloc/free/share at page granularity and the cold-prefix invariant (a
+# slot's cold pages are always a prefix of its logical pages).
+# ``PagedKVPools`` below holds the pools it addresses — the layout
+# kernels/paged_decode.py reads.
 
 
 class PageTable:
@@ -808,114 +738,6 @@ class MeshPageTable:
                 f"{name}: {self.bytes_in[name]} bytes arrived but " \
                 f"{in_sum[name]} attributed to edges"
         assert self.host_internal_bytes >= 0
-
-
-def copy_slot_rows(dst_tree, src_tree, slot: int, lo: int, hi: int,
-                   max_seq: int):
-    """dst[slot, lo:hi] = src[slot, lo:hi] on every seq-dim leaf; None and
-    non-seq leaves pass through.  Both trees are full-size batched caches in
-    the init_cache layout (batch-axis position decided by structure, as in
-    splice_slot).  This is the per-slot page demotion / re-host primitive:
-    only the named slot's rows move, nothing else is touched.  The seq-leaf
-    test runs on ``src`` (always a full ``max_seq`` cache), so ``dst`` may be
-    a cold *slice* whose seq dim is shorter — rows [lo, hi) must be valid in
-    both."""
-    def one(stacked):
-        def f(dst, src):
-            if dst is None or src is None or not _is_seq_leaf(src, max_seq):
-                return dst
-            if stacked:                                   # (P, B, S, H)
-                return dst.at[:, slot, lo:hi].set(src[:, slot, lo:hi])
-            return dst.at[slot, lo:hi].set(src[slot, lo:hi])
-        return f
-
-    none_leaf = lambda x: x is None
-    assert isinstance(dst_tree, dict) and set(dst_tree) == {"prologue",
-                                                            "slots"}
-    return {"prologue": jax.tree.map(one(False), dst_tree["prologue"],
-                                     src_tree["prologue"], is_leaf=none_leaf),
-            "slots": jax.tree.map(one(True), dst_tree["slots"],
-                                  src_tree["slots"], is_leaf=none_leaf)}
-
-
-@dataclass
-class PagedTieredCache:
-    """Cache with per-slot cold boundaries at page granularity.
-
-    ``hot`` is the full-size device tree (the working copy every decode step
-    writes into); ``cold`` holds host-resident copies of each slot's rows
-    below its boundary.  ``boundaries[b]`` is slot b's cold-token count,
-    always a multiple of ``page_tokens`` and monotone within one residency.
-    """
-    cold: Any
-    hot: Any
-    boundaries: Any               # (B,) int32 cold tokens per slot
-    page_tokens: int
-    max_seq: int
-    # host-side mirror of ``boundaries``: updated incrementally on admit /
-    # demote so per-step planning never round-trips the device array
-    host_boundaries: Optional[list] = None
-
-    def merged(self):
-        """Masked where-merge: rows below each slot's boundary read the cold
-        (host) copy — inside jit this read IS the streamed cold-KV fetch —
-        rows above it read the hot tree.  Bit-identical to the dense cache.
-        The cold tree is brought to the device explicitly first."""
-        b = jnp.asarray(self.boundaries, jnp.int32)
-        cold = to_device(self.cold)
-        pos = jnp.arange(self.max_seq)
-
-        def one(stacked):
-            def f(c, h):
-                if c is None or not _is_seq_leaf(h, self.max_seq):
-                    return h
-                mask = pos[None, :, None] < b[:, None, None]   # (B, S, 1)
-                if stacked:
-                    mask = mask[None]                          # (1, B, S, 1)
-                return jnp.where(mask, c, h)
-            return f
-
-        none_leaf = lambda x: x is None
-        return {"prologue": jax.tree.map(one(False), cold["prologue"],
-                                         self.hot["prologue"],
-                                         is_leaf=none_leaf),
-                "slots": jax.tree.map(one(True), cold["slots"],
-                                      self.hot["slots"], is_leaf=none_leaf)}
-
-    def set_boundary(self, slot: int, cold_tokens: int):
-        assert cold_tokens % self.page_tokens == 0
-        if self.host_boundaries is None:
-            self.host_boundaries = [0] * len(jnp.asarray(self.boundaries))
-        self.host_boundaries[slot] = int(cold_tokens)
-        self.boundaries = jnp.asarray(self.boundaries).at[slot].set(
-            cold_tokens)
-
-    def demote_rows(self, slot: int, new_cold_tokens: int):
-        """Advance slot's boundary: copy rows [old, new) from hot into the
-        host-resident cold tree — only this slot's pages move.  The old
-        boundary comes from the host-side mirror (no device round-trip)."""
-        old = self.host_boundaries[slot] if self.host_boundaries is not None \
-            else int(jnp.asarray(self.boundaries)[slot])
-        if new_cold_tokens <= old:
-            return 0
-        self.cold = to_host(copy_slot_rows(to_device(self.cold), self.hot,
-                                           slot, old, new_cold_tokens,
-                                           self.max_seq))
-        self.set_boundary(slot, new_cold_tokens)
-        return new_cold_tokens - old
-
-
-def init_paged_cache(cfg, batch: int, max_seq: int, page_tokens: int,
-                     dtype=jnp.bfloat16) -> PagedTieredCache:
-    """Paged tier-aware construction: boundaries start at zero (everything
-    hot); the cold tree mirrors the seq-leaf structure in host memory."""
-    assert max_seq % page_tokens == 0, (max_seq, page_tokens)
-    hot = init_cache(cfg, batch, max_seq, dtype)
-    cold = jax.tree.map(
-        lambda l: l if _is_seq_leaf(l, max_seq) else None, hot)
-    return PagedTieredCache(to_host(cold), hot,
-                            jnp.zeros((batch,), jnp.int32), page_tokens,
-                            max_seq, host_boundaries=[0] * batch)
 
 
 # ------------------------------------------------- persistent pools (serve) --

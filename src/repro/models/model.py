@@ -63,9 +63,10 @@ def forward(params, cfg, batch, *, caches=None, cache_index=None,
             positions=None, paged_view=None) -> Tuple[jax.Array, Any, jax.Array]:
     """Returns (logits, new_caches, aux_loss).
 
-    paged_view: with ``cfg.use_paged_decode``, the serving engine's page
-    layout ({"boundaries", "page_tokens"}); decode attention then reads KV
-    through the tiered page pools (models/attention._paged_decode_core)."""
+    paged_view: with ``caches`` holding the persistent page pools
+    (kvcache.PagedKVPools), the serving engine's page table, tiers and page
+    size; attention then reads KV through the pools
+    (models/attention._pool_decode_core)."""
     with jax.named_scope("boundary_in"):
         if decode:
             x = embed(params["embed"], cfg, batch["tokens"])

@@ -30,8 +30,8 @@ decode schedule.
 The resulting ``PlacementPlan`` subsumes the legacy training ``Plan`` and
 serving ``ServePlan``: it drives the JAX offload engine
 (``core/offload.from_plan`` — ``mi`` is the layer-scan block size), the
-serving engine (``serve/engine.ContinuousBatcher`` — ``cold_len`` /
-``cold_len_slot`` / ``page_tokens``), and the benchmarks; ``to_json`` /
+serving engine (``serve/engine.ContinuousBatcher`` — ``cold_len_slot`` /
+``page_tokens``), and the benchmarks; ``to_json`` /
 ``from_json`` round-trip it bit-identically for storage beside benchmark
 artifacts.  Where each paper equation lands in the code is mapped in
 ``docs/RUNTIME_API.md``.
@@ -220,8 +220,9 @@ class PlacementPlan:
         return self.sim.tokens / t if (self.sim and t) else 0.0
 
     def cold_len(self, max_seq: int) -> int:
-        """Cold-prefix length for a ``max_seq``-token cache buffer (global
-        boundary — the concat path)."""
+        """Cold-prefix length of a ``max_seq``-token sequence under the
+        global hot window, in tokens (per slot, in whole pages:
+        ``cold_len_slot``)."""
         return max(0, max_seq - self.hot_window)
 
     def slot_window(self, slot: int) -> int:

@@ -81,10 +81,9 @@ class DisaggregatedEngine(ContinuousBatcher):
     page-table ledger still counts the logical edge traffic).  With N > 1
     decode devices each batch slot lives on exactly one shard — taken from
     the plan's ``slot_devices`` (round-robin when the plan carries none) —
-    and decode runs one sub-batch forward per shard.  Requires the
-    persistent-pools layout (``cfg.use_paged_decode``), which is what makes
-    steady-state decode re-pack-free — the streamed pages land directly in
-    the decode pools.
+    and decode runs one sub-batch forward per shard.  Requires a plan: the
+    persistent pools are what make steady-state decode re-pack-free — the
+    streamed pages land directly in the decode pools.
     """
 
     def __init__(self, params, cfg, batch_slots: int, max_seq: int,
@@ -105,12 +104,8 @@ class DisaggregatedEngine(ContinuousBatcher):
         # pools from the wrong device, so the dense admission stays on (and
         # chunked prefill is refused)
         super().__init__(params, cfg, batch_slots, max_seq, scfg=scfg,
-                         plan=plan, paged=True, slot_tenants=slot_tenants,
+                         plan=plan, slot_tenants=slot_tenants,
                          device=self.decode_device, pool_prefill=False)
-        if self.pool is None:
-            raise ValueError(
-                "DisaggregatedEngine needs the persistent pools layout: "
-                "set cfg.use_paged_decode (and not cfg.prefix_lm)")
         pg = self.page_tokens
         self.device_hot_peak: dict = {}    # shard name -> peak hot pool bytes
         self._dev_note_version = None
@@ -220,28 +215,12 @@ class DisaggregatedEngine(ContinuousBatcher):
         pg = self.page_tokens
         d = self._shard_of(slot)
         pool = self.pools[d]
-        table = pool.table
-        # stale donor registrations for this slot die with its pages
-        for key in [k for k, (s, _) in self._prefix_donor.items()
-                    if s == slot]:
-            del self._prefix_donor[key]
-        pool.free_slot(slot)
-        shared_pages = 0
-        if prefix_key is not None:
-            donor = self._prefix_donor.get(prefix_key)
-            # intra-shard only: physical pages cannot alias across HBMs
-            if donor is not None and donor[0] != slot and \
-                    self._shard_of(donor[0]) == d and \
-                    table.n_pages[donor[0]] > 0:
-                lcp = 0
-                for a, b in zip(tok_host, donor[1]):
-                    if a != b:
-                        break
-                    lcp += 1
-                shared_pages = min(lcp // pg, table.n_pages[donor[0]])
-                if shared_pages:
-                    pool.share(slot, donor[0], shared_pages)
-            self._prefix_donor[prefix_key] = (slot, tok_host)
+        # intra-shard only: physical pages cannot alias across HBMs, and a
+        # slot's pages live only in its own shard's pool (a re-home moves
+        # them, an ownership change drops a finished slot's), so a donor on
+        # another shard holds no pages in ``pool`` and shares none
+        shared_pages = self._share_prefix(slot, tok_host, prefix_key,
+                                          pool=pool)
         n = -(-S // pg)
         self._alloc_admit_pages(slot, n)
         # the private tail's KV crosses the prefill->shard edge here

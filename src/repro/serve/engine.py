@@ -4,40 +4,32 @@
 function the decode-shape dry-run cells lower; ``generate`` drives it for the
 examples/benchmarks with greedy or temperature sampling.
 
-Sentinel-Serve: ``ContinuousBatcher`` optionally consults a decode-phase
-``ServePlan`` (core/planner.plan_serve).  With a plan, each slot's KV cache is
-tiered — the cold prefix (tokens older than the plan's hot window) lives in
-host memory, the hot window in HBM — and slot refills splice the prefilled
-cache into both tiers asynchronously.  Logits are bit-identical to the
-all-HBM path: the merged view reads the same values, only their placement
-(and therefore fetch bandwidth) differs.
+Sentinel-Serve: ``ContinuousBatcher`` serves from one of two caches.
 
-Three tiered layouts:
+  dense  (``plan=None``)  one all-HBM batched cache (kvcache.init_cache),
+      decoded by the eager ``model.forward``: the reference the tiered
+      path is held to, token for token.
+  pools  (a ``ServePlan``)  the persistent physical page pools
+      (kvcache.PagedKVPools) ARE the cache.  Each slot's cold prefix —
+      whole pages older than the plan's hot window for that slot
+      (``plan.cold_len_slot``) — lives in host memory, the rest in HBM;
+      decode writes each token's KV into its physical hot page through the
+      page table and attention reads the pools via
+      ops.paged_decode_attention (or the latent kernel for MLA layers), in
+      one compiled step (``make_pool_decode_step``).  Stateful layers keep
+      their dense per-slot state inside the pools' tree.  Steady-state
+      ``step()`` performs zero dense re-packs and zero boundary host-syncs —
+      layout state lives host-side in the PageTable and changes only on
+      admit / page-crossing / demote / free events.  Requests submitted
+      with a ``prefix_key`` share their common prompt prefix *physically*:
+      full pages below the fork point map to the same refcounted physical
+      pages (copy-on-write on the first divergent write), so N tenants with
+      one system prompt hold its KV once.
 
-  concat (``paged=False``)  one *global* cold boundary (``plan.cold_len``);
-      the cold tree is a sequence slice, reads concatenate cold+hot.  Simple,
-      but every slot pays the same boundary and a refill re-hosts the full
-      global prefix for that slot.
-  paged  (``paged=True``)   *per-slot* boundaries at page granularity
-      (``plan.cold_len_slot``), backed by kvcache.PagedTieredCache plus a
-      kvcache.PageTable that allocates/frees/demotes physical pages.  The
-      dense hot tree remains the working copy; the masked merge reads it.
-  pools  (``paged=True`` + ``cfg.use_paged_decode``)  the persistent
-      physical page pools (kvcache.PagedKVPools) ARE the cache: decode
-      writes each token's KV into its physical hot page through the page
-      table and attention reads the pools via ops.paged_decode_attention.
-      Steady-state ``step()`` performs zero dense re-packs and zero
-      boundary host-syncs — layout state lives host-side in the PageTable
-      and changes only on admit / page-crossing / demote / free events.
-      Requests submitted with a ``prefix_key`` share their common prompt
-      prefix *physically*: full pages below the fork point map to the same
-      refcounted physical pages (copy-on-write on the first divergent
-      write), so N tenants with one system prompt hold its KV once.
-
+Logits are bit-identical between the two: the pools hold the same values,
+only their placement (and therefore fetch bandwidth) differs.
 ``sim_migration_bytes`` counts every byte the batcher moves device<->host
-(cold re-hosting), so the layouts' migration traffic is directly comparable
-(benchmarks/bench_serve.py --paged gates paged <= concat; --shared-prefix
-gates shared < unshared).
+(benchmarks/bench_serve.py --shared-prefix gates shared < unshared).
 
 Every ``step()`` is one tree of ``repro.obs`` spans (``sentinel.step`` over
 ``sentinel.admit``, ``sentinel.prefill``, ``sentinel.decode``,
@@ -49,7 +41,7 @@ from __future__ import annotations
 
 import time
 from collections import deque
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 from typing import Any, Optional, Sequence
 
 import jax
@@ -140,11 +132,9 @@ class ContinuousBatcher:
 
     def __init__(self, params, cfg, batch_slots: int, max_seq: int,
                  scfg: Optional[ServeConfig] = None, plan=None,
-                 paged: bool = False, slot_tenants=None,
+                 slot_tenants=None,
                  prefill_chunk_tokens: Optional[int] = None, device=None,
                  pool_prefill: bool = True):
-        if paged and plan is None:
-            raise ValueError("paged=True requires a ServePlan (plan=...)")
         self.params, self.cfg = params, cfg
         self.B, self.max_seq = batch_slots, max_seq
         self.scfg = scfg or ServeConfig(max_seq=max_seq)
@@ -173,7 +163,6 @@ class ContinuousBatcher:
                 f"{batch_slots} batch slots (plan/batch geometry mismatch)")
         self.tenant_hot_peak: dict = {}        # tenant -> peak hot pool bytes
         self._tenant_note_version = -1         # last-sampled table version
-        self.cold_len = plan.cold_len(max_seq) if plan is not None else 0
         dt = jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
         dt_bytes = 2 if dt == jnp.bfloat16 else 4
         self._row_bytes = kvcache.kv_token_bytes(cfg, dt_bytes) \
@@ -189,28 +178,18 @@ class ContinuousBatcher:
         # sum(step_migration_bytes) == sim_migration_bytes always.
         self.step_migration_bytes: list = []
         self._mig_accounted = 0.0
-        self.paged = self.tiered = self.caches = self.ptable = None
-        self.pool = None
+        self.caches = self.ptable = self.pool = None
         self.device = device                       # pools' device (None: default)
-        if paged:
+        if plan is not None:
+            # persistent pools: the page table owns physical placement,
+            # decode writes through it (no dense mirror to re-pack)
             page = self._plan_page_tokens(plan)
             self.page_tokens = page
-            if cfg.use_paged_decode and not cfg.prefix_lm:
-                # persistent pools: the page table owns physical placement,
-                # decode writes through it (no dense mirror to re-pack)
-                self.pool = kvcache.PagedKVPools(cfg, batch_slots, max_seq,
-                                                 page, dt, device=device)
-                self.ptable = self.pool.table
-                self._decode_step = make_pool_decode_step(
-                    cfg, page, self.pool.garbage)
-            else:
-                self.paged = kvcache.init_paged_cache(cfg, batch_slots,
-                                                      max_seq, page, dt)
-                self.ptable = kvcache.PageTable(batch_slots, max_seq // page,
-                                                page)
-        elif self.cold_len > 0:
-            self.tiered = kvcache.init_tiered_cache(cfg, batch_slots, max_seq,
-                                                    self.cold_len, dt)
+            self.pool = kvcache.PagedKVPools(cfg, batch_slots, max_seq, page,
+                                             dt, device=device)
+            self.ptable = self.pool.table
+            self._decode_step = make_pool_decode_step(cfg, page,
+                                                      self.pool.garbage)
         else:
             self.caches = kvcache.init_cache(cfg, batch_slots, max_seq, dt)
         self.lengths = jnp.zeros((batch_slots,), jnp.int32)
@@ -256,8 +235,8 @@ class ContinuousBatcher:
             and not cfg.num_codebooks)
         if self.prefill_chunk_tokens and not self._pool_prefill_ok:
             raise ValueError(
-                "prefill_chunk_tokens requires pool-direct prefill: the "
-                "persistent-pools layout with an attention / MLA stack, on a "
+                "prefill_chunk_tokens requires pool-direct prefill: a plan "
+                "(the persistent pools) with an attention / MLA stack, on a "
                 "colocated engine (the DisaggregatedEngine prefills whole "
                 "prompts on its prefill group)")
 
@@ -369,27 +348,9 @@ class ContinuousBatcher:
         splice — the pool-direct scheduler writes through attention instead;
         this path remains for the disaggregated engine and mixed stacks.)"""
         pg = self.page_tokens
-        # stale donor registrations for this slot die with its pages
-        for key in [k for k, (s, _) in self._prefix_donor.items()
-                    if s == slot]:
-            del self._prefix_donor[key]
-        self.pool.free_slot(slot)
-        shared_pages = 0
-        if prefix_key is not None:
-            donor = self._prefix_donor.get(prefix_key)
-            if donor is not None and donor[0] != slot and \
-                    self.ptable.n_pages[donor[0]] > 0:
-                lcp = 0
-                for a, b in zip(tok_host, donor[1]):
-                    if a != b:
-                        break
-                    lcp += 1
-                # only full pages strictly below the write region are shared,
-                # so the page decode writes into is never a shared page
-                shared_pages = min(lcp // pg, self.ptable.n_pages[donor[0]])
-                if shared_pages:
-                    self.pool.share(slot, donor[0], shared_pages)
-            self._prefix_donor[prefix_key] = (slot, tok_host)
+        # only full pages strictly below the write region are shared, so the
+        # page decode writes into is never a shared page
+        shared_pages = self._share_prefix(slot, tok_host, prefix_key)
         n = -(-S // pg)
         self._alloc_admit_pages(slot, n)
         self.pool.admit_rows(fresh, slot, range(shared_pages, n))
@@ -399,6 +360,39 @@ class ContinuousBatcher:
         with obs.span("sentinel.demote") as attrs:
             attrs["pages"] = self._demote_to(
                 slot, self._slot_cold_target(slot, S))
+
+    def _share_prefix(self, slot: int, tok_host, prefix_key,
+                      cap: Optional[int] = None, pool=None) -> int:
+        """The head of every pools admission into ``pool`` (default: the
+        engine's own): free the slot's old pages (and the donor
+        registrations that named them), map the full pages of the prompt's
+        common prefix with the ``prefix_key``'s donor onto the donor's
+        physical pages, and register this prompt as the new donor.  The
+        shared pages are capped by the pages the donor holds in ``pool``
+        and by ``cap`` (None: no further cap).  Returns the pages shared."""
+        pool = pool or self.pool
+        for key in [k for k, (s, _) in self._prefix_donor.items()
+                    if s == slot]:
+            del self._prefix_donor[key]
+        pool.free_slot(slot)
+        shared = 0
+        if prefix_key is not None:
+            donor = self._prefix_donor.get(prefix_key)
+            if donor is not None and donor[0] != slot and \
+                    pool.table.n_pages[donor[0]] > 0:
+                lcp = 0
+                for a, b in zip(tok_host, donor[1]):
+                    if a != b:
+                        break
+                    lcp += 1
+                shared = min(lcp // self.page_tokens,
+                             pool.table.n_pages[donor[0]])
+                if cap is not None:
+                    shared = min(shared, cap)
+                if shared:
+                    pool.share(slot, donor[0], shared)
+            self._prefix_donor[prefix_key] = (slot, tok_host)
+        return shared
 
     def _demote_to(self, slot: int, target: int, pool=None) -> int:
         """Advance ``slot``'s cold boundary page by page to ``target``
@@ -428,7 +422,8 @@ class ContinuousBatcher:
         creates a ``_PrefillJob`` (``queued -> prefilling``) and
         ``_drain_prefill`` runs page-aligned chunks up to the per-step
         budget; a slot flips ``-> active`` only when its whole prompt's KV
-        is materialized.  Other layouts admit one-shot as before."""
+        is materialized.  Other stacks, and the dense reference, admit
+        one-shot (``_admit_dense``)."""
         for slot in range(self.B):
             if self.active[slot] or slot in self._jobs:
                 continue
@@ -450,34 +445,15 @@ class ContinuousBatcher:
 
     def _admit_dense(self, slot: int, tokens, tok_host, S: int, budget: int,
                      prefix_key):
-        """Legacy one-shot admission: dense full-prompt prefill, then a
-        layout-specific cache splice (async dispatch: overlaps with
-        in-flight decode work)."""
+        """One-shot admission: dense full-prompt prefill, then its rows
+        spliced into the pools or the dense cache (async dispatch: overlaps
+        with in-flight decode work)."""
         with obs.span("sentinel.prefill", rid=self._requests[slot][0],
                       slot=slot, start=0, end=S):
             last, fresh = self._prefill(self.params,
                                         {"tokens": tokens[None]})
             if self.pool is not None:
                 self._admit_pool(slot, tok_host, fresh, S, prefix_key)
-            elif self.paged is not None:
-                # per-slot boundary: only THIS slot's cold pages re-host
-                cold = self._slot_cold_target(slot, S)
-                self.ptable.splice_slot(slot, S, cold)
-                self.paged.hot = kvcache.splice_slot(self.paged.hot, fresh,
-                                                     slot, self.B)
-                self.paged.set_boundary(slot, 0)
-                if cold:
-                    self.paged.demote_rows(slot, cold)
-                self.sim_migration_bytes += cold * self._row_bytes
-            elif self.tiered is not None:
-                fc, fh = kvcache.split_seq_cache(fresh, self.max_seq,
-                                                 self.cold_len)
-                self.tiered.cold = kvcache.to_host(kvcache.splice_slot(
-                    kvcache.to_device(self.tiered.cold), fc, slot, self.B))
-                self.tiered.hot = kvcache.splice_slot(
-                    self.tiered.hot, fh, slot, self.B)
-                # global boundary: the full cold prefix re-hosts on refill
-                self.sim_migration_bytes += self.cold_len * self._row_bytes
             else:
                 self.caches = kvcache.splice_slot(self.caches, fresh, slot,
                                                   self.B)
@@ -532,31 +508,13 @@ class ContinuousBatcher:
         ``done = shared_pages * page_tokens``), and register this prompt as
         the new donor."""
         pg = self.page_tokens
-        for key in [k for k, (s, _) in self._prefix_donor.items()
-                    if s == slot]:
-            del self._prefix_donor[key]
-        self.pool.free_slot(slot)
-        shared = 0
-        if job.prefix_key is not None:
-            donor = self._prefix_donor.get(job.prefix_key)
-            if donor is not None and donor[0] != slot and \
-                    self.ptable.n_pages[donor[0]] > 0:
-                lcp = 0
-                for a, b in zip(job.tok_host, donor[1]):
-                    if a != b:
-                        break
-                    lcp += 1
-                # capped three ways: full pages of common prefix, pages the
-                # donor actually holds (a mid-prefill donor's pages are valid
-                # only up to its own ``done``), and strictly below the
-                # prompt's last token — the suffix pass must compute at least
-                # one row (the next-token logits), and a shared page is
-                # never written
-                shared = min(lcp // pg, self.ptable.n_pages[donor[0]],
-                             (job.S - 1) // pg)
-                if shared:
-                    self.pool.share(slot, donor[0], shared)
-            self._prefix_donor[job.prefix_key] = (slot, job.tok_host)
+        # capped three ways: full pages of common prefix, pages the donor
+        # actually holds (a mid-prefill donor's pages are valid only up to
+        # its own ``done``), and strictly below the prompt's last token —
+        # the suffix pass must compute at least one row (the next-token
+        # logits), and a shared page is never written
+        shared = self._share_prefix(slot, job.tok_host, job.prefix_key,
+                                    cap=(job.S - 1) // pg)
         job.shared_pages = shared
         job.done = shared * pg
         job.started = True
@@ -698,53 +656,12 @@ class ContinuousBatcher:
                 prompt_tokens=S)
 
     def _dense_decode_step(self):
-        """One decode forward on the dense, concat or paged layouts (every
-        layout but the persistent pools).  Returns the decoded tokens."""
-        if self.paged is not None:
-            caches = self.paged.merged()
-        elif self.tiered is not None:
-            caches = self.tiered.merged()
-        else:
-            caches = self.caches
+        """One decode forward on the dense no-plan cache: the all-HBM
+        reference the pools are held to.  Returns the decoded tokens."""
         with obs.span("sentinel.forward"):
-            logits, new_caches, _ = model.forward(
+            logits, self.caches, _ = model.forward(
                 self.params, self.cfg, {"tokens": self.last_tok[:, None]},
-                caches=caches, cache_index=self.lengths, decode=True)
-        if self.paged is not None:
-            self.paged.hot = new_caches
-            # advance each active slot's own boundary: when the new
-            # length pushes a page out of the slot's hot window, demote
-            # just that page (hot -> cold pool in the table, rows
-            # re-hosted)
-            for s in range(self.B):
-                if not self.active[s]:
-                    continue
-                new_len = self._host_len[s] + 1
-                while self.ptable.n_pages[s] * self.page_tokens < new_len:
-                    self.ptable.alloc(s, 0)    # decode grew into a new page
-                target = self._slot_cold_target(s, new_len)
-                moved = self.paged.demote_rows(s, target)
-                while self.ptable.cold_tokens(s) < target:
-                    self.ptable.demote(s, self.ptable.cold_pages(s))
-                self.sim_migration_bytes += moved * self._row_bytes
-            self._note_tenant_pages()
-        elif self.tiered is not None:
-            _, hot = kvcache.split_seq_cache(new_caches, self.max_seq,
-                                             self.cold_len)
-            self.tiered.hot = hot
-            # this step's KV writes land at each slot's length; a write
-            # inside the prefix (short slots) re-hosts only that slot's
-            # row, not a re-split of the whole batch cache
-            for s in range(self.B):
-                if self.active[s] and self._host_len[s] < self.cold_len:
-                    pos = self._host_len[s]
-                    self.tiered.cold = kvcache.to_host(
-                        kvcache.copy_slot_rows(
-                            kvcache.to_device(self.tiered.cold),
-                            new_caches, s, pos, pos + 1, self.max_seq))
-                    self.sim_migration_bytes += self._row_bytes
-        else:
-            self.caches = new_caches
+                caches=self.caches, cache_index=self.lengths, decode=True)
         return jnp.argmax(logits[:, -1, :self.cfg.vocab_size], axis=-1) \
             .astype(jnp.int32)
 
@@ -765,8 +682,8 @@ class ContinuousBatcher:
         decode step's ``step_migration_bytes`` entry, exactly as
         ``predict_pool_counters(..., plan_schedule=...)`` replays it."""
         if self.pool is None:
-            raise ValueError("apply_plan requires the persistent-pools "
-                             "layout (use_paged_decode=True)")
+            raise ValueError("apply_plan requires a plan: the dense "
+                             "reference (plan=None) has no pools to re-tier")
         if hasattr(new_plan, "changes"):       # a PlanDelta, not a plan
             new_plan = self.plan.apply_delta(new_plan)
         page = self._plan_page_tokens(new_plan)
@@ -840,8 +757,8 @@ def predict_pool_counters(requests: Sequence[tuple], plan, *, slots: int,
     per-decode-step ``step_migration_bytes`` series a CostModel prices),
     the pool's ``page_copies`` / ``admit_page_writes`` counters, and the
     per-tenant hot-pool byte peaks
-    — *exactly* (integer-for-integer) what a ``ContinuousBatcher``
-    (``paged=True`` + ``use_paged_decode``) will report
+    — *exactly* (integer-for-integer) what a ``ContinuousBatcher`` with
+    that plan will report
     on the same deterministic stream.  This is the engine/simulator
     agreement contract: the simulator predicts, the engine counts, the two
     never drift (``tests/test_multi_tenant.py`` pins it).

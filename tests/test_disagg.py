@@ -122,8 +122,7 @@ def engine_pair():
     from repro.serve.disagg import DisaggregatedEngine
     from repro.serve.engine import serve_trace_for
 
-    cfg = dataclasses.replace(get_config("smollm-360m").reduced(),
-                              use_paged_decode=True)
+    cfg = get_config("smollm-360m").reduced()
     params, _ = split_params(model.init_params(jax.random.PRNGKey(0), cfg))
     max_seq, slots = 32, 2
     requests = [(7, 6), (9, 5), (6, 7), (8, 6)]
@@ -142,7 +141,7 @@ def engine_pair():
                 sub, (plen,), 0, cfg.vocab_size).astype(jnp.int32), d)
         return b.run(), b
 
-    out_c, bc = drive(engine.ContinuousBatcher, paged=True)
+    out_c, bc = drive(engine.ContinuousBatcher)
     out_d, bd = drive(DisaggregatedEngine)
     return requests, plan, (out_c, bc), (out_d, bd)
 
@@ -171,26 +170,18 @@ def test_disagg_ledger_matches_prediction_exactly(engine_pair):
 
 
 def test_disagg_requires_pools_layout():
+    """The disaggregated engine streams pages into decode pools, which only
+    a plan builds: no plan, no engine."""
     import jax
 
-    from repro import runtime
     from repro.configs.base import get_config
-    from repro.core.hardware import TPU_V5E
     from repro.models import model
     from repro.models.layers import split_params
     from repro.serve.disagg import DisaggregatedEngine
-    from repro.serve.engine import serve_trace_for
 
-    cfg = get_config("smollm-360m").reduced()   # no use_paged_decode
+    cfg = get_config("smollm-360m").reduced()
     params, _ = split_params(model.init_params(jax.random.PRNGKey(0), cfg))
-    trace = serve_trace_for(get_config("smollm-360m"), [(7, 6)], slots=2,
-                            layer_group=8)
-    plan = runtime.plan(trace, TPU_V5E, 0.2 * trace.peak_kv_bytes())
-    plan = dataclasses.replace(plan, hot_window=16, slot_hot_windows=[4, 8],
-                               page_tokens=4)
-    with pytest.raises(ValueError):
-        DisaggregatedEngine(params, cfg, 2, 32, plan=plan)
-    with pytest.raises(ValueError):
+    with pytest.raises(ValueError, match="requires a plan"):
         DisaggregatedEngine(params, cfg, 2, 32, plan=None)
 
 

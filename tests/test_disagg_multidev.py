@@ -50,8 +50,7 @@ SCENARIO = textwrap.dedent("""
     pre, dec = disagg_groups(devs)
     rec["groups"] = [len(pre), len(dec)]
 
-    cfg = dataclasses.replace(get_config("smollm-360m").reduced(),
-                              use_paged_decode=True)
+    cfg = get_config("smollm-360m").reduced()
     params, _ = split_params(model.init_params(jax.random.PRNGKey(0), cfg))
     max_seq, slots = 32, 4
     key = jax.random.PRNGKey(3)
@@ -93,7 +92,7 @@ SCENARIO = textwrap.dedent("""
 
     # colocated all-HBM reference: same admission schedule, no tiering
     ref = engine.ContinuousBatcher(
-        params, cfg, slots, max_seq, paged=True,
+        params, cfg, slots, max_seq,
         plan=dataclasses.replace(plan, hot_window=max_seq,
                                  slot_hot_windows=None, slot_devices=None))
     out_ref = drive(ref)

@@ -6,7 +6,6 @@ pages cold and with all hot.  ``mla_attention`` on the pools (the query
 absorbed into the latent, ``w_uk`` before the kernel and ``w_uv`` after it)
 gives what the dense, expanded ``mla_attention`` gives for the same cache,
 through the oracle and through the kernel."""
-import dataclasses
 
 import jax
 import jax.numpy as jnp
@@ -44,8 +43,7 @@ def test_latent_kernel_is_bit_exact_against_its_oracle(tiers):
 
 
 def _cfg():
-    return dataclasses.replace(get_config("deepseek-v2-lite-16b").reduced(),
-                               use_paged_decode=True)
+    return get_config("deepseek-v2-lite-16b").reduced()
 
 
 def _pack(dense, cold_tokens):

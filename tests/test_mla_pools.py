@@ -23,7 +23,7 @@ MAX_SEQ, PAGE = 32, 4
 
 def _cfg(dtype="float32"):
     return dataclasses.replace(get_config("deepseek-v2-lite-16b").reduced(),
-                               use_paged_decode=True, dtype=dtype)
+                               dtype=dtype)
 
 
 def test_latent_pool_layout_and_page_size():
@@ -96,8 +96,7 @@ def _stream(cfg, params, hot_window):
         runtime.plan(trace, TPU_V5E, 0.2 * trace.peak_kv_bytes()),
         hot_window=hot_window, slot_hot_windows=[hot_window] * 2,
         page_tokens=PAGE)
-    b = engine.ContinuousBatcher(params, cfg, 2, MAX_SEQ, plan=plan,
-                                 paged=True)
+    b = engine.ContinuousBatcher(params, cfg, 2, MAX_SEQ, plan=plan)
     assert b._pool_prefill_ok
     for p in PROMPTS:
         b.submit(p, 7)
@@ -114,8 +113,7 @@ def test_mla_stack_serves_dense_tokens_through_the_pools(hot_window):
     cfg = _cfg()
     params, _ = split_params(model.init_params(jax.random.PRNGKey(0), cfg))
     b, got = _stream(cfg, params, hot_window)
-    dense = dataclasses.replace(cfg, use_paged_decode=False)
-    want = [list(map(int, engine.generate(params, dense, {"tokens": p[None]},
+    want = [list(map(int, engine.generate(params, cfg, {"tokens": p[None]},
                                           7)[0])) for p in PROMPTS]
     assert sorted(got) == sorted(want)
     c = b.counters()

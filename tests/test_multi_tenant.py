@@ -143,7 +143,6 @@ def tenant_run():
 
     cfg = get_config("smollm-360m").reduced()
     params, _ = split_params(model.init_params(jax.random.PRNGKey(0), cfg))
-    cfg_k = dataclasses.replace(cfg, use_paged_decode=True)
     max_seq, slots = 32, 4
     chatty = [(5, 5), (6, 4), (7, 5)]
     bursty = [(12, 6), (11, 5)]
@@ -165,16 +164,15 @@ def tenant_run():
             reqs.append((jax.random.randint(sub, (p,), 0, cfg.vocab_size)
                          .astype(jnp.int32), d, tn))
 
-    def drive(c, p, paged):
-        b = engine.ContinuousBatcher(params, c, slots, max_seq, plan=p,
-                                     paged=paged,
+    def drive(p):
+        b = engine.ContinuousBatcher(params, cfg, slots, max_seq, plan=p,
                                      slot_tenants=plan.slot_tenants)
         for t, d, tn in reqs:
             b.submit(t, d, tenant=tn)
         return b.run(), b
 
-    out_ref, _ = drive(cfg, None, False)
-    out, b = drive(cfg_k, plan, True)
+    out_ref, _ = drive(None)
+    out, b = drive(plan)
     return {"engine": engine, "plan": plan, "b": b, "out": out,
             "out_ref": out_ref, "reqs": reqs, "slots": slots,
             "max_seq": max_seq}

@@ -32,7 +32,7 @@ PARENT = {"sentinel.admit": "sentinel.step",
 def setup():
     cfg = get_config("smollm-360m").reduced()
     params, _ = split_params(model.init_params(jax.random.PRNGKey(0), cfg))
-    return dataclasses.replace(cfg, use_paged_decode=True), params
+    return cfg, params
 
 
 @pytest.fixture(scope="module", params=[0, 4], ids=["one-shot", "chunked"])
@@ -48,7 +48,6 @@ def served(request, setup):
         runtime.plan(trace, TPU_V5E, 0.2 * trace.peak_kv_bytes()),
         hot_window=4, slot_hot_windows=[4, 4], page_tokens=page)
     b = engine.ContinuousBatcher(params, cfg, 2, max_seq, plan=plan,
-                                 paged=True,
                                  prefill_chunk_tokens=request.param)
     t0 = time.perf_counter_ns()
     rids = [b.submit(jnp.arange(n, dtype=jnp.int32) % cfg.vocab_size, d)
@@ -130,8 +129,7 @@ def test_compiled_decode_builds_nothing_after_warm_up(setup):
     plan = dataclasses.replace(
         runtime.plan(trace, TPU_V5E, 0.2 * trace.peak_kv_bytes()),
         hot_window=4, slot_hot_windows=[4, 4], page_tokens=page)
-    b = engine.ContinuousBatcher(params, cfg, 2, max_seq, plan=plan,
-                                 paged=True)
+    b = engine.ContinuousBatcher(params, cfg, 2, max_seq, plan=plan)
     handed = []                    # the hot pools of every decode call
     compiled = b._decode_step
 

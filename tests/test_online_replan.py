@@ -218,7 +218,6 @@ def replan_run():
 
     cfg = get_config("smollm-360m").reduced()
     params, _ = split_params(model.init_params(jax.random.PRNGKey(0), cfg))
-    cfg_k = dataclasses.replace(cfg, use_paged_decode=True)
     max_seq, slots = 32, 4
     chatty = [(5, 5), (6, 4), (7, 5), (5, 4)]
     bursty = [(12, 6), (11, 5), (10, 4)]
@@ -245,9 +244,8 @@ def replan_run():
             reqs.append((jax.random.randint(sub, (p,), 0, cfg.vocab_size)
                          .astype(jnp.int32), d, tn))
 
-    def drive(c, p, paged, schedule=()):
-        b = engine.ContinuousBatcher(params, c, slots, max_seq, plan=p,
-                                     paged=paged,
+    def drive(p, schedule=()):
+        b = engine.ContinuousBatcher(params, cfg, slots, max_seq, plan=p,
                                      slot_tenants=plan_a.slot_tenants)
         for t, d, tn in reqs:
             b.submit(t, d, tenant=tn)
@@ -265,8 +263,8 @@ def replan_run():
         return results, b, moved
 
     schedule = [(3, delta_b), (6, plan_c)]
-    out_ref, _, _ = drive(cfg, None, False)
-    out, b, moved = drive(cfg_k, plan_a, True, schedule)
+    out_ref, _, _ = drive(None)
+    out, b, moved = drive(plan_a, schedule)
     return {"engine": engine, "b": b, "out": out, "out_ref": out_ref,
             "moved": moved, "reqs": reqs, "slots": slots, "max_seq": max_seq,
             "plan_a": plan_a, "schedule": schedule}

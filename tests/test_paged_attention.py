@@ -1,7 +1,7 @@
-"""ops.paged_decode_attention wired into the model attention layer behind
-``cfg.use_paged_decode``: decode reads KV through the tiered page pools
-(hot/cold + per-slot page table) instead of the dense masked-merge view,
-and the results are parity with the masked-merge path."""
+"""ops.paged_decode_attention wired into the model attention layer: with a
+plan, the serving engine's decode reads KV through the persistent page
+pools (hot/cold + per-slot page table) and serves exactly the all-HBM
+reference's tokens; ``pack_kv_pools`` lays a dense cache out as pools."""
 import dataclasses
 
 import jax
@@ -11,7 +11,7 @@ import pytest
 from repro import runtime
 from repro.configs.base import get_config
 from repro.core.hardware import TPU_V5E
-from repro.models import kvcache, model
+from repro.models import model
 from repro.models.layers import split_params
 from repro.serve import engine
 
@@ -23,60 +23,9 @@ def setup():
     return cfg, params
 
 
-def test_paged_decode_logits_parity(setup):
-    """One decode step: logits through the page pools match the dense
-    masked-merge path (same values, different read layout/reduction)."""
-    cfg, params = setup
-    B, S, page = 2, 16, 4
-    prompts = jax.random.randint(jax.random.PRNGKey(1), (B, 7), 0,
-                                 cfg.vocab_size).astype(jnp.int32)
-    _, caches = model.prefill(params, cfg, {"tokens": prompts}, max_seq=S)
-    lengths = jnp.array([7, 7], jnp.int32)
-    tok = jnp.array([[3], [5]], jnp.int32)
-
-    dense_logits, _, _ = model.forward(
-        params, cfg, {"tokens": tok}, caches=caches, cache_index=lengths,
-        decode=True)
-    cfg_paged = dataclasses.replace(cfg, use_paged_decode=True)
-    paged_logits, _, _ = model.forward(
-        params, cfg_paged, {"tokens": tok}, caches=caches,
-        cache_index=lengths, decode=True,
-        paged_view={"boundaries": [4, 0], "page_tokens": page})
-    assert jnp.allclose(dense_logits, paged_logits, atol=1e-4, rtol=1e-4)
-    # the flag alone (no page view provided) must not change the path
-    flag_only, _, _ = model.forward(
-        params, cfg_paged, {"tokens": tok}, caches=caches,
-        cache_index=lengths, decode=True)
-    assert jnp.array_equal(dense_logits, flag_only)
-
-
-def test_paged_decode_cold_rows_are_read_from_pools(setup):
-    """The kernel path really reads through the page table: scribbling over
-    the dense rows of a *hot* page changes the output, while the packed
-    pools pin which physical page each logical page resolves to."""
-    cfg, params = setup
-    B, S, page = 2, 16, 4
-    prompts = jax.random.randint(jax.random.PRNGKey(2), (B, 9), 0,
-                                 cfg.vocab_size).astype(jnp.int32)
-    _, caches = model.prefill(params, cfg, {"tokens": prompts}, max_seq=S)
-    lengths = jnp.array([9, 9], jnp.int32)
-    tok = jnp.array([[3], [5]], jnp.int32)
-    cfg_paged = dataclasses.replace(cfg, use_paged_decode=True)
-    pv = {"boundaries": [8, 4], "page_tokens": page}
-    a, _, _ = model.forward(params, cfg_paged, {"tokens": tok}, caches=caches,
-                            cache_index=lengths, decode=True, paged_view=pv)
-    # zero the K rows the slots actually attend to -> output must change
-    wiped = jax.tree.map(
-        lambda l: l.at[..., :, :9, :].set(0.0)
-        if l.ndim >= 3 and l.shape[-2] == S else l, caches)
-    b, _, _ = model.forward(params, cfg_paged, {"tokens": tok}, caches=wiped,
-                            cache_index=lengths, decode=True, paged_view=pv)
-    assert not jnp.allclose(a, b, atol=1e-4)
-
-
 def test_paged_kernel_batcher_matches_reference(setup):
-    """End to end: ContinuousBatcher(paged=True) with use_paged_decode
-    produces exactly the tokens of the all-HBM reference run."""
+    """End to end: ContinuousBatcher with a plan decodes through the pools
+    and produces exactly the tokens of the all-HBM reference run."""
     cfg, params = setup
     max_seq, slots = 32, 2
     requests = [(7, 6), (9, 5), (6, 7)]
@@ -86,9 +35,8 @@ def test_paged_kernel_batcher_matches_reference(setup):
     plan = dataclasses.replace(plan, hot_window=max_seq // 2,
                                slot_hot_windows=[4, 8], page_tokens=4)
 
-    def run(c, p, paged=False):
-        b = engine.ContinuousBatcher(params, c, slots, max_seq, plan=p,
-                                     paged=paged)
+    def run(p):
+        b = engine.ContinuousBatcher(params, cfg, slots, max_seq, plan=p)
         key = jax.random.PRNGKey(3)
         for plen, d in requests:
             key, sub = jax.random.split(key)
@@ -96,14 +44,13 @@ def test_paged_kernel_batcher_matches_reference(setup):
                                         cfg.vocab_size).astype(jnp.int32), d)
         return b.run(), b
 
-    base, _ = run(cfg, None)
-    cfg_kernel = dataclasses.replace(cfg, use_paged_decode=True)
-    paged, b = run(cfg_kernel, plan, paged=True)
+    base, _ = run(None)
+    paged, b = run(plan)
     assert base == paged
     assert len(base) == len(requests)
     # the persistent pools really are the cache: boundaries advanced in the
     # page table, pool data moved, and nothing was ever dense-re-packed
-    assert b.pool is not None and b.paged is None
+    assert b.pool is not None and b.caches is None
     assert any(b.ptable.cold_tokens(s) > 0 for s in range(slots))
     assert b.pool.stats["repacks"] == 0
     assert b.pool.stats["page_copies"] > 0

@@ -1,13 +1,14 @@
 """Per-slot cold boundaries end-to-end: PageTable alloc/free/splice
 invariants, planner slot windows, boundary monotonicity under slot refill,
-and the paged ContinuousBatcher matching the all-HBM reference while moving
-fewer simulated migration bytes than the global-boundary concat path."""
+and the pools ContinuousBatcher matching the all-HBM reference — on the
+GQA stack and on every other kind of stack the registry holds."""
 import dataclasses
 
 import jax
 import jax.numpy as jnp
 import pytest
 
+from repro import runtime
 from repro.configs.base import get_config
 from repro.core import planner
 from repro.core.hardware import TPU_V5E
@@ -59,30 +60,6 @@ def test_page_table_guards():
         pt.demote(0, 1)                   # cold pool only had one page
 
 
-def test_paged_cache_merge_is_bit_identical():
-    """Scribbling over hot rows below a slot's boundary must not leak into
-    the merged view — cold rows are the copy of record."""
-    cfg = get_config("smollm-360m").reduced()
-    B, S, page = 2, 32, 8
-    pc = kvcache.init_paged_cache(cfg, B, S, page, jnp.float32)
-    dense = jax.tree.map(
-        lambda a: jax.random.normal(jax.random.PRNGKey(a.size % 89),
-                                    a.shape).astype(a.dtype),
-        kvcache.init_cache(cfg, B, S, jnp.float32))
-    pc.hot = dense
-    assert pc.demote_rows(0, 16) == 16
-    assert pc.demote_rows(0, 16) == 0            # idempotent at the boundary
-    pc.hot = kvcache.copy_slot_rows(
-        jax.tree.map(lambda a: a, pc.hot),
-        jax.tree.map(lambda a: None if a is None else jnp.full_like(a, -9.0),
-                     pc.hot, is_leaf=lambda x: x is None),
-        0, 0, 16, S)
-    merged = pc.merged()
-    for a, b in zip(jax.tree.leaves(dense), jax.tree.leaves(merged)):
-        if a.ndim >= 3 and a.shape[-2] == S:
-            assert jnp.array_equal(a, b)
-
-
 # --------------------------------------------------------------- planner ----
 
 def test_plan_serve_slot_windows():
@@ -115,7 +92,9 @@ def test_plan_serve_slot_windows():
 
 @pytest.fixture(scope="module")
 def served():
-    """Run the same request stream through all three batcher layouts."""
+    """Run the same request stream through the dense reference and the
+    pools, logging every slot's length, cold boundary, activity and plan
+    target after each step of the pools run."""
     cfg = get_config("smollm-360m").reduced()
     params, _ = split_params(model.init_params(jax.random.PRNGKey(0), cfg))
     max_seq, slots = 32, 2
@@ -128,9 +107,8 @@ def served():
     plan = dataclasses.replace(plan, hot_window=max_seq // 2,
                                slot_hot_windows=[4, 8], page_tokens=4)
 
-    def run(p, paged=False):
-        b = engine.ContinuousBatcher(params, cfg, slots, max_seq, plan=p,
-                                     paged=paged)
+    def run(p):
+        b = engine.ContinuousBatcher(params, cfg, slots, max_seq, plan=p)
         key = jax.random.PRNGKey(3)
         boundary_log = []
         for plen, d in requests:
@@ -141,10 +119,15 @@ def served():
         while b.queue or any(b.active):
             if not b.step():
                 break
-            if paged:
+            if p is not None:
+                lengths = [int(x) for x in b.lengths]
                 boundary_log.append((
-                    [int(x) for x in b.lengths],
-                    [int(x) for x in jnp.asarray(b.paged.boundaries)]))
+                    lengths,
+                    [b.ptable.cold_tokens(s) for s in range(b.B)],
+                    list(b.active),
+                    [p.cold_len_slot(s, lengths[s], b.page_tokens)
+                     for s in range(b.B)]))
+                b.ptable.check()
             for i in range(b.B):
                 if not b.active[i] and b.outputs[i]:
                     results.append(b.outputs[i])
@@ -152,43 +135,76 @@ def served():
         return results, b, boundary_log
 
     base, _, _ = run(None)
-    concat, b_concat, _ = run(plan)
-    paged, b_paged, log = run(plan, paged=True)
-    return base, concat, paged, b_concat, b_paged, log
+    pools, b_pools, log = run(plan)
+    return base, pools, b_pools, log
 
 
 def test_paged_batcher_matches_all_hbm(served):
-    base, concat, paged, *_ = served
-    assert base == concat == paged
+    base, pools, b_pools, _ = served
+    assert b_pools.pool is not None
+    assert base == pools
     assert len(base) == 3
-
-
-def test_paged_moves_fewer_bytes_than_concat(served):
-    *_, b_concat, b_paged, _ = served
-    assert b_paged.sim_migration_bytes > 0       # boundaries actually moved
-    assert b_paged.sim_migration_bytes < b_concat.sim_migration_bytes
 
 
 def test_per_slot_boundary_monotone_under_refill(served):
     """Within one residency a slot's cold boundary only advances (and stays
     page-aligned, at or below the slot's length); it resets only when the
     slot is refilled with a new request."""
-    *_, b_paged, log = served
-    page = b_paged.page_tokens
-    assert any(any(bd > 0 for bd in bounds) for _, bounds in log)
-    for (len_prev, bd_prev), (len_now, bd_now) in zip(log, log[1:]):
+    *_, b_pools, log = served
+    page = b_pools.page_tokens
+    assert b_pools.sim_migration_bytes > 0       # boundaries actually moved
+    assert any(any(bd > 0 for bd in bounds) for _, bounds, _, _ in log)
+    for (len_prev, bd_prev, _, _), (len_now, bd_now, _, _) in zip(log,
+                                                                  log[1:]):
         for s in range(len(bd_now)):
             assert bd_now[s] % page == 0
             assert bd_now[s] <= len_now[s]
             if len_now[s] == len_prev[s] + 1:    # same residency, one decode
                 assert bd_now[s] >= bd_prev[s]
-    b_paged.ptable.check()
+    b_pools.ptable.check()
 
 
 def test_paged_table_consistent_with_boundaries(served):
-    """The PageTable's per-slot cold pages agree with the storage-side
-    boundary vector at the end of the run."""
-    *_, b_paged, _ = served
-    bounds = [int(x) for x in jnp.asarray(b_paged.paged.boundaries)]
-    for s in range(b_paged.B):
-        assert b_paged.ptable.cold_tokens(s) == bounds[s]
+    """After every step, each active slot's cold pages in the PageTable are
+    exactly the plan's per-slot boundary at the slot's length (and the
+    table's invariants held: ``check`` ran after each step)."""
+    *_, log = served
+    assert log
+    for lengths, bounds, active, targets in log:
+        for s in range(len(bounds)):
+            if active[s]:
+                assert bounds[s] == targets[s], (s, lengths[s])
+
+
+@pytest.mark.parametrize("arch", ["paligemma-3b", "gemma2-2b", "zamba2-7b",
+                                  "lstm-ptb", "granite-moe-3b-a800m"])
+def test_every_stack_serves_dense_tokens_through_the_pools(arch):
+    """A plan puts every kind of stack on the pools — prefix-LM, local and
+    global attention, mamba with shared attention, a stack with no pooled
+    layer, MoE — and the tokens equal the dense no-plan reference's while
+    pages demote."""
+    cfg = get_config(arch).reduced()
+    params, _ = split_params(model.init_params(jax.random.PRNGKey(0), cfg))
+    slots, max_seq, new = 2, 64, 6
+    prompts = [5, 20, 33]
+    trace = engine.serve_trace_for(cfg, [(p, new) for p in prompts],
+                                   slots=slots)
+    plan = runtime.plan(trace, TPU_V5E, 0.2 * trace.peak_kv_bytes())
+    plan = dataclasses.replace(plan, hot_window=8, page_tokens=8,
+                               slot_hot_windows=None)
+
+    def run(p):
+        b = engine.ContinuousBatcher(params, cfg, slots, max_seq, plan=p)
+        key = jax.random.PRNGKey(5)
+        for plen in prompts:
+            key, sub = jax.random.split(key)
+            b.submit(jax.random.randint(sub, (plen,), 0,
+                                        cfg.vocab_size).astype(jnp.int32),
+                     new)
+        return b.run(), b
+
+    base, _ = run(None)
+    pools, b = run(plan)
+    assert b.pool.stats["page_copies"] > 0
+    assert pools == base
+    assert len(base) == len(prompts)

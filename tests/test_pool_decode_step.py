@@ -22,7 +22,7 @@ MAX_SEQ, PAGE = 32, 4
 @pytest.mark.parametrize("hot_window", [4, MAX_SEQ], ids=["tiered", "allfast"])
 def test_compiled_step_matches_eager_forward(dtype, hot_window):
     cfg = dataclasses.replace(get_config("smollm-360m").reduced(),
-                              use_paged_decode=True, dtype=dtype)
+                              dtype=dtype)
     params, _ = split_params(model.init_params(jax.random.PRNGKey(0), cfg))
     trace = engine.serve_trace_for(get_config("smollm-360m"),
                                    [(9, 6), (11, 6)], slots=2, layer_group=8)
@@ -30,8 +30,7 @@ def test_compiled_step_matches_eager_forward(dtype, hot_window):
         runtime.plan(trace, TPU_V5E, 0.2 * trace.peak_kv_bytes()),
         hot_window=hot_window, slot_hot_windows=[hot_window] * 2,
         page_tokens=PAGE)
-    b = engine.ContinuousBatcher(params, cfg, 2, MAX_SEQ, plan=plan,
-                                 paged=True)
+    b = engine.ContinuousBatcher(params, cfg, 2, MAX_SEQ, plan=plan)
     b.submit(jnp.arange(9, dtype=jnp.int32), 6)
     b.submit(jnp.arange(3, 14, dtype=jnp.int32), 6)
     for _ in range(3):

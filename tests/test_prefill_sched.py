@@ -48,12 +48,12 @@ def _toks(key, n, cfg):
                               cfg.vocab_size).astype(jnp.int32)
 
 
-def _drive(params, cfg, plan, reqs, *, paged, keys=None, chunk=0,
+def _drive(params, cfg, plan, reqs, *, keys=None, chunk=0,
            replan_at=None, new_plan=None):
     """Run a batcher to completion; returns (sorted output tuples, engine,
     whether a re-plan landed while a prefill job was in flight)."""
     b = engine.ContinuousBatcher(params, cfg, SLOTS, MAX_SEQ, plan=plan,
-                                 paged=paged, prefill_chunk_tokens=chunk)
+                                 prefill_chunk_tokens=chunk)
     for i, (t, d) in enumerate(reqs):
         b.submit(t, d, prefix_key=keys[i] if keys else None)
     results, steps, mid_prefill = [], 0, False
@@ -99,15 +99,14 @@ def test_shared_admit_skips_donor_pages(setup, prefix_len):
     admits compute only over its suffix (strictly fewer prefill tokens than
     the unshared run), tokens identical to the dense all-HBM reference."""
     cfg, params = setup
-    cfg_k = dataclasses.replace(cfg, use_paged_decode=True)
     plan = _plan()
     sys_p = _toks(7, prefix_len, cfg)
     reqs = [(jnp.concatenate([sys_p, _toks(11 + i, 2 + i, cfg)]), 5)
             for i in range(3)]
-    base, _, _ = _drive(params, cfg, None, reqs, paged=False)
-    out_s, b_s, _ = _drive(params, cfg_k, plan, reqs, paged=True,
+    base, _, _ = _drive(params, cfg, None, reqs)
+    out_s, b_s, _ = _drive(params, cfg, plan, reqs,
                            keys=["sys"] * len(reqs))
-    out_u, b_u, _ = _drive(params, cfg_k, plan, reqs, paged=True)
+    out_u, b_u, _ = _drive(params, cfg, plan, reqs)
     assert base == out_s == out_u
     c_s, c_u = b_s.counters(), b_u.counters()
     assert c_s["prefill_compute_tokens"] < c_u["prefill_compute_tokens"]
@@ -128,13 +127,12 @@ def test_chunked_prefill_bit_identical_across_chunk_sizes(setup, chunk):
     keeps decoding: same token set as one-shot admission and as the dense
     all-HBM engine, for every chunk size."""
     cfg, params = setup
-    cfg_k = dataclasses.replace(cfg, use_paged_decode=True)
     plan = _plan()
     reqs = [(_toks(3, 5, cfg), 14), (_toks(4, 18, cfg), 4),
             (_toks(5, 15, cfg), 4)]
-    base, _, _ = _drive(params, cfg, None, reqs, paged=False)
-    one, b1, _ = _drive(params, cfg_k, plan, reqs, paged=True, chunk=0)
-    chk, bc, _ = _drive(params, cfg_k, plan, reqs, paged=True, chunk=chunk)
+    base, _, _ = _drive(params, cfg, None, reqs)
+    one, b1, _ = _drive(params, cfg, plan, reqs, chunk=0)
+    chk, bc, _ = _drive(params, cfg, plan, reqs, chunk=chunk)
     assert base == one == chk
     cc = bc.counters()
     assert cc["prefill_compute_tokens"] == \
@@ -147,10 +145,16 @@ def test_chunked_prefill_bit_identical_across_chunk_sizes(setup, chunk):
 
 
 def test_chunk_requires_pool_layout(setup):
+    """Chunks are written into the pools: the dense reference (no plan) and
+    the dense one-shot admission (``pool_prefill=False``) refuse them."""
     cfg, params = setup
     with pytest.raises(ValueError, match="pool"):
+        engine.ContinuousBatcher(params, cfg, SLOTS, MAX_SEQ, plan=None,
+                                 prefill_chunk_tokens=PAGE)
+    with pytest.raises(ValueError, match="pool"):
         engine.ContinuousBatcher(params, cfg, SLOTS, MAX_SEQ, plan=_plan(),
-                                 paged=True, prefill_chunk_tokens=PAGE)
+                                 prefill_chunk_tokens=PAGE,
+                                 pool_prefill=False)
 
 
 def test_replan_lands_mid_prefill(setup):
@@ -158,14 +162,13 @@ def test_replan_lands_mid_prefill(setup):
     under the new plan and the run stays bit-identical to the dense
     reference."""
     cfg, params = setup
-    cfg_k = dataclasses.replace(cfg, use_paged_decode=True)
     plan = _plan()
     shrunk = dataclasses.replace(plan, hot_window=8,
                                  slot_hot_windows=[4, 8])
     reqs = [(_toks(3, 5, cfg), 14), (_toks(4, 20, cfg), 4),
             (_toks(5, 16, cfg), 4)]
-    base, _, _ = _drive(params, cfg, None, reqs, paged=False)
-    out, b, mid = _drive(params, cfg_k, plan, reqs, paged=True, chunk=PAGE,
+    base, _, _ = _drive(params, cfg, None, reqs)
+    out, b, mid = _drive(params, cfg, plan, reqs, chunk=PAGE,
                          replan_at=2, new_plan=shrunk)
     assert mid                      # the re-plan really hit an in-flight job
     assert base == out
@@ -180,12 +183,11 @@ def test_predict_pool_counters_chunked_integer_exact(setup):
     chunked engine's books integer-for-integer: migration total and series,
     page copies, admit writes."""
     cfg, params = setup
-    cfg_k = dataclasses.replace(cfg, use_paged_decode=True)
     plan = _plan(windows=(4, 8))       # small windows: demotions occur
     requests = [(5, 9), (17, 4), (14, 5), (9, 6)]
     reqs = [(_toks(20 + i, p, cfg), d) for i, (p, d) in enumerate(requests)]
     for chunk in (0, PAGE, 2 * PAGE):
-        _, b, _ = _drive(params, cfg_k, plan, reqs, paged=True, chunk=chunk)
+        _, b, _ = _drive(params, cfg, plan, reqs, chunk=chunk)
         pred = engine.predict_pool_counters(
             requests, plan, slots=SLOTS, max_seq=MAX_SEQ,
             page_tokens=b.page_tokens, row_bytes=b._row_bytes,
@@ -217,14 +219,13 @@ if HAVE_HYP:
         shared, chunked pool engine always reproduces the dense all-HBM
         token set."""
         cfg, params = setup
-        cfg_k = dataclasses.replace(cfg, use_paged_decode=True)
         plan = _plan()
         sys_p = _toks(40 + seed, prefix_len, cfg)
         reqs = [(jnp.concatenate([sys_p, _toks(50 + seed + i, 1 + (seed + i) % 5,
                                                cfg)]), 3 + (seed + i) % 4)
                 for i in range(3)]
-        base, _, _ = _drive(params, cfg, None, reqs, paged=False)
-        out, b, _ = _drive(params, cfg_k, plan, reqs, paged=True,
+        base, _, _ = _drive(params, cfg, None, reqs)
+        out, b, _ = _drive(params, cfg, plan, reqs,
                            keys=["sys"] * len(reqs), chunk=chunk)
         assert base == out
         b.ptable.check()

@@ -129,11 +129,9 @@ def test_pool_steady_state_zero_repacks_zero_syncs(setup, monkeypatch):
     monkeypatch.setattr(pd, "pool_layout", poisoned)
 
     cfg, params = setup
-    cfg_k = dataclasses.replace(cfg, use_paged_decode=True)
     max_seq, page = 32, 4
     plan = _plan(max_seq, [16, 16], page)     # huge windows: no demotions
-    b = engine.ContinuousBatcher(params, cfg_k, 2, max_seq, plan=plan,
-                                 paged=True)
+    b = engine.ContinuousBatcher(params, cfg, 2, max_seq, plan=plan)
     b.submit(jnp.arange(5, dtype=jnp.int32), 8)
     b.submit(jnp.arange(6, dtype=jnp.int32), 8)
     assert b.step()                            # admits + first decode
@@ -160,17 +158,15 @@ def test_pool_decode_writes_land_in_physical_pages(setup):
     pool pages of a slot hold the KV the dense path would hold (the pools
     are the only storage — scribbling the pool changes the next logits)."""
     cfg, params = setup
-    cfg_k = dataclasses.replace(cfg, use_paged_decode=True)
     max_seq, page = 32, 4
     plan = _plan(max_seq, [16, 16], page)
-    b = engine.ContinuousBatcher(params, cfg_k, 2, max_seq, plan=plan,
-                                 paged=True)
+    b = engine.ContinuousBatcher(params, cfg, 2, max_seq, plan=plan)
     b.submit(jnp.arange(5, dtype=jnp.int32), 4)
     for _ in range(3):
         b.step()
     view = b.pool.forward_tree(stage=b.pool.has_cold())
     logits_ref, _, _ = model.forward(
-        params, cfg_k, {"tokens": b.last_tok[:, None]}, caches=view,
+        params, cfg, {"tokens": b.last_tok[:, None]}, caches=view,
         cache_index=b.lengths, decode=True,
         paged_view=b.pool.paged_view(b._active_mask))
     # zero slot 0's first physical hot page -> attention must change
@@ -180,7 +176,7 @@ def test_pool_decode_writes_land_in_physical_pages(setup):
     tree = {"prologue": list(view["prologue"]),
             "slots": [wiped] + list(view["slots"][1:])}
     logits_wiped, _, _ = model.forward(
-        params, cfg_k, {"tokens": b.last_tok[:, None]}, caches=tree,
+        params, cfg, {"tokens": b.last_tok[:, None]}, caches=tree,
         cache_index=b.lengths, decode=True,
         paged_view=b.pool.paged_view(b._active_mask))
     assert not jnp.allclose(logits_ref[0], logits_wiped[0], atol=1e-4)
@@ -193,7 +189,6 @@ def test_shared_prefix_slots_bit_identical_and_cheaper(setup):
     all-HBM reference, logits bit-identical to the unshared pool run, and
     strictly fewer physical pages + migration bytes."""
     cfg, params = setup
-    cfg_k = dataclasses.replace(cfg, use_paged_decode=True)
     max_seq, slots, page = 32, 2, 4
     plan = _plan(max_seq, [4, 8], page)       # small windows: demotions occur
     sys_p = jax.random.randint(jax.random.PRNGKey(7), (9,), 0,
@@ -203,9 +198,8 @@ def test_shared_prefix_slots_bit_identical_and_cheaper(setup):
              for i in range(2)]
     reqs = [(jnp.concatenate([sys_p, u]), 6) for u in users]
 
-    def drive(c, p, paged, shared):
-        b = engine.ContinuousBatcher(params, c, slots, max_seq, plan=p,
-                                     paged=paged)
+    def drive(p, shared):
+        b = engine.ContinuousBatcher(params, cfg, slots, max_seq, plan=p)
         for t, d in reqs:
             b.submit(t, d, prefix_key="sys" if shared else None)
         logit_log = []
@@ -217,9 +211,9 @@ def test_shared_prefix_slots_bit_identical_and_cheaper(setup):
             logit_log.append(b.last_tok)
         return b.outputs, b, logit_log
 
-    out_base, _, _ = drive(cfg, None, False, False)
-    out_s, b_s, log_s = drive(cfg_k, plan, True, True)
-    out_u, b_u, log_u = drive(cfg_k, plan, True, False)
+    out_base, _, _ = drive(None, False)
+    out_s, b_s, log_s = drive(plan, True)
+    out_u, b_u, log_u = drive(plan, False)
     assert out_s == out_u == out_base
     # bit-identical decode trajectories shared vs unshared
     for a, b in zip(log_s, log_u):
@@ -243,7 +237,6 @@ def test_pool_cold_arrays_stay_in_host_memory(setup):
     CoW — every transfer is explicit — and a 20% plan's tokens equal the
     all-HBM engine's."""
     cfg, params = setup
-    cfg_k = dataclasses.replace(cfg, use_paged_decode=True)
     max_seq, slots, page = 32, 2, 4
     host = kvcache.host_memory_kind()
     trace = engine.serve_trace_for(get_config("smollm-360m"),
@@ -257,16 +250,14 @@ def test_pool_cold_arrays_stay_in_host_memory(setup):
     reqs = [(jnp.concatenate([sys_p, jnp.arange(2 + i, dtype=jnp.int32)]),
              6) for i in range(2)]
 
-    def drive(c, p, paged):
-        b = engine.ContinuousBatcher(params, c, slots, max_seq, plan=p,
-                                     paged=paged)
+    def drive(p):
+        b = engine.ContinuousBatcher(params, cfg, slots, max_seq, plan=p)
         for t, d in reqs:
-            b.submit(t, d, prefix_key="sys" if paged else None)
+            b.submit(t, d, prefix_key="sys" if p is not None else None)
         return b.run(), b
 
-    want, _ = drive(cfg, None, False)
-    b = engine.ContinuousBatcher(params, cfg_k, slots, max_seq, plan=plan,
-                                 paged=True)
+    want, _ = drive(None)
+    b = engine.ContinuousBatcher(params, cfg, slots, max_seq, plan=plan)
     assert _cold_kinds(b.pool) == {host}
     for t, d in reqs:
         b.submit(t, d, prefix_key="sys")
@@ -294,21 +285,19 @@ def test_shared_prefix_logits_bit_identical_one_step(setup):
     produces exactly the logits of a private-pages run (same values read
     through a different physical mapping)."""
     cfg, params = setup
-    cfg_k = dataclasses.replace(cfg, use_paged_decode=True)
     max_seq, page = 32, 4
     plan = _plan(max_seq, [16, 16], page)
     prompt = jax.random.randint(jax.random.PRNGKey(5), (8,), 0,
                                 cfg.vocab_size).astype(jnp.int32)
 
     def one(shared):
-        b = engine.ContinuousBatcher(params, cfg_k, 2, max_seq, plan=plan,
-                                     paged=True)
+        b = engine.ContinuousBatcher(params, cfg, 2, max_seq, plan=plan)
         b.submit(prompt, 3, prefix_key="p" if shared else None)
         b.submit(prompt, 3, prefix_key="p" if shared else None)
         b._admit()
         pv = b.pool.paged_view(b._active_mask)
         logits, _, _ = model.forward(
-            params, cfg_k, {"tokens": b.last_tok[:, None]},
+            params, cfg, {"tokens": b.last_tok[:, None]},
             caches=b.pool.forward_tree(stage=b.pool.has_cold()),
             cache_index=b.lengths, decode=True, paged_view=pv)
         return logits, b

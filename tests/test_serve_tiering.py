@@ -139,21 +139,7 @@ def test_plan_serve_constraints(trace):
     assert pl.cold_len(pl.hot_window + 7) == 7
 
 
-# -------------------------------------------------- tiered cache pytrees ----
-
-def test_split_merge_roundtrip():
-    cfg = get_config("smollm-360m").reduced()
-    max_seq, cold = 40, 24
-    full = kvcache.init_cache(cfg, 2, max_seq, jnp.float32)
-    full = jax.tree.map(
-        lambda a: jax.random.normal(jax.random.PRNGKey(a.size % 97), a.shape)
-        .astype(a.dtype), full)
-    c, h = kvcache.split_seq_cache(full, max_seq, cold)
-    merged = kvcache.merge_seq_cache(kvcache.to_host(c), h)
-    for a, b in zip(jax.tree.leaves(full), jax.tree.leaves(merged)):
-        assert a.shape == b.shape
-        assert jnp.array_equal(a, b)
-
+# ------------------------------------------------------- cache splice ----
 
 def test_splice_slot_matches_direct_write():
     cfg = get_config("smollm-360m").reduced()
@@ -173,8 +159,9 @@ def test_splice_slot_matches_direct_write():
 # ------------------------------------------------------------------ e2e ----
 
 def test_tiered_batcher_matches_all_hbm():
-    """ContinuousBatcher with a host-offloaded cold prefix produces exactly
-    the tokens of the all-HBM reference run."""
+    """A plan alone puts the ContinuousBatcher on the persistent pools
+    (cold pages in host memory), and it produces exactly the tokens of the
+    all-HBM reference run."""
     cfg = get_config("smollm-360m").reduced()
     params, _ = split_params(model.init_params(jax.random.PRNGKey(0), cfg))
     max_seq, slots = 32, 2
@@ -187,15 +174,20 @@ def test_tiered_batcher_matches_all_hbm():
             key, sub = jax.random.split(key)
             b.submit(jax.random.randint(sub, (plen,), 0,
                                         cfg.vocab_size).astype(jnp.int32), d)
-        return b.run()
+        return b.run(), b
 
     trace = engine.serve_trace_for(get_config("smollm-360m"), requests,
                                    slots=slots, layer_group=8)
     plan = planner.plan_serve(trace, TPU_V5E, 0.2 * trace.peak_kv_bytes())
-    plan = dataclasses.replace(plan, hot_window=max_seq // 2)
-    assert plan.cold_len(max_seq) == max_seq // 2      # real cold prefix
+    # one window for every slot, short enough that each request's oldest
+    # page goes cold before it finishes
+    plan = dataclasses.replace(plan, hot_window=max_seq // 4, page_tokens=4,
+                               slot_hot_windows=None)
+    assert plan.cold_len(max_seq) == 3 * max_seq // 4  # real cold prefix
 
-    base = run(None)
-    tiered = run(plan)
+    base, b_base = run(None)
+    tiered, b = run(plan)
+    assert b_base.pool is None and b.pool is not None
+    assert b.pool.stats["page_copies"] > 0             # pages went cold
     assert base == tiered
     assert len(base) == len(requests)

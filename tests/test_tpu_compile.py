@@ -175,8 +175,7 @@ def test_decode_step_compiles_at_published_width(shapes, monkeypatch):
     sds, _ = shapes
     monkeypatch.setitem(ops._FORCE, "pallas", True)
     monkeypatch.setattr(ops, "_interpret", lambda: False)
-    cfg = dataclasses.replace(get_config("smollm-360m"),
-                              use_paged_decode=True)
+    cfg = get_config("smollm-360m")
     params = jax.tree.map(lambda a: sds(a.shape, a.dtype), jax.eval_shape(
         lambda k: split_params(model.init_params(k, cfg))[0],
         jax.random.PRNGKey(0)))
@@ -275,8 +274,8 @@ def test_mla_decode_step_compiles_at_published_width(shapes, monkeypatch):
     monkeypatch.setitem(ops._FORCE, "pallas", True)
     monkeypatch.setattr(ops, "_interpret", lambda: False)
     base = get_config("deepseek-v2-lite-16b")
-    cfg = dataclasses.replace(base, use_paged_decode=True, moe=dataclasses.
-                              replace(base.moe, num_held=DS_HELD))
+    cfg = dataclasses.replace(
+        base, moe=dataclasses.replace(base.moe, num_held=DS_HELD))
     params = jax.tree.map(lambda a: sds(a.shape, a.dtype), jax.eval_shape(
         lambda k: split_params(model.init_params(k, cfg))[0],
         jax.random.PRNGKey(0)))
